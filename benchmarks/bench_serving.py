@@ -599,18 +599,10 @@ def run_spec_agents(cfg, params, *, spec_k: int = 4, max_len: int = 256,
 QUANT_LOGIT_TOL = {"int8": 0.25, "fp8": 0.5}
 
 
-def _quant_modes():
-    from repro.models import cache as cache_mod
-
-    return tuple(m for m in cache_mod.KV_QUANT_MODES
-                 if m != "fp8" or cache_mod.FP8_DTYPE is not None)
-
-
 def run_quant_sweep(cfg, params, *, batch: int, max_len: int, page_size: int,
                     n_requests: int, prompt_hi: int, max_new: int,
                     chunk_size: int = 16, seed: int = 0) -> list[dict]:
-    """Quantized page-pool sweep: off vs int8 (vs fp8 when the jax build
-    has ``float8_e4m3fn``).
+    """Quantized page-pool sweep: off vs int8 vs fp8.
 
     One shared ragged workload through the full engine per mode.  The
     ``off`` row is the bf16-pool reference; the ``int8`` row must
@@ -622,6 +614,7 @@ def run_quant_sweep(cfg, params, *, batch: int, max_len: int, page_size: int,
     layout (pool + scale leaves) — how many MORE tokens the same pool MB
     can hold.
     """
+    from repro.models.cache import KV_QUANT_MODES
     from repro.serving.scheduler import ContinuousBatchingEngine, Request
 
     rng = np.random.default_rng(seed)
@@ -633,7 +626,7 @@ def run_quant_sweep(cfg, params, *, batch: int, max_len: int, page_size: int,
                                     page_size=page_size, kv_quant="off")
     rows: list[dict] = []
     streams0 = None
-    for mode in _quant_modes():
+    for mode in KV_QUANT_MODES:
         reqs = [Request(rid=i, prompt=list(p), max_new_tokens=max_new)
                 for i, p in enumerate(prompts)]
         eng = ContinuousBatchingEngine(cfg, params, batch=batch,
@@ -701,6 +694,7 @@ def quant_error_report(cfg, params, *, max_len: int = 64, page_size: int = 8,
     import jax.numpy as jnp
 
     from repro.models import lm
+    from repro.models.cache import KV_QUANT_MODES
 
     rng = np.random.default_rng(seed)
     batch = 2
@@ -727,7 +721,7 @@ def quant_error_report(cfg, params, *, max_len: int = 64, page_size: int = 8,
 
     ref_outs, ref_inputs = run("off")
     modes = {}
-    for mode in _quant_modes():
+    for mode in KV_QUANT_MODES:
         if mode == "off":
             continue
         outs, _ = run(mode, inputs=ref_inputs)

@@ -20,6 +20,9 @@ def main() -> None:
     args = ap.parse_args()
     runs = 2 if args.quick else args.runs
 
+    from repro.runtime import compile_cache
+    compile_cache.enable()
+
     from benchmarks import tables
     from benchmarks.common import run_suite
 

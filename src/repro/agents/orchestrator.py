@@ -179,6 +179,7 @@ def run_task(cfg: ModelConfig, params, task: TaskSpec, *, mode: str,
              page_size: int = 64, chunk_size: int = 32, replicas: int = 1,
              spec_decode: str = "off", spec_k: int = 4,
              kv_quant: str = "off", disaggregate: bool = False,
+             impl: Optional[str] = None,
              time_fn=time.perf_counter) -> RunResult:
     """``kv="paged"`` backs the agents with the paged KV cache.
 
@@ -188,7 +189,10 @@ def run_task(cfg: ModelConfig, params, task: TaskSpec, *, mode: str,
     prompt AND one decode token for every generating agent, in the same
     batched call — so an agent replaying a fresh prompt after an
     invalidation never stalls its neighbours.  ``"replay"`` is the paper's
-    token-by-token baseline (one decode step per prompt token)."""
+    token-by-token baseline (one decode step per prompt token).
+
+    ``impl`` picks the attention path of the mixed and verify steps
+    (``None``: the platform's, ``kernels.ops.default_impl``)."""
     assert mode in ("sequential", "parallel")
     assert merge in ("allgather", "pmax", "delta")
     assert kv in ("dense", "paged")
@@ -319,7 +323,7 @@ def run_task(cfg: ModelConfig, params, task: TaskSpec, *, mode: str,
 
     mixed_fn = None
     if chunked:
-        mixed_fn = jax.jit(engine_mod.make_mixed_step_fn(cfg))
+        mixed_fn = jax.jit(engine_mod.make_mixed_step_fn(cfg, impl=impl))
 
     # Speculative decoding through the mixed step: a host-side drafter
     # widens GEN rows from span 1 to 1+k, one verify call scores the whole
@@ -338,7 +342,7 @@ def run_task(cfg: ModelConfig, params, task: TaskSpec, *, mode: str,
     if spec_decode != "off":
         drafter = draft_mod.make_drafter(spec_decode)
         wclamp = max(chunk_size, 1 + spec_k)
-        verify_fn = jax.jit(engine_mod.make_verify_step_fn(cfg))
+        verify_fn = jax.jit(engine_mod.make_verify_step_fn(cfg, impl=impl))
 
         def _snap_fn(c, start, width):
             out = {"spans": cache_mod.snapshot_span(c, start, width)}
@@ -839,9 +843,13 @@ def main() -> None:
                          "on decode replicas and adopt it cross-replica")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.runtime import compile_cache
+    compile_cache.enable()
 
     cfg, params = make_sim_llm(args.seed)
-    r = run_task(cfg, params, TASKS[args.task], mode=args.mode,
+    # The sim-llm's 16-wide heads are narrower than the Pallas kernels'
+    # 128-lane tiles, so this CLI runs the jnp path on every platform.
+    r = run_task(cfg, params, TASKS[args.task], mode=args.mode, impl="ref",
                  n_agents=args.agents, seed=args.seed, merge=args.merge,
                  delta_capacity=args.delta_capacity, kv=args.kv,
                  prefill=args.prefill, page_size=args.page_size,

@@ -98,16 +98,6 @@ def tree_join_stacked(stacked: Any) -> Any:
 # Collective merges (use inside shard_map over ``axis_name``).
 # ---------------------------------------------------------------------------
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions (older jax ships it under
-    jax.experimental with ``check_rep`` instead of ``check_vma``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
 def allgather_merge(state: Any, axis_name: str) -> Any:
     """Paper-faithful: every replica observes every replica, folds locally."""
     gathered = jax.tree.map(

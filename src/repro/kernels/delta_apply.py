@@ -41,7 +41,7 @@ def _apply_kernel(idx_ref, dkey_ref, dpay_ref, key_ref, pay_ref,
         tgt = idx_ref[j]
         dk = dkey_ref[j]
         hit = (rows == tgt) & (dk > key)
-        drow = pl.load(dpay_ref, (pl.dslice(j, 1), slice(None)))     # [1, D]
+        drow = dpay_ref[pl.ds(j, 1), :]                              # [1, D]
         key = jnp.where(hit, dk, key)
         pay = jnp.where(hit[:, None], drow, pay)
         return key, pay

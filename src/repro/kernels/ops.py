@@ -35,12 +35,17 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def default_impl() -> str:
+    """Attention path the serving entry points run unless told otherwise:
+    the Pallas kernels on a TPU, the jnp oracles of ``kernels/ref.py``
+    elsewhere (where the kernels would only run in interpret mode)."""
+    return "pallas" if _on_tpu() else "ref"
+
+
 # Minimum sublane count per pool dtype (second-to-last dim of the TPU tile;
 # the lane dim is always 128).  f32 default 8; narrow dtypes pack more rows.
-_SUBLANE = {jnp.dtype(jnp.bfloat16): 16, jnp.dtype(jnp.int8): 32}
-_FP8 = getattr(jnp, "float8_e4m3fn", None)
-if _FP8 is not None:
-    _SUBLANE[jnp.dtype(_FP8)] = 32
+_SUBLANE = {jnp.dtype(jnp.bfloat16): 16, jnp.dtype(jnp.int8): 32,
+            jnp.dtype(jnp.float8_e4m3fn): 32}
 
 
 def _check_tileable(kernel: str, dtype, **dims) -> None:
@@ -201,7 +206,7 @@ def _quant_qmax(dtype) -> float:
     """Symmetric-quant max magnitude for a quantized pool dtype."""
     if jnp.dtype(dtype) == jnp.dtype(jnp.int8):
         return 127.0
-    if _FP8 is not None and jnp.dtype(dtype) == jnp.dtype(_FP8):
+    if jnp.dtype(dtype) == jnp.dtype(jnp.float8_e4m3fn):
         return 448.0            # e4m3 finite max
     raise ValueError(f"not a quantized pool dtype: {jnp.dtype(dtype).name}")
 
@@ -229,7 +234,8 @@ def paged_decode_attention_quant(q, k_pages, k_scales, v_pages, v_scales,
     on_tpu = _on_tpu()
     if on_tpu:
         _check_tileable("paged_decode_attention_quant", k_pages.dtype,
-                        page_size=(ps, None), head_dim=(d, 128))
+                        page_size=(ps, None), head_dim=(d, 128),
+                        scale_row=(ps, 128))
     return _pdec.paged_decode_attention_quant(
         q, k_pages, k_scales.astype(jnp.float32), v_pages,
         v_scales.astype(jnp.float32), block_tables.astype(jnp.int32),
@@ -303,7 +309,8 @@ def paged_chunk_attention_quant(q, k_pages, k_scales, v_pages, v_scales,
     on_tpu = _on_tpu()
     if on_tpu:
         _check_tileable("paged_chunk_attention_quant", k_pages.dtype,
-                        page_size=(ps, None), head_dim=(d, 128))
+                        page_size=(ps, None), head_dim=(d, 128),
+                        scale_row=(ps, 128))
     return _pchunk.paged_chunk_attention_quant(
         q, k_pages, k_scales.astype(jnp.float32), v_pages,
         v_scales.astype(jnp.float32), block_tables.astype(jnp.int32),
@@ -376,7 +383,8 @@ def paged_mla_chunk_quant(q_abs, q_rope, latent_pages, latent_scales,
     on_tpu = _on_tpu()
     if on_tpu:
         _check_tileable("paged_mla_chunk_quant", latent_pages.dtype,
-                        page_size=(ps, None), latent_width=(dp, 128))
+                        page_size=(ps, None), latent_width=(dp, 128),
+                        scale_row=(ps, 128))
     qc = jnp.concatenate([q_abs.astype(jnp.float32),
                           q_rope.astype(jnp.float32)], axis=-1)
     return _pchunk.paged_mla_chunk_quant(
@@ -454,7 +462,8 @@ def paged_mla_decode_quant(q_abs, q_rope, latent_pages, latent_scales,
     on_tpu = _on_tpu()
     if on_tpu:
         _check_tileable("paged_mla_decode_quant", latent_pages.dtype,
-                        page_size=(ps, None), latent_width=(dp, 128))
+                        page_size=(ps, None), latent_width=(dp, 128),
+                        scale_row=(ps, 128))
     qc = jnp.concatenate([q_abs.astype(jnp.float32),
                           q_rope.astype(jnp.float32)], axis=-1)
     return _pmla.paged_mla_decode_quant(

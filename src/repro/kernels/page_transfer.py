@@ -85,9 +85,9 @@ def page_transfer(src_pool: jax.Array, dst_pool: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,              # src_ids, dst_ids
         grid=(n,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((2,) + row, src_pool.dtype),     # staging double-buffer
             pltpu.SemaphoreType.DMA((2,)),

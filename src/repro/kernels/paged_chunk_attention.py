@@ -4,12 +4,13 @@ The token-budget mixed serve step composes, per batch row, a query *span* —
 1 token for rows that are decoding, up to C tokens for rows whose prompt is
 being admitted chunk by chunk.  These kernels are the hot path of that step:
 
-  * **fused multi-slot KV write** — the span's K/V rows are DMA'd into their
-    page slots (pages ``bt[b, (start+j)//ps]``, slots ``(start+j) % ps``,
-    j < span) *before* the attend, so intra-span causality falls out of the
-    ordinary block-table walk: by the time query j reads a page, every key
-    at a position ≤ start+j is already resident.  A span may straddle page
-    boundaries — each token targets its own slot, ``-1`` table entries drop;
+  * **fused multi-slot KV write** — the span's K/V rows are merged into
+    their page slots (pages ``bt[b, (start+j)//ps]``, slots
+    ``(start+j) % ps``, j < span) *before* the attend, one page block at a
+    time (read, merge in VMEM, write back), so intra-span causality falls
+    out of the ordinary block-table walk: by the time query j reads a page,
+    every key at a position ≤ start+j is already resident.  A span may
+    straddle page boundaries; ``-1`` table entries drop;
   * **block-table walk over the cached prefix** — double-buffered page DMA
     HBM→VMEM with split-K online softmax, exactly the decode kernel's
     schedule, but carrying [group·C] query rows instead of [group];
@@ -36,15 +37,35 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.paged_decode_attention import _quantize_rows
+from repro.kernels.paged_decode_attention import (_merge_rows, _merge_scales,
+                                                  _quantize_rows,
+                                                  _rewrite_page)
 
 NEG_INF = -1e30
 
 
+def _span_pages(bt_ref, b, start, span, ps, write_page):
+    """Call ``write_page(page, base)`` for every allocated page the span
+    ``start..start+span`` touches, where page row r holds span token
+    r + base.  ``-1`` table entries and slots past the table drop."""
+    maxp = bt_ref.shape[1]
+    first = start // ps
+    last = jnp.minimum((start + span - 1) // ps, maxp - 1)
+
+    def body(i, _):
+        page = bt_ref[b, i]
+
+        @pl.when(page >= 0)
+        def _():
+            write_page(page, i * ps - start)
+        return 0
+
+    jax.lax.fori_loop(first, jnp.where(span > 0, last + 1, first), body, 0)
+
+
 def _mha_kernel(bt_ref, start_ref, span_ref, q_ref, kn_ref, vn_ref,
-                kp_in, vp_in, o_ref, kp, vp, kbuf, vbuf, tokk, tokv,
-                ksem, vsem, wksem, wvsem, *, ps: int, c: int, scale: float,
-                window: int | None):
+                kp_in, vp_in, o_ref, kp, vp, kbuf, vbuf, ksem, vsem,
+                *, ps: int, c: int, scale: float, window: int | None):
     b = pl.program_id(0)
     h = pl.program_id(1)
     start = start_ref[b]
@@ -54,49 +75,18 @@ def _mha_kernel(bt_ref, start_ref, span_ref, q_ref, kn_ref, vn_ref,
     n_pages = jnp.minimum((jnp.maximum(kv_len, 1) + ps - 1) // ps, maxp)
 
     # -- fused multi-slot write: span tokens -> their page slots ------------
-    # All valid copies start first (distinct slots, so order is free), then
-    # all are waited: the walk below reads the pages the span just wrote.
-    tokk[:, 0, 0, :] = kn_ref[0, 0]
-    tokv[:, 0, 0, :] = vn_ref[0, 0]
+    # Every touched page is rewritten (and waited) before the walk, so the
+    # walk below reads the pages the span just wrote.
+    def write_page(page, base):
+        for pool, buf, sem, new in ((kp, kbuf, ksem, kn_ref),
+                                    (vp, vbuf, vsem, vn_ref)):
+            _rewrite_page(
+                pool.at[pl.ds(page, 1), pl.ds(h, 1)],
+                buf.at[pl.ds(0, 1)], sem.at[0],
+                lambda old, new=new: _merge_rows(
+                    old[0, 0], new[0, 0], base, span)[None, None])
 
-    def _start_write(j, _):
-        pos = start + j
-        page_raw = bt_ref[b, jnp.minimum(pos // ps, maxp - 1)]
-        page_w = jnp.maximum(page_raw, 0)
-        slot_w = pos % ps
-
-        @pl.when((j < span) & (page_raw >= 0) & (pos < maxp * ps))
-        def _():
-            pltpu.make_async_copy(
-                tokk.at[pl.ds(j, 1)],
-                kp.at[pl.ds(page_w, 1), pl.ds(h, 1), pl.ds(slot_w, 1), :],
-                wksem.at[j]).start()
-            pltpu.make_async_copy(
-                tokv.at[pl.ds(j, 1)],
-                vp.at[pl.ds(page_w, 1), pl.ds(h, 1), pl.ds(slot_w, 1), :],
-                wvsem.at[j]).start()
-        return 0
-
-    def _wait_write(j, _):
-        pos = start + j
-        page_raw = bt_ref[b, jnp.minimum(pos // ps, maxp - 1)]
-        page_w = jnp.maximum(page_raw, 0)
-        slot_w = pos % ps
-
-        @pl.when((j < span) & (page_raw >= 0) & (pos < maxp * ps))
-        def _():
-            pltpu.make_async_copy(
-                tokk.at[pl.ds(j, 1)],
-                kp.at[pl.ds(page_w, 1), pl.ds(h, 1), pl.ds(slot_w, 1), :],
-                wksem.at[j]).wait()
-            pltpu.make_async_copy(
-                tokv.at[pl.ds(j, 1)],
-                vp.at[pl.ds(page_w, 1), pl.ds(h, 1), pl.ds(slot_w, 1), :],
-                wvsem.at[j]).wait()
-        return 0
-
-    jax.lax.fori_loop(0, c, _start_write, 0)
-    jax.lax.fori_loop(0, c, _wait_write, 0)
+    _span_pages(bt_ref, b, start, span, ps, write_page)
 
     # -- split-K online softmax over the row's live pages -------------------
     def page_dma(pool, buf, sem, i, slot):
@@ -173,7 +163,7 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
 
     q_spec = pl.BlockSpec((1, group, c, d), lambda i, j, *_: (i, j, 0, 0))
     tok_spec = pl.BlockSpec((1, 1, c, d), lambda i, j, *_: (i, j, 0, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,              # block_tables, start, span
@@ -183,12 +173,8 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
         scratch_shapes=[
             pltpu.VMEM((2, 1, ps, d), k_pages.dtype),   # k page double-buffer
             pltpu.VMEM((2, 1, ps, d), v_pages.dtype),
-            pltpu.VMEM((c, 1, 1, d), k_pages.dtype),    # staged span writes
-            pltpu.VMEM((c, 1, 1, d), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((c,)),
-            pltpu.SemaphoreType.DMA((c,)),
         ],
     )
     kernel = functools.partial(_mha_kernel, ps=ps, c=c, scale=scale,
@@ -209,8 +195,7 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
 
 def _mha_kernel_quant(bt_ref, start_ref, span_ref, q_ref, kn_ref, vn_ref,
                       kp_in, vp_in, ks_in, vs_in, o_ref, kp, vp, ks, vs,
-                      kbuf, vbuf, ksbuf, vsbuf, tokk, tokv, tokks, tokvs,
-                      ksem, vsem, kssem, vssem, wksem, wvsem, wkssem, wvssem,
+                      kbuf, vbuf, ksbuf, vsbuf, ksem, vsem, kssem, vssem,
                       *, ps: int, c: int, scale: float, window: int | None,
                       qmax: float, qdtype):
     """Quantized twin of ``_mha_kernel``: the span's K/V rows quantize
@@ -225,55 +210,27 @@ def _mha_kernel_quant(bt_ref, start_ref, span_ref, q_ref, kn_ref, vn_ref,
     maxp = bt_ref.shape[1]
     n_pages = jnp.minimum((jnp.maximum(kv_len, 1) + ps - 1) // ps, maxp)
 
-    # -- fused multi-slot write: quantize span rows, stage values + scales --
+    # -- fused multi-slot write: quantize span rows, merge values + scales --
     kq, kscales = _quantize_rows(kn_ref[0, 0].astype(jnp.float32),
-                                 qdtype, qmax)               # [C, D], [C]
+                                 qdtype, qmax)               # [C, D], [C, 1]
     vq, vscales = _quantize_rows(vn_ref[0, 0].astype(jnp.float32),
                                  qdtype, qmax)
-    tokk[:, 0, 0, :] = kq
-    tokv[:, 0, 0, :] = vq
-    tokks[:, 0, 0] = kscales
-    tokvs[:, 0, 0] = vscales
 
-    def _copies(j):
-        pos = start + j
-        page_raw = bt_ref[b, jnp.minimum(pos // ps, maxp - 1)]
-        page_w = jnp.maximum(page_raw, 0)
-        slot_w = pos % ps
-        dst = (pl.ds(page_w, 1), pl.ds(h, 1), pl.ds(slot_w, 1))
-        return page_raw, pos, (
-            pltpu.make_async_copy(
-                tokk.at[pl.ds(j, 1)], kp.at[dst + (slice(None),)],
-                wksem.at[j]),
-            pltpu.make_async_copy(
-                tokv.at[pl.ds(j, 1)], vp.at[dst + (slice(None),)],
-                wvsem.at[j]),
-            pltpu.make_async_copy(
-                tokks.at[pl.ds(j, 1)], ks.at[dst], wkssem.at[j]),
-            pltpu.make_async_copy(
-                tokvs.at[pl.ds(j, 1)], vs.at[dst], wvssem.at[j]),
-        )
+    def write_page(page, base):
+        dst = (pl.ds(page, 1), pl.ds(h, 1))
+        for pool, buf, sem, new in ((kp, kbuf, ksem, kq), (vp, vbuf, vsem, vq)):
+            _rewrite_page(
+                pool.at[dst], buf.at[pl.ds(0, 1)], sem.at[0],
+                lambda old, new=new: _merge_rows(
+                    old[0, 0], new, base, span)[None, None])
+        for pool, buf, sem, new in ((ks, ksbuf, kssem, kscales),
+                                    (vs, vsbuf, vssem, vscales)):
+            _rewrite_page(
+                pool.at[dst], buf.at[pl.ds(0, 1)], sem.at[0],
+                lambda old, new=new: _merge_scales(
+                    old[0], new, base, span)[None])
 
-    def _start_write(j, _):
-        page_raw, pos, copies = _copies(j)
-
-        @pl.when((j < span) & (page_raw >= 0) & (pos < maxp * ps))
-        def _():
-            for cp in copies:
-                cp.start()
-        return 0
-
-    def _wait_write(j, _):
-        page_raw, pos, copies = _copies(j)
-
-        @pl.when((j < span) & (page_raw >= 0) & (pos < maxp * ps))
-        def _():
-            for cp in copies:
-                cp.wait()
-        return 0
-
-    jax.lax.fori_loop(0, c, _start_write, 0)
-    jax.lax.fori_loop(0, c, _wait_write, 0)
+    _span_pages(bt_ref, b, start, span, ps, write_page)
 
     # -- split-K online softmax, dequant fused into the walk ----------------
     def page_dma(pool, buf, sem, i, slot):
@@ -356,7 +313,7 @@ def paged_chunk_attention_quant(q: jax.Array, k_pages: jax.Array,
 
     q_spec = pl.BlockSpec((1, group, c, d), lambda i, j, *_: (i, j, 0, 0))
     tok_spec = pl.BlockSpec((1, 1, c, d), lambda i, j, *_: (i, j, 0, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,              # block_tables, start, span
@@ -369,18 +326,10 @@ def paged_chunk_attention_quant(q: jax.Array, k_pages: jax.Array,
             pltpu.VMEM((2, 1, ps, d), v_pages.dtype),
             pltpu.VMEM((2, 1, ps), jnp.float32),        # page scale rows
             pltpu.VMEM((2, 1, ps), jnp.float32),
-            pltpu.VMEM((c, 1, 1, d), k_pages.dtype),    # staged span writes
-            pltpu.VMEM((c, 1, 1, d), v_pages.dtype),
-            pltpu.VMEM((c, 1, 1), jnp.float32),         # staged span scales
-            pltpu.VMEM((c, 1, 1), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((c,)),
-            pltpu.SemaphoreType.DMA((c,)),
-            pltpu.SemaphoreType.DMA((c,)),
-            pltpu.SemaphoreType.DMA((c,)),
         ],
     )
     kernel = functools.partial(_mha_kernel_quant, ps=ps, c=c, scale=scale,
@@ -404,7 +353,7 @@ def paged_chunk_attention_quant(q: jax.Array, k_pages: jax.Array,
 
 
 def _mla_kernel(bt_ref, start_ref, span_ref, q_ref, ln_ref, lp_in,
-                o_ref, lp, buf, tok, dsem, wsem, *, ps: int, c: int,
+                o_ref, lp, buf, dsem, *, ps: int, c: int,
                 r: int, width: int, scale: float):
     b = pl.program_id(0)
     start = start_ref[b]
@@ -414,38 +363,12 @@ def _mla_kernel(bt_ref, start_ref, span_ref, q_ref, ln_ref, lp_in,
     n_pages = jnp.minimum((jnp.maximum(kv_len, 1) + ps - 1) // ps, maxp)
 
     # -- fused multi-slot write: span latent rows -> their page slots -------
-    tok[:, 0, :] = ln_ref[0]
+    def write_page(page, base):
+        _rewrite_page(lp.at[pl.ds(page, 1)], buf.at[pl.ds(0, 1)], dsem.at[0],
+                      lambda old: _merge_rows(old[0], ln_ref[0], base,
+                                              span)[None])
 
-    def _start_write(j, _):
-        pos = start + j
-        page_raw = bt_ref[b, jnp.minimum(pos // ps, maxp - 1)]
-        page_w = jnp.maximum(page_raw, 0)
-        slot_w = pos % ps
-
-        @pl.when((j < span) & (page_raw >= 0) & (pos < maxp * ps))
-        def _():
-            pltpu.make_async_copy(
-                tok.at[pl.ds(j, 1)],
-                lp.at[pl.ds(page_w, 1), pl.ds(slot_w, 1), :],
-                wsem.at[j]).start()
-        return 0
-
-    def _wait_write(j, _):
-        pos = start + j
-        page_raw = bt_ref[b, jnp.minimum(pos // ps, maxp - 1)]
-        page_w = jnp.maximum(page_raw, 0)
-        slot_w = pos % ps
-
-        @pl.when((j < span) & (page_raw >= 0) & (pos < maxp * ps))
-        def _():
-            pltpu.make_async_copy(
-                tok.at[pl.ds(j, 1)],
-                lp.at[pl.ds(page_w, 1), pl.ds(slot_w, 1), :],
-                wsem.at[j]).wait()
-        return 0
-
-    jax.lax.fori_loop(0, c, _start_write, 0)
-    jax.lax.fori_loop(0, c, _wait_write, 0)
+    _span_pages(bt_ref, b, start, span, ps, write_page)
 
     # -- split-K online softmax over the row's live pages -------------------
     def page_dma(i, slot):
@@ -512,7 +435,7 @@ def paged_mla_chunk(q: jax.Array, latent_pages: jax.Array,
     q_spec = pl.BlockSpec((1, h, c, width), lambda i, *_: (i, 0, 0, 0))
     tok_spec = pl.BlockSpec((1, c, dp), lambda i, *_: (i, 0, 0))
     out_spec = pl.BlockSpec((1, h, c, r), lambda i, *_: (i, 0, 0, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,              # block_tables, start, span
@@ -521,9 +444,7 @@ def paged_mla_chunk(q: jax.Array, latent_pages: jax.Array,
         out_specs=[out_spec, any_spec],
         scratch_shapes=[
             pltpu.VMEM((2, ps, dp), latent_pages.dtype),     # double buffer
-            pltpu.VMEM((c, 1, dp), latent_pages.dtype),      # staged writes
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((c,)),
         ],
     )
     kernel = functools.partial(_mla_kernel, ps=ps, c=c, r=r, width=width,
@@ -542,8 +463,8 @@ def paged_mla_chunk(q: jax.Array, latent_pages: jax.Array,
 
 
 def _mla_kernel_quant(bt_ref, start_ref, span_ref, q_ref, ln_ref, lp_in,
-                      ls_in, o_ref, lp, ls, buf, sbuf, tok, toks,
-                      dsem, ssem, wsem, wssem, *, ps: int, c: int,
+                      ls_in, o_ref, lp, ls, buf, sbuf, dsem, ssem,
+                      *, ps: int, c: int,
                       r: int, width: int, scale: float, qmax: float, qdtype):
     """Quantized twin of ``_mla_kernel``: span latent rows quantize
     in-kernel (one scale per token), write fused with their scales, and the
@@ -555,48 +476,18 @@ def _mla_kernel_quant(bt_ref, start_ref, span_ref, q_ref, ln_ref, lp_in,
     maxp = bt_ref.shape[1]
     n_pages = jnp.minimum((jnp.maximum(kv_len, 1) + ps - 1) // ps, maxp)
 
-    # -- fused multi-slot write: quantize span rows, stage values + scales --
+    # -- fused multi-slot write: quantize span rows, merge values + scales --
     lq, lscales = _quantize_rows(ln_ref[0].astype(jnp.float32),
-                                 qdtype, qmax)               # [C, Dp], [C]
-    tok[:, 0, :] = lq
-    toks[:, 0] = lscales
+                                 qdtype, qmax)               # [C, Dp], [C, 1]
 
-    def _copies(j):
-        pos = start + j
-        page_raw = bt_ref[b, jnp.minimum(pos // ps, maxp - 1)]
-        page_w = jnp.maximum(page_raw, 0)
-        slot_w = pos % ps
-        return page_raw, pos, (
-            pltpu.make_async_copy(
-                tok.at[pl.ds(j, 1)],
-                lp.at[pl.ds(page_w, 1), pl.ds(slot_w, 1), :],
-                wsem.at[j]),
-            pltpu.make_async_copy(
-                toks.at[pl.ds(j, 1)],
-                ls.at[pl.ds(page_w, 1), pl.ds(slot_w, 1)],
-                wssem.at[j]),
-        )
+    def write_page(page, base):
+        _rewrite_page(lp.at[pl.ds(page, 1)], buf.at[pl.ds(0, 1)], dsem.at[0],
+                      lambda old: _merge_rows(old[0], lq, base, span)[None])
+        _rewrite_page(ls.at[pl.ds(page, 1)], sbuf.at[pl.ds(0, 1)],
+                      ssem.at[0],
+                      lambda old: _merge_scales(old, lscales, base, span))
 
-    def _start_write(j, _):
-        page_raw, pos, copies = _copies(j)
-
-        @pl.when((j < span) & (page_raw >= 0) & (pos < maxp * ps))
-        def _():
-            for cp in copies:
-                cp.start()
-        return 0
-
-    def _wait_write(j, _):
-        page_raw, pos, copies = _copies(j)
-
-        @pl.when((j < span) & (page_raw >= 0) & (pos < maxp * ps))
-        def _():
-            for cp in copies:
-                cp.wait()
-        return 0
-
-    jax.lax.fori_loop(0, c, _start_write, 0)
-    jax.lax.fori_loop(0, c, _wait_write, 0)
+    _span_pages(bt_ref, b, start, span, ps, write_page)
 
     # -- split-K online softmax, dequant fused into the walk ----------------
     def page_dma(i, slot):
@@ -671,7 +562,7 @@ def paged_mla_chunk_quant(q: jax.Array, latent_pages: jax.Array,
     q_spec = pl.BlockSpec((1, h, c, width), lambda i, *_: (i, 0, 0, 0))
     tok_spec = pl.BlockSpec((1, c, dp), lambda i, *_: (i, 0, 0))
     out_spec = pl.BlockSpec((1, h, c, r), lambda i, *_: (i, 0, 0, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,              # block_tables, start, span
@@ -681,12 +572,8 @@ def paged_mla_chunk_quant(q: jax.Array, latent_pages: jax.Array,
         scratch_shapes=[
             pltpu.VMEM((2, ps, dp), latent_pages.dtype),     # double buffer
             pltpu.VMEM((2, ps), jnp.float32),                # page scales
-            pltpu.VMEM((c, 1, dp), latent_pages.dtype),      # staged writes
-            pltpu.VMEM((c, 1), jnp.float32),                 # staged scales
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((c,)),
-            pltpu.SemaphoreType.DMA((c,)),
         ],
     )
     kernel = functools.partial(_mla_kernel_quant, ps=ps, c=c, r=r,

@@ -9,8 +9,9 @@ what the latent page pool stores: ``[P, page_size, Dp]`` where the first
 padded to the TPU lane width at init (never per step).
 
 Per step this kernel:
-  * DMAs the token's latent row into page ``bt[b, pos//ps]`` slot ``pos%ps``
-    (O(Dp) bytes — the dense path's one-hot rewrite of [B, S, r] vanishes);
+  * merges the token's latent row into page ``bt[b, pos//ps]`` slot
+    ``pos%ps`` (one page block read and written back — the dense path's
+    one-hot rewrite of [B, S, r] vanishes);
   * walks the row's live pages via scalar-prefetched block tables,
     double-buffering each page HBM→VMEM, with split-K online softmax;
   * accumulates the latent context from the ckv half of each page.
@@ -28,33 +29,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.paged_decode_attention import _quantize_rows
+from repro.kernels.paged_decode_attention import (_merge_rows, _merge_scales,
+                                                  _quantize_rows,
+                                                  _rewrite_page)
 
 NEG_INF = -1e30
 
 
 def _kernel(bt_ref, pos_ref, q_ref, ln_ref, lp_in, o_ref, lp,
-            buf, tok, dsem, wsem, *, ps: int, r: int, width: int,
-            scale: float):
+            buf, dsem, *, ps: int, r: int, width: int, scale: float):
     b = pl.program_id(0)
     pos = pos_ref[b]
     kv_len = pos + 1
     n_pages = (kv_len + ps - 1) // ps
 
-    # -- fused write: current latent row -> one page slot -------------------
+    # -- fused write: current latent row -> its page slot -------------------
     page_raw = bt_ref[b, pos // ps]
-    page_w = jnp.maximum(page_raw, 0)
     slot_w = pos % ps
-    tok[0, 0, :] = ln_ref[0]
 
+    # The written page is also read below (self-attention of the new token);
+    # _rewrite_page waits, so the walk sees the merged page.
     @pl.when(page_raw >= 0)
     def _write():
-        w = pltpu.make_async_copy(
-            tok, lp.at[pl.ds(page_w, 1), pl.ds(slot_w, 1), :], wsem)
-        w.start()
-        # The written page is also read below (self-attention of the new
-        # token) — the copy must land before the walk reaches it.
-        w.wait()
+        _rewrite_page(lp.at[pl.ds(page_raw, 1)], buf.at[pl.ds(0, 1)],
+                      dsem.at[0],
+                      lambda old: _merge_rows(old[0], ln_ref[0], -slot_w,
+                                              1)[None])
 
     # -- split-K online softmax over the row's live pages -------------------
     def page_dma(i, slot):
@@ -116,9 +116,10 @@ def paged_mla_decode(q: jax.Array, latent_pages: jax.Array,
     grid = (b,)
 
     q_spec = pl.BlockSpec((1, h, width), lambda i, *_: (i, 0, 0))
-    tok_spec = pl.BlockSpec((1, dp), lambda i, *_: (i, 0))
+    # [B, 1, Dp]: a block's last two dims must be whole array dims.
+    tok_spec = pl.BlockSpec((1, 1, dp), lambda i, *_: (i, 0, 0))
     out_spec = pl.BlockSpec((1, h, r), lambda i, *_: (i, 0, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,              # block_tables, pos
@@ -127,9 +128,7 @@ def paged_mla_decode(q: jax.Array, latent_pages: jax.Array,
         out_specs=[out_spec, any_spec],
         scratch_shapes=[
             pltpu.VMEM((2, ps, dp), latent_pages.dtype),     # double buffer
-            pltpu.VMEM((1, 1, dp), latent_pages.dtype),      # staged write
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA(()),
         ],
     )
     kernel = functools.partial(_kernel, ps=ps, r=r, width=width, scale=scale)
@@ -143,11 +142,11 @@ def paged_mla_decode(q: jax.Array, latent_pages: jax.Array,
         # Input indices count the scalar-prefetch operands (0, 1).
         input_output_aliases={4: 1},
         interpret=interpret,
-    )(block_tables, pos, q, latent_new, latent_pages)
+    )(block_tables, pos, q, latent_new.reshape(b, 1, dp), latent_pages)
 
 
 def _kernel_quant(bt_ref, pos_ref, q_ref, ln_ref, lp_in, ls_in, o_ref,
-                  lp, ls, buf, sbuf, tok, toks, dsem, ssem, wsem,
+                  lp, ls, buf, sbuf, dsem, ssem,
                   *, ps: int, r: int, width: int, scale: float,
                   qmax: float, qdtype):
     """Quantized twin of ``_kernel``: latent pool int8/fp8 + per-row f32
@@ -159,24 +158,19 @@ def _kernel_quant(bt_ref, pos_ref, q_ref, ln_ref, lp_in, ls_in, o_ref,
     kv_len = pos + 1
     n_pages = (kv_len + ps - 1) // ps
 
-    # -- fused write: quantize the latent row, stage value + scale ----------
+    # -- fused write: quantize the latent row, merge value + scale ----------
     page_raw = bt_ref[b, pos // ps]
-    page_w = jnp.maximum(page_raw, 0)
     slot_w = pos % ps
     lq, lscale = _quantize_rows(ln_ref[0].astype(jnp.float32), qdtype, qmax)
-    tok[0, 0, :] = lq
-    toks[0, 0] = lscale
 
     @pl.when(page_raw >= 0)
     def _write():
-        w = pltpu.make_async_copy(
-            tok, lp.at[pl.ds(page_w, 1), pl.ds(slot_w, 1), :], wsem.at[0])
-        wsc = pltpu.make_async_copy(
-            toks, ls.at[pl.ds(page_w, 1), pl.ds(slot_w, 1)], wsem.at[1])
-        w.start()
-        wsc.start()
-        w.wait()
-        wsc.wait()
+        _rewrite_page(lp.at[pl.ds(page_raw, 1)], buf.at[pl.ds(0, 1)],
+                      dsem.at[0],
+                      lambda old: _merge_rows(old[0], lq, -slot_w, 1)[None])
+        _rewrite_page(ls.at[pl.ds(page_raw, 1)], sbuf.at[pl.ds(0, 1)],
+                      ssem.at[0],
+                      lambda old: _merge_scales(old, lscale, -slot_w, 1))
 
     # -- split-K online softmax, dequant fused into the walk ----------------
     def page_dma(i, slot):
@@ -245,9 +239,10 @@ def paged_mla_decode_quant(q: jax.Array, latent_pages: jax.Array,
     grid = (b,)
 
     q_spec = pl.BlockSpec((1, h, width), lambda i, *_: (i, 0, 0))
-    tok_spec = pl.BlockSpec((1, dp), lambda i, *_: (i, 0))
+    # [B, 1, Dp]: a block's last two dims must be whole array dims.
+    tok_spec = pl.BlockSpec((1, 1, dp), lambda i, *_: (i, 0, 0))
     out_spec = pl.BlockSpec((1, h, r), lambda i, *_: (i, 0, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,              # block_tables, pos
@@ -257,9 +252,6 @@ def paged_mla_decode_quant(q: jax.Array, latent_pages: jax.Array,
         scratch_shapes=[
             pltpu.VMEM((2, ps, dp), latent_pages.dtype),     # double buffer
             pltpu.VMEM((2, ps), jnp.float32),                # page scales
-            pltpu.VMEM((1, 1, dp), latent_pages.dtype),      # staged write
-            pltpu.VMEM((1, 1), jnp.float32),                 # staged scale
-            pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
@@ -278,4 +270,5 @@ def paged_mla_decode_quant(q: jax.Array, latent_pages: jax.Array,
         # Input indices count the scalar-prefetch operands (0, 1).
         input_output_aliases={4: 1, 5: 2},
         interpret=interpret,
-    )(block_tables, pos, q, latent_new, latent_pages, latent_scales)
+    )(block_tables, pos, q, latent_new.reshape(b, 1, dp), latent_pages,
+      latent_scales)

@@ -70,14 +70,13 @@ def delta_apply(key: jax.Array, payload: jax.Array, d_idx: jax.Array,
 
 INT8_QMAX = 127.0
 FP8_QMAX = 448.0                    # e4m3 finite max
-_FP8 = getattr(jnp, "float8_e4m3fn", None)
 
 
 def quant_qmax(dtype) -> float:
     """Symmetric representable max the row scale maps abs-max onto."""
     if dtype == jnp.int8:
         return INT8_QMAX
-    if _FP8 is not None and dtype == _FP8:
+    if dtype == jnp.float8_e4m3fn:
         return FP8_QMAX
     raise ValueError(f"unsupported quantized pool dtype {dtype}")
 

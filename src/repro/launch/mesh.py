@@ -9,17 +9,24 @@ state.  Production target: TPU v5e pods, 256 chips each.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
+    # Auto axes: the sharding rules are annotations XLA propagates, not
+    # explicit per-op sharding types (jax.make_mesh defaults to Explicit).
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2) -> jax.sharding.Mesh:
     """Small host-device mesh for CPU integration tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 def dp_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

@@ -19,6 +19,7 @@ import repro.configs as configs
 from repro.agents.orchestrator import run_task
 from repro.agents.tasks import TASKS
 from repro.models import lm
+from repro.runtime import compile_cache
 
 
 def main() -> None:
@@ -30,9 +31,13 @@ def main() -> None:
     ap.add_argument("--agents", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--d-model", type=int, default=64,
-                    help="reduced model width (CPU)")
+                    help="width of the reduced model this CLI drives (a "
+                         "CPU-sized loop check, not a serving "
+                         "configuration; chip_smoke.py serves the "
+                         "published widths)")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = configs.reduced(configs.get(args.arch), d_model=args.d_model,
                           vocab=512)

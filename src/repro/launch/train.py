@@ -15,6 +15,7 @@ import tempfile
 
 import repro.configs as configs
 from repro.data.pipeline import DataConfig
+from repro.runtime import compile_cache
 from repro.runtime.elastic import Worker, make_queue, make_shared_fold_sync
 from repro.training.optimizer import AdamW
 from repro.training.trainer import Trainer, TrainerConfig
@@ -35,6 +36,7 @@ def main() -> None:
                     help="use the full arch config (needs real hardware)")
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = configs.get(args.arch)
     if not args.full_config:

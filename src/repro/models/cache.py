@@ -52,10 +52,9 @@ ROLE_SCALE = "scale"
 ROLE_TABLE = "table"
 ROLE_STATE = "state"
 
-# fp8 support is gated on the dtype existing in the installed jax; int8 is
-# always available.  Quantization maxima are the symmetric representable
-# ranges the kernels/oracles scale into.
-FP8_DTYPE = getattr(jnp, "float8_e4m3fn", None)
+# Quantization maxima are the symmetric representable ranges the
+# kernels/oracles scale into.
+FP8_DTYPE = jnp.float8_e4m3fn
 INT8_QMAX = 127.0
 FP8_QMAX = 448.0                 # e4m3 finite max
 
@@ -214,18 +213,12 @@ def _paged_mla_q8(kind, cfg, batch, max_len, dtype, **kw) -> CacheSpec:
 
 @register_layout("paged_mha_fp8")
 def _paged_mha_fp8(kind, cfg, batch, max_len, dtype, **kw) -> CacheSpec:
-    if FP8_DTYPE is None:
-        raise ValueError("kv_quant='fp8' needs jnp.float8_e4m3fn, which "
-                         "this jax build lacks — use kv_quant='int8'")
     return _quantized("paged_mha", "paged_mha_fp8", FP8_DTYPE, kind, cfg,
                       batch, max_len, dtype, **kw)
 
 
 @register_layout("paged_mla_fp8")
 def _paged_mla_fp8(kind, cfg, batch, max_len, dtype, **kw) -> CacheSpec:
-    if FP8_DTYPE is None:
-        raise ValueError("kv_quant='fp8' needs jnp.float8_e4m3fn, which "
-                         "this jax build lacks — use kv_quant='int8'")
     return _quantized("paged_mla", "paged_mla_fp8", FP8_DTYPE, kind, cfg,
                       batch, max_len, dtype, **kw)
 
@@ -358,7 +351,7 @@ def layout_of(layer_cache: dict) -> str | None:
     if not isinstance(layer_cache, dict):
         return None
     name = _LEAFSETS.get(frozenset(layer_cache.keys()))
-    if name in ("paged_mha_q8", "paged_mla_q8") and FP8_DTYPE is not None:
+    if name in ("paged_mha_q8", "paged_mla_q8"):
         pool = layer_cache["k_pages" if "k_pages" in layer_cache
                            else "latent_pages"]
         if pool.dtype == FP8_DTYPE:

@@ -22,6 +22,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import delta as delta_mod
 from repro.core import doc as doc_mod
 from repro.core import gset, merge as merge_mod
+from repro.kernels import ops
 from repro.models import lm
 from repro.models.config import ModelConfig
 
@@ -35,6 +36,10 @@ def sample_token(logits: jax.Array, rng: jax.Array,
     return jax.random.categorical(rng, logits / temperature).astype(jnp.int32)
 
 
+# The dense decode and prefill constructors keep the jnp path by default on
+# every platform: kernels/decode_attention.py (the dense-cache decode
+# kernel) does not compile for v5e yet.  The served paged path goes through
+# make_mixed_step_fn / make_verify_step_fn, which take the platform default.
 def make_serve_step(cfg: ModelConfig, *, impl: str = "ref",
                     temperature: float = 0.0):
     """(params, cache, token[B], pos[B], rng) -> (next_token, cache, pos+1)."""
@@ -96,7 +101,7 @@ def bucket_len(n: int, buckets=PROMPT_BUCKETS, max_len: Optional[int] = None
 # Token-budget mixed step (chunked prefill fused with decode)
 # ---------------------------------------------------------------------------
 
-def make_mixed_step_fn(cfg: ModelConfig, *, impl: str = "ref",
+def make_mixed_step_fn(cfg: ModelConfig, *, impl: Optional[str] = None,
                        temperature: float = 0.0):
     """(params, cache, tokens [B, C], start [B], span [B], rng)
     -> (next_token [B], cache).
@@ -106,6 +111,7 @@ def make_mixed_step_fn(cfg: ModelConfig, *, impl: str = "ref",
     stalls decode.  ``next_token`` is sampled from each row's last valid
     span position (garbage for span-0 rows; callers ignore it).
     """
+    impl = impl or ops.default_impl()
     def mixed_step(params, cache, tokens, start, span, rng):
         logits, cache = lm.mixed_step(params, cfg, tokens, cache, start,
                                       span, impl=impl)
@@ -115,7 +121,7 @@ def make_mixed_step_fn(cfg: ModelConfig, *, impl: str = "ref",
     return mixed_step
 
 
-def make_verify_step_fn(cfg: ModelConfig, *, impl: str = "ref"):
+def make_verify_step_fn(cfg: ModelConfig, *, impl: Optional[str] = None):
     """(params, cache, tokens [B, C], start [B], span [B])
     -> (preds [B, C], accepted [B], cache).
 
@@ -126,6 +132,7 @@ def make_verify_step_fn(cfg: ModelConfig, *, impl: str = "ref"):
     after span position j — non-drafting rows decode normally by reading
     ``preds[b, span[b]-1]``, so one compiled fn serves every lane.
     """
+    impl = impl or ops.default_impl()
     def verify_step(params, cache, tokens, start, span):
         return lm.verify_step(params, cfg, tokens, cache, start, span,
                               impl=impl)
@@ -212,9 +219,8 @@ def make_coord_merge(mesh: Mesh, dp_axes: tuple[str, ...],
     def merge_fn(coord_stacked):
         specs = jax.tree.map(
             lambda x: P(dp_axes, *([None] * (x.ndim - 1))), coord_stacked)
-        return merge_mod.shard_map(local, mesh=mesh, in_specs=(specs,),
-                                   out_specs=specs,
-                                   check_vma=False)(coord_stacked)
+        return jax.shard_map(local, mesh=mesh, in_specs=(specs,),
+                             out_specs=specs, check_vma=False)(coord_stacked)
 
     return merge_fn
 
@@ -279,6 +285,9 @@ def make_fused_serve_step(cfg: ModelConfig, mesh: Mesh,
 
     ``temperature > 0`` samples instead of argmax-decoding; pass an rng key
     as the trailing ``rng`` argument (split per step by the caller).
+
+    ``impl`` stays ``"ref"`` on every platform: the step is one GSPMD
+    program over the mesh, and XLA cannot partition a Mosaic kernel.
     """
     merge_fn = make_coord_merge(mesh, dp_axes, merge_strategy,
                                 delta_capacity=delta_capacity)
@@ -298,9 +307,9 @@ def make_fused_serve_step(cfg: ModelConfig, mesh: Mesh,
         specs = jax.tree.map(
             lambda x: P(dp_axes, *([None] * (x.ndim - 1))), coord_stacked)
         bspec = P(dp_axes)
-        return merge_mod.shard_map(local, mesh=mesh,
-                                   in_specs=(specs, bspec, bspec, bspec),
-                                   out_specs=specs, check_vma=False)(
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(specs, bspec, bspec, bspec),
+                             out_specs=specs, check_vma=False)(
             coord_stacked, token, slots, active)
 
     def serve_step(params, cache, token, pos, slots, active, coord, step,

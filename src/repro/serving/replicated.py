@@ -327,25 +327,29 @@ class ReplicatedPageStore:
 
     def state(self) -> dict:
         """The registered-CRDT pytree this replica's state IS (the thing the
-        delta engine extracts from / applies into / joins)."""
+        delta engine extracts from / applies into / joins).
+
+        The leaves are copies: on CPU ``jnp.asarray`` may alias the host
+        arrays, which the local mutators then change in place while an
+        asynchronously dispatched extract still reads them."""
         import jax.numpy as jnp
         return {
-            "ref": counter_mod.PNCounter(inc=jnp.asarray(self.inc),
-                                         dec=jnp.asarray(self.dec)),
+            "ref": counter_mod.PNCounter(inc=jnp.array(self.inc),
+                                         dec=jnp.array(self.dec)),
             "lease": lww.LWWBank(
-                clock=jnp.asarray(self.lease_clock),
-                client=jnp.asarray(self.lease_client),
-                payload={"owner": jnp.asarray(self.lease_owner),
-                         "seq": jnp.asarray(self.lease_seq)}),
+                clock=jnp.array(self.lease_clock),
+                client=jnp.array(self.lease_client),
+                payload={"owner": jnp.array(self.lease_owner),
+                         "seq": jnp.array(self.lease_seq)}),
             "prefix": lww.LWWBank(
-                clock=jnp.asarray(self.pfx_clock),
-                client=jnp.asarray(self.pfx_client),
-                payload={k: jnp.asarray(v) for k, v in self.pfx.items()}),
-            "hb": gset.GCounter(jnp.asarray(self.hb)),
-            "retire": gset.GSet(jnp.asarray(self.retire)),
+                clock=jnp.array(self.pfx_clock),
+                client=jnp.array(self.pfx_client),
+                payload={k: jnp.array(v) for k, v in self.pfx.items()}),
+            "hb": gset.GCounter(jnp.array(self.hb)),
+            "retire": gset.GSet(jnp.array(self.retire)),
             "journal": gset.GLog(
-                count=jnp.asarray(self.jr_count),
-                fields={k: jnp.asarray(v) for k, v in self.jr.items()}),
+                count=jnp.array(self.jr_count),
+                fields={k: jnp.array(v) for k, v in self.jr.items()}),
         }
 
     def load(self, tree: dict) -> None:

@@ -329,7 +329,8 @@ class ContinuousBatchingEngine:
 
     def __init__(self, cfg: ModelConfig, params: Params, *, batch: int,
                  max_len: int, paged: bool = True, page_size: int = 64,
-                 num_pages: Optional[int] = None, impl: str = "ref",
+                 num_pages: Optional[int] = None,
+                 impl: Optional[str] = None,
                  temperature: float = 0.0, seed: int = 0,
                  prefix_sharing: bool = False, chunk_size: int = 32,
                  token_budget: Optional[int] = None,

@@ -27,7 +27,8 @@ SCRIPT = textwrap.dedent("""
     from repro.core import doc as doc_mod, gset, lww, merge, todo
     from repro.serving import engine as engine_mod
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     R = 4
 
     # --- divergent per-replica LWW boards: each data shard wrote its own key
@@ -49,9 +50,9 @@ SCRIPT = textwrap.dedent("""
             return jax.tree.map(lambda x: x[None], m)
         specs = jax.tree.map(lambda x: P("data", *([None] * (x.ndim - 1))),
                              stacked)
-        out = jax.jit(merge.shard_map(local, mesh=mesh, in_specs=(specs,),
-                                      out_specs=specs,
-                                      check_vma=False))(stacked)
+        out = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(specs,),
+                                    out_specs=specs,
+                                    check_vma=False))(stacked)
         for i in range(R):
             got = jax.tree.map(lambda x: np.asarray(x[i]), out)
             want = jax.tree.map(np.asarray, expected)
@@ -83,7 +84,7 @@ SCRIPT = textwrap.dedent("""
                            stacked_docs)
     f_specs = jax.tree.map(lambda x: P("data", *([None] * (x.ndim - 1))),
                            fr_stacked)
-    out_docs, out_fr = jax.jit(merge.shard_map(
+    out_docs, out_fr = jax.jit(jax.shard_map(
         local_delta, mesh=mesh, in_specs=(d_specs, f_specs),
         out_specs=(d_specs, f_specs), check_vma=False))(stacked_docs,
                                                         fr_stacked)

@@ -41,8 +41,7 @@ try:
 except ModuleNotFoundError:
     HAVE_HYPOTHESIS = False
 
-QMODES = [m for m in ("int8", "fp8")
-          if m != "fp8" or cache_mod.FP8_DTYPE is not None]
+QMODES = ["int8", "fp8"]
 
 
 def _qdtype(mode: str):

@@ -16,7 +16,8 @@ pytestmark = pytest.mark.usefixtures()
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 class FakeMesh:
@@ -163,7 +164,8 @@ def test_paged_decode_lowers_multi_device():
         from repro.sharding.partition import Partitioner
         from repro.sharding import activation
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         for arch in ("olmo-1b", "deepseek-v2-lite-16b"):
             cfg = configs.reduced(configs.get(arch))
             part = Partitioner(mesh, fsdp=False)
