@@ -246,6 +246,7 @@ def run_prefix_share(cfg, params, *, max_len: int, page_size: int,
     peak resident KV MB and per-admission µs for the with/without-COW
     comparison column.
     """
+    from repro.runtime import trace
     from repro.serving.scheduler import ContinuousBatchingEngine, Request
 
     rng = np.random.default_rng(seed)
@@ -258,25 +259,32 @@ def run_prefix_share(cfg, params, *, max_len: int, page_size: int,
     # Warm the prefill bucket / decode shapes so admission_us measures the
     # steady-state admission path, not the jit compile.
     eng.run([Request(rid=-1, prompt=list(prompt), max_new_tokens=max_new)])
-    eng.stats.update(admit_s=0.0, prefills=0, peak_pages=0,
+    eng.stats.update(prefills=0, peak_pages=0,
                      shared_pages=0, cow_copies=0, completed=0)
     for r in requests:
         eng.submit(r)
     resident_peak = 0
-    while True:
-        more = eng.step()
-        resident_peak = max(resident_peak, eng.resident_cache_bytes())
-        if not more:
-            break
-        if eng.stats["steps"] > 50_000:
-            raise RuntimeError("prefix-share bench runaway")
+    trace.enable()
+    try:
+        while True:
+            more = eng.step()
+            resident_peak = max(resident_peak, eng.resident_cache_bytes())
+            if not more:
+                break
+            if eng.stats["steps"] > 50_000:
+                raise RuntimeError("prefix-share bench runaway")
+    finally:
+        trace.disable()
+        spans = trace.drain()
+    admit_ns = sum(sp.t1_ns - sp.t0_ns for sp in spans
+                   if sp.name == "engine.admit")
     s = eng.stats
     return {
         "fanout": fanout, "prompt_len": prompt_len, "max_new": max_new,
         "page_size": page_size, "cow": share,
         "resident_cache_mb": resident_peak / 2**20,
         "peak_pages": s["peak_pages"],
-        "admission_us": 1e6 * s["admit_s"] / max(s["prefills"], 1),
+        "admission_us": 1e-3 * admit_ns / max(s["prefills"], 1),
         "shared_pages": s["shared_pages"], "cow_copies": s["cow_copies"],
         "completed": s["completed"],
     }

@@ -23,6 +23,10 @@ grid (B,), pool ``[P, ps, Dp]`` storing concat([ckv; krope]) rows; queries
 arrive pre-absorbed (concat([q_abs; q_rope])) so both logits terms are one
 contraction, as in kernels/paged_mla_decode.py.
 
+Each ``pallas_call`` names its kernel (``paged_chunk_attention``,
+``paged_mla_chunk``, and the ``_quant`` twins), so a profiler trace finds
+it by that name whatever jitted function wraps it.
+
 The pools are ANY-space refs aliased input→output (in-place update on TPU).
 Alignment follows the decode kernels: ``page_size`` a multiple of the
 sublane count and the lane dim a multiple of 128 on real TPU; interpret
@@ -190,6 +194,7 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
         # Input indices count the scalar-prefetch operands (0, 1, 2).
         input_output_aliases={6: 1, 7: 2},
         interpret=interpret,
+        name="paged_chunk_attention",
     )(block_tables, start, span, q, k_new, v_new, k_pages, v_pages)
 
 
@@ -348,6 +353,7 @@ def paged_chunk_attention_quant(q: jax.Array, k_pages: jax.Array,
         # Input indices count the scalar-prefetch operands (0, 1, 2).
         input_output_aliases={6: 1, 7: 2, 8: 3, 9: 4},
         interpret=interpret,
+        name="paged_chunk_attention_quant",
     )(block_tables, start, span, q, k_new, v_new,
       k_pages, v_pages, k_scales, v_scales)
 
@@ -459,6 +465,7 @@ def paged_mla_chunk(q: jax.Array, latent_pages: jax.Array,
         # Input indices count the scalar-prefetch operands (0, 1, 2).
         input_output_aliases={5: 1},
         interpret=interpret,
+        name="paged_mla_chunk",
     )(block_tables, start, span, q, latent_new, latent_pages)
 
 
@@ -590,5 +597,6 @@ def paged_mla_chunk_quant(q: jax.Array, latent_pages: jax.Array,
         # Input indices count the scalar-prefetch operands (0, 1, 2).
         input_output_aliases={5: 1, 6: 2},
         interpret=interpret,
+        name="paged_mla_chunk_quant",
     )(block_tables, start, span, q, latent_new,
       latent_pages, latent_scales)

@@ -56,6 +56,7 @@ import numpy as np
 from repro.models import cache as cache_mod
 from repro.models import lm
 from repro.models.config import ModelConfig
+from repro.runtime import trace
 from repro.serving import draft as draft_mod
 from repro.serving import engine as engine_mod
 from repro.serving.engine import PROMPT_BUCKETS, bucket_len  # noqa: F401
@@ -307,6 +308,11 @@ class Request:
     # -- tiered page memory -------------------------------------------------
     swap_slots: list[int] = field(default_factory=list)  # held host slots
     swap_tokens: int = 0              # context tokens the swapped pages cover
+    # -- clock times, time.perf_counter_ns() (-1: not yet) ------------------
+    t_submit_ns: int = -1             # submit()
+    t_queued_ns: int = -1             # last entry to the queue: submit/preempt
+    t_admit_ns: int = -1              # last binding to a row
+    t_first_ns: int = -1              # first token appended
 
     @property
     def context(self) -> list[int]:
@@ -492,7 +498,7 @@ class ContinuousBatchingEngine:
                       "admitted": 0, "completed": 0, "peak_pages": 0,
                       "gen_tokens": 0, "prefill_tokens": 0,
                       "shared_pages": 0, "cow_copies": 0, "preemptions": 0,
-                      "grown_pages": 0, "admit_s": 0.0,
+                      "grown_pages": 0,
                       "decode_stall_steps": 0, "stalled_lane_steps": 0,
                       # Fault-tolerance accounting: totals plus per-cause
                       # counters (the satellite: causes are distinct).
@@ -514,7 +520,11 @@ class ContinuousBatchingEngine:
                       # pools, prompt tokens those pages covered, and
                       # prefill chunk steps that adoption skipped.
                       "adopted_pages": 0, "adopted_tokens": 0,
-                      "prefill_steps_avoided": 0}
+                      "prefill_steps_avoided": 0,
+                      # Positions the dispatched mixed/verify steps computed
+                      # (batch x width) and those that were rows' spans; the
+                      # difference is padding.
+                      "step_positions": 0, "span_positions": 0}
 
     # -- request lifecycle --------------------------------------------------
 
@@ -536,6 +546,7 @@ class ContinuousBatchingEngine:
                                  f"> pool {self.allocator.num_pages}")
         req.status = QUEUED
         req.submitted_step = self.stats["steps"]
+        req.t_submit_ns = req.t_queued_ns = time.perf_counter_ns()
         if self.max_queue is not None and len(self.queue) >= self.max_queue:
             # Bounded admission queue: shed the lowest-priority request
             # (ties broken toward the youngest) among the queue plus the
@@ -687,7 +698,6 @@ class ContinuousBatchingEngine:
         a request in retry backoff (``retry_at`` in the future) is skipped
         without blocking the requests behind it.
         """
-        t0 = time.perf_counter()
         admitted = 0
         reset_rows: list[int] = []
         now = self.stats["steps"]
@@ -786,6 +796,9 @@ class ContinuousBatchingEngine:
             req.filled = 0
             req.admit_len = len(ctx)
             req.admitted_step = self.stats["steps"]
+            req.t_admit_ns = time.perf_counter_ns()
+            trace.interval("engine.queued", req.t_queued_ns, req.t_admit_ns,
+                           req.rid)
             self.row_pos[row] = 0
             if swapped:
                 # The swapped-in pages already hold positions
@@ -812,7 +825,6 @@ class ContinuousBatchingEngine:
             self.stats["admitted"] += admitted
             if self.paged:
                 self._note_peak()
-        self.stats["admit_s"] += time.perf_counter() - t0
         return admitted
 
     def _done(self, req: Request) -> bool:
@@ -874,6 +886,7 @@ class ContinuousBatchingEngine:
         self._release_row(victim)
         self.rows[victim] = None
         req.status = PREEMPTED
+        req.t_queued_ns = time.perf_counter_ns()
         self.queue.appendleft(req)             # resumes with context intact
         self.row_pos[victim] = 0
         spans[victim] = 0                      # no span for the evicted row
@@ -1133,10 +1146,26 @@ class ContinuousBatchingEngine:
     # -- decode loop --------------------------------------------------------
 
     def step(self) -> bool:
-        """One token-budget mixed step.  Returns False when fully drained."""
-        self._check_deadlines()
-        self._shed_on_capacity_loss()
-        self.admit()
+        """One token-budget mixed step.  Returns False when fully drained.
+        While ``repro.runtime.trace`` is on, the step and its phases are
+        recorded as ``engine.*`` spans (named in that module)."""
+        with trace.span("engine.step"):
+            return self._step()
+
+    def _first_token(self, req: Request) -> None:
+        """Stamp ``req``'s first token, once: a preempted request's
+        re-admission keeps its TRUE time to first token."""
+        if req.first_token_step < 0:
+            req.first_token_step = self.stats["steps"]
+            req.t_first_ns = time.perf_counter_ns()
+            trace.interval("engine.prefill", req.t_admit_ns, req.t_first_ns,
+                           req.rid)
+
+    def _step(self) -> bool:
+        with trace.span("engine.admit"):
+            self._check_deadlines()
+            self._shed_on_capacity_loss()
+            self.admit()
         if all(r is None for r in self.rows):
             if self.queue:
                 # Nothing bound (every queued request backing off or blocked
@@ -1145,10 +1174,13 @@ class ContinuousBatchingEngine:
                 self.stats["steps"] += 1
                 return True
             return False
-        spans = self._compose()
-        drafts = self._fund_drafts(spans) if self.drafter is not None else {}
+        with trace.span("engine.compose"):
+            spans = self._compose()
+            drafts = (self._fund_drafts(spans) if self.drafter is not None
+                      else {})
         if self.paged:
-            self._ensure_pages(spans)
+            with trace.span("engine.pages"):
+                self._ensure_pages(spans)
         # A mid-walk eviction zeroes the victim's span; drop its draft.
         drafts = {r: d for r, d in drafts.items()
                   if self.rows[r] is not None and spans[r] == 1 + len(d)}
@@ -1157,146 +1189,159 @@ class ContinuousBatchingEngine:
             # stall-only bookkeeping step.
             self.stats["steps"] += 1
             return True
-        clamp = (max(self.chunk_size, 1) if self.prefill_interleave
-                 else self.max_len)
+        with trace.span("engine.compose"):
+            clamp = (max(self.chunk_size, 1) if self.prefill_interleave
+                     else self.max_len)
+            if self.drafter is not None:
+                clamp = max(clamp, 1 + self.spec_k)
+            width = engine_mod.width_bucket(int(spans.max()), clamp)
+            toks = np.zeros((self.batch, width), np.int64)
+            for row in range(self.batch):
+                req = self.rows[row]
+                if req is None or spans[row] == 0:
+                    continue
+                if req.admitting:
+                    seg = req.context[req.filled:
+                                      req.filled + int(spans[row])]
+                    toks[row, :len(seg)] = seg
+                else:
+                    toks[row, 0] = self.token[row]
+                    d = drafts.get(row)
+                    if d:
+                        toks[row, 1:1 + len(d)] = d
+        with trace.span("engine.upload"):
+            toks_dev = self._to_dev(f"tok{width}", toks.astype(np.int32))
+            start_dev = self._to_dev("start", self.row_pos.astype(np.int32))
+            span_dev = self._to_dev(f"span{width}", spans.astype(np.int32))
+        self.stats["step_positions"] += self.batch * width
+        self.stats["span_positions"] += int(spans.sum())
         if self.drafter is not None:
-            clamp = max(clamp, 1 + self.spec_k)
-        width = engine_mod.width_bucket(int(spans.max()), clamp)
-        toks = np.zeros((self.batch, width), np.int64)
-        for row in range(self.batch):
-            req = self.rows[row]
-            if req is None or spans[row] == 0:
-                continue
-            if req.admitting:
-                seg = req.context[req.filled: req.filled + int(spans[row])]
-                toks[row, :len(seg)] = seg
-            else:
-                toks[row, 0] = self.token[row]
-                d = drafts.get(row)
-                if d:
-                    toks[row, 1:1 + len(d)] = d
-        toks_dev = self._to_dev(f"tok{width}", toks.astype(np.int32))
-        start_dev = self._to_dev("start", self.row_pos.astype(np.int32))
-        span_dev = self._to_dev(f"span{width}", spans.astype(np.int32))
-        if self.drafter is not None:
-            snap = (self._snap(self.cache, start_dev, width)
-                    if drafts else None)
-            preds_d, acc_d, self.cache = self._verify(
-                self.params, self.cache, toks_dev, start_dev, span_dev)
-            preds = np.asarray(preds_d)        # [B, width]
-            acc = np.asarray(acc_d)
-            sampled = preds[np.arange(self.batch),
-                            np.clip(spans - 1, 0, width - 1)]
+            with trace.span("engine.dispatch"):
+                snap = (self._snap(self.cache, start_dev, width)
+                        if drafts else None)
+                preds_d, acc_d, self.cache = self._verify(
+                    self.params, self.cache, toks_dev, start_dev, span_dev)
+            with trace.span("engine.sync"):
+                preds = np.asarray(preds_d)        # [B, width]
+                acc = np.asarray(acc_d)
+                sampled = preds[np.arange(self.batch),
+                                np.clip(spans - 1, 0, width - 1)]
         else:
-            self.rng, sub = jax.random.split(self.rng)
-            nxt, self.cache = self._mixed(self.params, self.cache, toks_dev,
-                                          start_dev, span_dev, sub)
-            sampled = np.asarray(nxt)          # the one per-step sync
+            with trace.span("engine.dispatch"):
+                self.rng, sub = jax.random.split(self.rng)
+                nxt, self.cache = self._mixed(self.params, self.cache,
+                                              toks_dev, start_dev, span_dev,
+                                              sub)
+            with trace.span("engine.sync"):
+                sampled = np.asarray(nxt)          # the one per-step sync
         self.stats["steps"] += 1
-        chunks = 0
-        freed = False
-        roll_lo = np.zeros((self.batch,), np.int64)
-        roll_hi = np.zeros((self.batch,), np.int64)   # lo == hi: no-op row
-        replay_spans = np.zeros((self.batch,), np.int64)
-        rolled = False
-        for row in range(self.batch):
-            req = self.rows[row]
-            if req is None or spans[row] == 0:
-                continue
-            d = drafts.get(row)
-            if d is not None:
-                # Speculative lane: commit the longest accepted prefix plus
-                # the verifier's bonus token, roll the rejected tail back.
-                pos0 = int(self.row_pos[row])
-                appended, a_dev = draft_mod.accept_tokens(
-                    d, acc[row], preds[row],
-                    req.max_new_tokens - len(req.tokens), req.eos_id)
-                n_app = len(appended)
-                self.stats["draft_tokens"] += len(d)
-                self.stats["accepted_tokens"] += min(n_app, a_dev)
-                n_roll = int(spans[row]) - n_app
-                self.row_pos[row] += n_app
-                for t in appended:
-                    req.tokens.append(int(t))
-                    if self._journal is not None:
-                        self._journal("gen", req)
-                self.stats["gen_tokens"] += n_app
-                self.token[row] = int(appended[-1])
-                if req.first_token_step < 0:
-                    req.first_token_step = self.stats["steps"]
-                if n_roll > 0:
-                    self.stats["rollback_tokens"] += n_roll
-                    roll_lo[row] = pos0 + n_app
-                    roll_hi[row] = pos0 + int(spans[row])
-                    replay_spans[row] = n_app
-                    rolled = True
-                    if self.paged:
-                        self._rollback_tail_pages(row, pos0 + n_app,
-                                                  pos0 + int(spans[row]))
+        with trace.span("engine.commit"):
+            chunks = 0
+            freed = False
+            roll_lo = np.zeros((self.batch,), np.int64)
+            roll_hi = np.zeros((self.batch,), np.int64)  # lo == hi: no-op
+            replay_spans = np.zeros((self.batch,), np.int64)
+            rolled = False
+            for row in range(self.batch):
+                req = self.rows[row]
+                if req is None or spans[row] == 0:
+                    continue
+                d = drafts.get(row)
+                if d is not None:
+                    # Speculative lane: commit the longest accepted prefix
+                    # plus the verifier's bonus token, roll the rejected
+                    # tail back.
+                    pos0 = int(self.row_pos[row])
+                    appended, a_dev = draft_mod.accept_tokens(
+                        d, acc[row], preds[row],
+                        req.max_new_tokens - len(req.tokens), req.eos_id)
+                    n_app = len(appended)
+                    self.stats["draft_tokens"] += len(d)
+                    self.stats["accepted_tokens"] += min(n_app, a_dev)
+                    n_roll = int(spans[row]) - n_app
+                    self.row_pos[row] += n_app
+                    for t in appended:
+                        req.tokens.append(int(t))
+                        if self._journal is not None:
+                            self._journal("gen", req)
+                    self.stats["gen_tokens"] += n_app
+                    self.token[row] = int(appended[-1])
+                    self._first_token(req)
+                    if n_roll > 0:
+                        self.stats["rollback_tokens"] += n_roll
+                        roll_lo[row] = pos0 + n_app
+                        roll_hi[row] = pos0 + int(spans[row])
+                        replay_spans[row] = n_app
+                        rolled = True
+                        if self.paged:
+                            self._rollback_tail_pages(row, pos0 + n_app,
+                                                      pos0 + int(spans[row]))
+                    self._mark_filled(req, upto=int(self.row_pos[row]))
+                    if self._done(req):
+                        self._free_row(row)
+                        freed = True
+                    continue
+                self.row_pos[row] += int(spans[row])
+                if req.admitting:
+                    req.filled += int(spans[row])
+                    chunks += 1
+                    self.stats["prefill_tokens"] += int(spans[row])
+                    if req.admitting:
+                        self._mark_filled(req)
+                        continue              # mid-prompt logits: discarded
+                    # Admission complete: this chunk's last logits sampled
+                    # the request's first token (``_first_token`` below).
+                    if self.prefix_sharing and not req.tokens:
+                        self.prefix_cache.register(req.prompt, req.pages)
                 self._mark_filled(req, upto=int(self.row_pos[row]))
+                self.token[row] = int(sampled[row])
+                req.tokens.append(int(sampled[row]))
+                self.stats["gen_tokens"] += 1
+                if self._journal is not None:
+                    self._journal("gen", req)
+                self._first_token(req)
                 if self._done(req):
                     self._free_row(row)
                     freed = True
-                continue
-            self.row_pos[row] += int(spans[row])
-            if req.admitting:
-                req.filled += int(spans[row])
-                chunks += 1
-                self.stats["prefill_tokens"] += int(spans[row])
-                if req.admitting:
-                    self._mark_filled(req)
-                    continue                  # mid-prompt logits: discarded
-                # Admission complete: this chunk's last logits sampled the
-                # request's first token.  TTFT is recorded below, guarded,
-                # so a preempted request's re-admission keeps its TRUE
-                # time-to-first-token.
-                if self.prefix_sharing and not req.tokens:
-                    self.prefix_cache.register(req.prompt, req.pages)
-            self._mark_filled(req, upto=int(self.row_pos[row]))
-            self.token[row] = int(sampled[row])
-            req.tokens.append(int(sampled[row]))
-            self.stats["gen_tokens"] += 1
-            if self._journal is not None:
-                self._journal("gen", req)
-            if req.first_token_step < 0:
-                req.first_token_step = self.stats["steps"]
-            if self._done(req):
-                self._free_row(row)
-                freed = True
-        if drafts:
-            self.stats["spec_steps"] += 1
-        if rolled:
-            # Restore rejected-tail slots bitwise from the pre-verify
-            # snapshot.  The scatter walks the block tables INSIDE the
-            # device cache, which still hold the pre-rollback mapping (the
-            # host-side page frees above only touch host_bt; _push_tables
-            # runs before the next verify) — so tail bytes land in exactly
-            # the pages they were snapshotted from.
-            self.stats["spec_rollbacks"] += 1
-            self.cache = self._restore(
-                self.cache, snap, start_dev,
-                jnp.asarray(roll_lo.astype(np.int32)),
-                jnp.asarray(roll_hi.astype(np.int32)),
-                jnp.asarray(replay_spans > 0))
-            if self._has_state and replay_spans.any():
-                # Recurrent carries fold every span token irreversibly, so
-                # a partial rejection restored the PRE-verify state above;
-                # replay just the committed tokens to advance it.  The
-                # replay's attention writes are writes of the same tokens
-                # at the same positions — harmless overwrites.
-                w2 = engine_mod.width_bucket(int(replay_spans.max()), clamp)
-                _, _, self.cache = self._verify(
-                    self.params, self.cache,
-                    self._to_dev(f"rtok{w2}",
-                                 toks[:, :w2].astype(np.int32)),
-                    start_dev,
-                    self._to_dev(f"rspan{w2}",
-                                 replay_spans.astype(np.int32)))
-        if chunks:
-            self.stats["prefill_chunks"] += chunks
-            self.stats["prefills"] += 1        # steps that carried a chunk
+            if drafts:
+                self.stats["spec_steps"] += 1
+            if rolled:
+                # Restore rejected-tail slots bitwise from the pre-verify
+                # snapshot.  The scatter walks the block tables INSIDE the
+                # device cache, which still hold the pre-rollback mapping
+                # (the host-side page frees above only touch host_bt;
+                # _push_tables runs before the next verify) — so tail bytes
+                # land in exactly the pages they were snapshotted from.
+                self.stats["spec_rollbacks"] += 1
+                self.cache = self._restore(
+                    self.cache, snap, start_dev,
+                    jnp.asarray(roll_lo.astype(np.int32)),
+                    jnp.asarray(roll_hi.astype(np.int32)),
+                    jnp.asarray(replay_spans > 0))
+                if self._has_state and replay_spans.any():
+                    # Recurrent carries fold every span token irreversibly,
+                    # so a partial rejection restored the PRE-verify state
+                    # above; replay just the committed tokens to advance
+                    # it.  The replay's attention writes are writes of the
+                    # same tokens at the same positions — harmless
+                    # overwrites.
+                    w2 = engine_mod.width_bucket(int(replay_spans.max()),
+                                                 clamp)
+                    self.stats["step_positions"] += self.batch * w2
+                    self.stats["span_positions"] += int(replay_spans.sum())
+                    _, _, self.cache = self._verify(
+                        self.params, self.cache,
+                        self._to_dev(f"rtok{w2}",
+                                     toks[:, :w2].astype(np.int32)),
+                        start_dev,
+                        self._to_dev(f"rspan{w2}",
+                                     replay_spans.astype(np.int32)))
+            if chunks:
+                self.stats["prefill_chunks"] += chunks
+                self.stats["prefills"] += 1        # steps that carried a chunk
         if freed:
-            self.admit()
+            with trace.span("engine.admit"):
+                self.admit()
         return any(r is not None for r in self.rows) or bool(self.queue)
 
     def run(self, requests: list[Request], max_steps: int = 100_000

@@ -16,6 +16,7 @@ import every test file.
 from __future__ import annotations
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,14 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def _kernel_ops(compiled) -> list[str]:
+    """Instruction names of the Mosaic kernels in ``compiled``: the names
+    their events carry in a profiler trace."""
+    return [re.match(r"\s*(?:ROOT )?%([\w.-]+) = ", line).group(1)
+            for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
 @pytest.fixture
 def spec(one_chip):
     return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
@@ -76,6 +85,50 @@ def test_paged_chunk_attention_compiles_for_v5e(spec):
         spec((B,), jnp.int32), spec((B, H, C, D), jnp.bfloat16),
         spec((B, H, C, D), jnp.bfloat16))
     assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("kernel", ["paged_chunk_attention",
+                                    "paged_chunk_attention_quant",
+                                    "paged_mla_chunk",
+                                    "paged_mla_chunk_quant"])
+def test_chunk_kernels_carry_their_own_names_for_v5e(spec, kernel):
+    """Each chunk kernel is named by its ``pallas_call``, not by the jitted
+    function around it: called unwrapped inside a function of another
+    name, its trace event still starts with the kernel's name (an MHA
+    name holds ``paged_chunk_attention``, a quantized one ``_quant``)."""
+    from repro.kernels import paged_chunk_attention as pc
+    bt, vec = spec((B, MAXP), jnp.int32), spec((B,), jnp.int32)
+    width = MLA_R + MLA_RD
+    fn = getattr(pc, kernel).__wrapped__
+    if kernel == "paged_chunk_attention":
+        pool = spec((P, H, PS, D), jnp.bfloat16)
+        tok = spec((B, H, C, D), jnp.bfloat16)
+        args = (tok, pool, pool, bt, vec, vec, tok, tok)
+        kw = dict(scale=0.1)
+    elif kernel == "paged_chunk_attention_quant":
+        pool, scales = (spec((P, H, QPS, D), jnp.int8),
+                        spec((P, H, QPS), jnp.float32))
+        new = spec((B, H, C, D), jnp.float32)
+        args = (spec((B, H, C, D), jnp.bfloat16), pool, scales, pool,
+                scales, bt, vec, vec, new, new)
+        kw = dict(scale=0.1, qmax=127.0)
+    elif kernel == "paged_mla_chunk":
+        args = (spec((B, H, C, width), jnp.float32),
+                spec((P, PS, MLA_DP), jnp.bfloat16), bt, vec, vec,
+                spec((B, C, MLA_DP), jnp.bfloat16))
+        kw = dict(r=MLA_R, scale=0.1)
+    else:
+        args = (spec((B, H, C, width), jnp.float32),
+                spec((P, QPS, MLA_DP), jnp.int8),
+                spec((P, QPS), jnp.float32), bt, vec, vec,
+                spec((B, C, MLA_DP), jnp.float32))
+        kw = dict(r=MLA_R, scale=0.1, qmax=127.0)
+
+    def served_step(*a):
+        return fn(*a, **kw)
+
+    ops_ = _kernel_ops(_compile(served_step, *args))
+    assert len(ops_) == 1 and ops_[0].split(".")[0] == kernel
 
 
 def test_paged_decode_attention_compiles_for_v5e(spec):
