@@ -13,6 +13,8 @@ decode kernels were verified at.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -101,6 +103,22 @@ CHUNK_CASES = [
     (3, 4, 2, 10, 3, 16, 5, None),        # unaligned sizes (interpret)
     (2, 8, 2, 8, 4, 32, 16, 11),          # span > page, sliding window
     (2, 2, 2, 8, 4, 32, 1, None),         # span 1 == fused decode
+    # Served widths, so the walk takes several multi-page blocks; each
+    # row is (start, span, table entries set to -1).
+    pytest.param((4, 16, 16, 64, 11, 128, 32, None, (
+        (626, 32, ()),                    # 11 pages: the last block partial;
+                                          # the span straddles pages 9|10
+        (300, 0, ()),                     # idle beside live rows
+        (100, 5, tuple(range(2, 11))),    # -1 past its pages, in its block
+        (191, 17, ()))), id="mha16"),     # straddles pages 2|3
+    pytest.param((3, 16, 16, 64, 11, 128, 1, None, (
+        (640, 1, ()), (5, 0, ()), (63, 1, tuple(range(1, 11))))),
+        id="mha16-decode"),
+    pytest.param((3, 48, 4, 64, 13, 128, 32, 300, (
+        (760, 32, tuple(range(6))),       # -1 inside the live pages, all
+                                          # below the window
+        (0, 0, ()),
+        (180, 20, tuple(range(4, 13))))), id="gqa12-window"),
 ]
 
 
@@ -119,6 +137,21 @@ def _chunk_setup(b, hq, hkv, ps, maxp, d, c, dtype, seed=0):
     return q, kp, vp, bt, start, span, kn, vn
 
 
+def _chunk_layout(b, hq, hkv, ps, maxp, d, c, dtype, rows, seed=0):
+    """``_chunk_setup`` with each row's (start, span, -1 entries) given.
+    Page 0 is in no table, so a -1 entry (read as page 0 by both paths)
+    reads a page no row writes."""
+    q, kp, vp, _, _, _, kn, vn = _chunk_setup(b, hq, hkv, ps, maxp, d, c,
+                                              dtype, seed)
+    r = np.random.default_rng(seed + 1)
+    bt = (1 + r.permutation(kp.shape[0] - 1)[:b * maxp]).reshape(b, maxp)
+    for i, (_, _, holes) in enumerate(rows):
+        bt[i, list(holes)] = -1
+    start = jnp.asarray([row[0] for row in rows], jnp.int32)
+    span = jnp.asarray([row[1] for row in rows], jnp.int32)
+    return q, kp, vp, jnp.asarray(bt, jnp.int32), start, span, kn, vn
+
+
 def _tol(dtype):
     return (dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16
             else dict(rtol=2e-5, atol=2e-5))
@@ -127,8 +160,10 @@ def _tol(dtype):
 @pytest.mark.parametrize("case", CHUNK_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_chunk_attention_kernel_matches_oracle(case, dtype):
-    b, hq, hkv, ps, maxp, d, c, window = case
-    q, kp, vp, bt, start, span, kn, vn = _chunk_setup(
+    b, hq, hkv, ps, maxp, d, c, window, *rows = case
+    setup = (functools.partial(_chunk_layout, rows=rows[0]) if rows
+             else _chunk_setup)
+    q, kp, vp, bt, start, span, kn, vn = setup(
         b, hq, hkv, ps, maxp, d, c, dtype)
     o1, kp1, vp1 = ops.paged_chunk_attention(q, kp, vp, bt, start, span,
                                              kn, vn, window=window)
@@ -144,6 +179,11 @@ def test_paged_chunk_attention_kernel_matches_oracle(case, dtype):
     # scatter — and touch only the written slots.
     np.testing.assert_array_equal(np.asarray(kp1), np.asarray(kp2))
     np.testing.assert_array_equal(np.asarray(vp1), np.asarray(vp2))
+    # An idle row's pages stay as they were.
+    idle = np.asarray(bt)[np.asarray(span) == 0]
+    idle = idle[idle >= 0]
+    np.testing.assert_array_equal(np.asarray(kp1)[idle], np.asarray(kp)[idle])
+    np.testing.assert_array_equal(np.asarray(vp1)[idle], np.asarray(vp)[idle])
 
 
 def test_paged_chunk_span1_matches_decode_kernel_write():

@@ -4,10 +4,11 @@ Each test compiles for one chip of a described (not attached) ``v5e:2x2``
 topology with ``interpret=False`` and checks that the program holds the
 Mosaic kernel (``tpu_custom_call``).  Shapes are olmo-1b's (16 MHA heads,
 head_dim 128, bf16 pools, page 64, batch 8) and deepseek-v2-lite's latent
-width for MLA; the quantized twins use page 128, because their f32 scale
-rows are one page wide and a TPU row is 128 lanes.  Mosaic refuses what
-interpret mode accepts (unaligned slices, oversized VMEM), so these guard
-every change to the kernels at no chip time.
+width for MLA; the chunk kernel also compiles at each served cell's shapes
+(olmo-1b and starcoder2-15b, batch 32).  The quantized twins use page 128,
+because their f32 scale rows are one page wide and a TPU row is 128 lanes.
+Mosaic refuses what interpret mode accepts (unaligned slices, oversized
+VMEM), so these guard every change to the kernels at no chip time.
 
 The topology is described inside a module fixture and never at import
 time: only one process may load the TPU library, and the test workers
@@ -85,6 +86,34 @@ def test_paged_chunk_attention_compiles_for_v5e(spec):
         spec((B,), jnp.int32), spec((B, H, C, D), jnp.bfloat16),
         spec((B, H, C, D), jnp.bfloat16))
     assert _has_kernel(compiled)
+
+
+# (Hq, Hkv, maxp, pool pages, window) of the served cells: olmo-1b (MHA,
+# context 2048) and starcoder2-15b (GQA group 12, context 4096, its
+# published window), batch 32.
+SERVED = {"olmo-1b": (16, 16, 32, 640, None),
+          "starcoder2-15b": (48, 4, 64, 2049, 4096)}
+
+
+@pytest.mark.parametrize("c", [1, 32])
+@pytest.mark.parametrize("arch", sorted(SERVED))
+def test_paged_chunk_attention_compiles_for_v5e_at_served_shapes(
+        spec, arch, c):
+    """The block walk at each served cell's shapes and at both ends of its
+    widths: one Mosaic kernel, and its event keeps the name the
+    benchmark's matcher sums."""
+    from repro.kernels import paged_chunk_attention as pc
+    hq, hkv, maxp, pages, window = SERVED[arch]
+    pool = spec((pages, hkv, PS, D), jnp.bfloat16)
+    bt, vec = spec((32, maxp), jnp.int32), spec((32,), jnp.int32)
+    new = spec((32, hkv, c, D), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, kp, vp, bt, st, sp, kn, vn: pc.paged_chunk_attention(
+            q, kp, vp, bt, st, sp, kn, vn, scale=D ** -0.5, window=window),
+        spec((32, hq, c, D), jnp.bfloat16), pool, pool, bt, vec, vec, new,
+        new)
+    ops_ = _kernel_ops(compiled)
+    assert len(ops_) == 1 and ops_[0].startswith("paged_chunk_attention")
 
 
 @pytest.mark.parametrize("kernel", ["paged_chunk_attention",
