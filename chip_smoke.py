@@ -136,8 +136,8 @@ def logit_phase(cfg, params, seed: int) -> None:
         outs = []
         for i, (toks, start, span) in enumerate(steps):
             start = start + (shift if i == 1 else 0)
-            out, cache = fn(params, cache, jnp.asarray(toks, jnp.int32),
-                            jnp.asarray(start), jnp.asarray(span))
+            out, cache, _ = fn(params, cache, jnp.asarray(toks, jnp.int32),
+                               jnp.asarray(start), jnp.asarray(span))
             valid = np.arange(CHUNK)[None, :] < span[:, None]
             outs.append(np.asarray(out)[valid])
         return np.concatenate(outs), cache

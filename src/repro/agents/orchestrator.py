@@ -338,7 +338,7 @@ def run_task(cfg: ModelConfig, params, task: TaskSpec, *, mode: str,
     wclamp = chunk_size
     has_state = any(
         cache_mod.layout_for(k, cfg, paged=False) == "state"
-        for k in tuple(cfg.block_pattern) + tuple(cfg.tail_blocks))
+        for k in cfg.all_blocks)
     if spec_decode != "off":
         drafter = draft_mod.make_drafter(spec_decode)
         wclamp = max(chunk_size, 1 + spec_k)

@@ -19,7 +19,8 @@ from repro.configs.recurrentgemma_2b import CONFIG as recurrentgemma_2b
 from repro.configs.starcoder2_15b import CONFIG as starcoder2_15b
 from repro.configs.whisper_tiny import CONFIG as whisper_tiny
 from repro.configs.xlstm_125m import CONFIG as xlstm_125m
-from repro.models.config import EncoderConfig, MLAConfig, ModelConfig, MoEConfig
+from repro.models.config import (EncoderConfig, MLAConfig, ModelConfig,
+                                 MoEConfig, YarnConfig)
 
 ARCHS: dict[str, ModelConfig] = {
     c.name: c for c in [
@@ -42,7 +43,8 @@ def reduced(cfg: ModelConfig, *, layers: int | None = None,
     # One full pattern group, plus the same tail remainder as the full config
     # (so tail code paths are exercised too).
     n_pat = len(cfg.block_pattern)
-    n_layers = layers if layers is not None else n_pat + len(cfg.tail_blocks)
+    n_layers = (layers if layers is not None
+                else cfg.first_dense + n_pat + len(cfg.tail_blocks))
     heads = min(cfg.num_heads, 4)
     kv = min(cfg.num_kv_heads, heads)
     while heads % kv:
@@ -58,8 +60,8 @@ def reduced(cfg: ModelConfig, *, layers: int | None = None,
     )
     if cfg.moe is not None:
         kw["moe"] = dataclasses.replace(
-            cfg.moe, num_experts=8, top_k=2, d_expert=32, num_shared=1,
-            capacity_factor=4.0)
+            cfg.moe, num_experts=8, top_k=2, d_expert=32, num_shared=1)
+        kw["experts_first"], kw["experts_held"] = 0, 0
     if cfg.mla is not None:
         kw["mla"] = dataclasses.replace(
             cfg.mla, kv_lora_rank=32, rope_head_dim=8, nope_head_dim=16,
@@ -71,4 +73,4 @@ def reduced(cfg: ModelConfig, *, layers: int | None = None,
 
 
 __all__ = ["ARCHS", "get", "reduced", "shapes", "ModelConfig", "MoEConfig",
-           "MLAConfig", "EncoderConfig"]
+           "MLAConfig", "EncoderConfig", "YarnConfig"]

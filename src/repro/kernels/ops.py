@@ -23,6 +23,7 @@ from repro.kernels import decode_attention as _dec
 from repro.kernels import delta_apply as _da
 from repro.kernels import flash_attention as _fa
 from repro.kernels import lww_merge as _lww
+from repro.kernels import moe_grouped_matmul as _moe
 from repro.kernels import page_transfer as _pxfer
 from repro.kernels import paged_chunk_attention as _pchunk
 from repro.kernels import paged_decode_attention as _pdec
@@ -519,6 +520,21 @@ def page_transfer(src_pool, dst_pool, src_ids, dst_ids, *,
                                 src_ids.astype(jnp.int32),
                                 dst_ids.astype(jnp.int32),
                                 interpret=not on_tpu)
+
+
+def moe_grouped_swiglu(x, w_gate, w_up, w_down, tile_group, num_tiles, *,
+                       tm: int):
+    """Grouped SwiGLU of the held experts over group-aligned pair tiles
+    (see kernels/moe_grouped_matmul.py): the Pallas kernel on a TPU, where
+    it visits only the ``num_tiles`` live tiles, the jnp oracle elsewhere.
+    x: [T*tm, d]; w_gate / w_up: [G, d, h]; w_down: [G, h, d];
+    tile_group: i32[T]; num_tiles: i32[1].  Rows of tiles past
+    ``num_tiles`` are undefined."""
+    if not _on_tpu():
+        return ref.moe_grouped_swiglu(x, w_gate, w_up, w_down, tile_group,
+                                      num_tiles, tm=tm)
+    return _moe.moe_grouped_swiglu(x, w_gate, w_up, w_down, tile_group,
+                                   num_tiles, tm=tm)
 
 
 def linear_scan(a, b, h0, *, block_t: int = 128, use_pallas: bool = True):

@@ -621,3 +621,28 @@ def page_transfer(src_pool: jax.Array, dst_pool: jax.Array,
     tgt = jnp.where(keep, jnp.clip(dst_ids, 0, dst_pool.shape[0] - 1),
                     dst_pool.shape[0])
     return dst_pool.at[tgt].set(rows, mode="drop")
+
+
+# ---------------------------------------------------------------------------
+# Grouped expert SwiGLU (kernels/moe_grouped_matmul.py)
+# ---------------------------------------------------------------------------
+
+def moe_grouped_swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+                       w_down: jax.Array, tile_group: jax.Array,
+                       num_tiles: jax.Array, *, tm: int) -> jax.Array:
+    """Oracle of the grouped SwiGLU: tile t of ``x`` ([T*tm, d]) through
+    expert ``tile_group[t]``; tiles at or past ``num_tiles[0]`` give 0.
+    Same rounding as the kernel: f32 accumulation, the SwiGLU product
+    rounded to x's dtype before the down projection."""
+    rows, d = x.shape
+    t = rows // tm
+    xt = x.reshape(t, tm, d)
+    g = jnp.einsum("tmd,tdh->tmh", xt, w_gate[tile_group],
+                   preferred_element_type=jnp.float32)
+    u = jnp.einsum("tmd,tdh->tmh", xt, w_up[tile_group],
+                   preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(g) * u).astype(x.dtype)
+    y = jnp.einsum("tmh,thd->tmd", a, w_down[tile_group],
+                   preferred_element_type=jnp.float32)
+    live = (jnp.arange(t) < num_tiles[0])[:, None, None]
+    return jnp.where(live, y, 0).astype(x.dtype).reshape(rows, d)

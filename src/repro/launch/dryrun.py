@@ -15,7 +15,7 @@ Usage:
   python -m repro.launch.dryrun --arch olmo-1b --shape train_4k --mesh single
   python -m repro.launch.dryrun --all --mesh both
   python -m repro.launch.dryrun --arch deepseek-moe-16b --shape train_4k \
-      --mesh single --variant dense_dispatch --moe-dispatch dense
+      --mesh single --variant no_remat --no-remat
 """
 from __future__ import annotations
 
@@ -377,7 +377,7 @@ def extrapolated_costs(cfg, shape, mesh, **kw) -> dict:
     pat, tail = len(cfg.block_pattern), len(cfg.tail_blocks)
 
     def variant(groups):
-        kw_c = {"num_layers": groups * pat + tail}
+        kw_c = {"num_layers": cfg.first_dense + groups * pat + tail}
         if cfg.encoder is not None:
             kw_c["encoder"] = cfg.encoder.__class__(
                 num_layers=groups, num_heads=cfg.encoder.num_heads,
@@ -408,8 +408,7 @@ def extrapolated_costs(cfg, shape, mesh, **kw) -> dict:
 def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
              variant: str = "baseline", force: bool = False,
              merge_strategy: str = "pmax", fused_coord: bool = False,
-             moe_dispatch: str | None = None, remat: bool = True,
-             microbatches: int = 1, capacity_factor: float | None = None,
+             remat: bool = True, microbatches: int = 1,
              mla_cache: str = "latent", merge_every: int = 1,
              delta_capacity: int = 64, ring_cache: bool = False,
              kv_layout: str = "dense", page_size: int = 64) -> dict:
@@ -417,13 +416,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
     cfg = configs.get(arch)
     if ring_cache:
         cfg = cfg.replace(ring_local_cache=True)
-    if cfg.moe and (moe_dispatch or capacity_factor is not None):
-        kw = dict(cfg.moe.__dict__)
-        if moe_dispatch:
-            kw["dispatch"] = moe_dispatch
-        if capacity_factor is not None:
-            kw["capacity_factor"] = capacity_factor
-        cfg = cfg.replace(moe=cfg.moe.__class__(**kw))
 
     out_dir = OUT_DIR / mesh_kind
     out_dir.mkdir(parents=True, exist_ok=True)
@@ -524,11 +516,8 @@ def main() -> None:
     ap.add_argument("--merge-strategy", default="pmax",
                     choices=["pmax", "allgather", "delta"])
     ap.add_argument("--fused-coord", action="store_true")
-    ap.add_argument("--moe-dispatch", default=None,
-                    choices=[None, "gather", "dense"])
     ap.add_argument("--no-remat", action="store_true")
     ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--capacity-factor", type=float, default=None)
     ap.add_argument("--mla-cache", default="latent",
                     choices=["latent", "replicated", "seq"])
     ap.add_argument("--merge-every", type=int, default=1)
@@ -554,10 +543,8 @@ def main() -> None:
                     arch, shape_name, mesh_kind, variant=args.variant,
                     force=args.force, merge_strategy=args.merge_strategy,
                     fused_coord=args.fused_coord,
-                    moe_dispatch=args.moe_dispatch,
                     remat=not args.no_remat,
                     microbatches=mb,
-                    capacity_factor=args.capacity_factor,
                     mla_cache=args.mla_cache,
                     merge_every=args.merge_every,
                     delta_capacity=args.delta_capacity,

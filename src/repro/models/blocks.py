@@ -111,11 +111,22 @@ def _cross_attend(p, cfg, x, k, v, impl):
     return common.dense(p["wo"], attention._merge_heads(out).astype(x.dtype))
 
 
+def _moe(p, cfg, x, ctx):
+    """The expert FFN over the positions that hold tokens (a mixed step's
+    spans, a ragged prefill's lengths), and its aux (see ``block_apply``)."""
+    valid = (None if ctx.lengths is None
+             else jnp.arange(x.shape[1])[None, :] < ctx.lengths[:, None])
+    f, aux, counts = moe.forward(p, cfg, x, valid)
+    return f, jnp.concatenate([aux[None], counts])
+
+
 def block_apply(kind: str, p: Params, cfg: ModelConfig, x: jax.Array,
                 ctx: BlockCtx, cache: Params | None
                 ) -> tuple[jax.Array, Params | None, jax.Array]:
-    """Returns (x, new_cache, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+    """Returns (x, new_cache, aux).  aux is f32[3]: the MoE load-balancing
+    loss, the expert pairs computed, and the most pairs one held expert
+    took (see ``moe.forward``); zeros in a block without experts."""
+    aux = jnp.zeros((3,), jnp.float32)
     decode = ctx.mode == "decode"
     mixed = ctx.mode == "mixed"
 
@@ -147,7 +158,7 @@ def block_apply(kind: str, p: Params, cfg: ModelConfig, x: jax.Array,
         x = x + a
         h2 = _norm(p["norm2"], cfg, x)
         if kind == "moe":
-            f, aux = moe.forward(p["ffn"], cfg, h2)
+            f, aux = _moe(p["ffn"], cfg, h2, ctx)
         else:
             f = ffn.forward(p["ffn"], cfg, h2)
         return x + f, cache, aux
@@ -173,7 +184,7 @@ def block_apply(kind: str, p: Params, cfg: ModelConfig, x: jax.Array,
         x = x + a
         h2 = _norm(p["norm2"], cfg, x)
         if kind == "mla_moe":
-            f, aux = moe.forward(p["ffn"], cfg, h2)
+            f, aux = _moe(p["ffn"], cfg, h2, ctx)
         else:
             f = ffn.forward(p["ffn"], cfg, h2)
         return x + f, cache, aux

@@ -305,7 +305,8 @@ def model_cache_specs(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
                       *, paged: bool = False, page_size: int = 64,
                       num_pages: int | None = None,
                       kv_quant: str = "off") -> dict[str, Any]:
-    """The full registry for one model: {"groups": {i: spec}, "tail": ...}.
+    """The full registry for one model: {"groups": {i: spec}, "lead": ...,
+    "tail": ...} (lead/tail only where the model has such layers).
 
     Group specs describe ONE group's leaves; the stacked cache carries a
     leading [G] axis on every array (see lm.init_cache).
@@ -315,12 +316,14 @@ def model_cache_specs(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
                          page_size=page_size, num_pages=num_pages,
                          kv_quant=kv_quant)
         for i, kind in enumerate(cfg.block_pattern)}}
-    tail = {str(i): spec_for(kind, cfg, batch, max_len, dtype, paged=paged,
-                             page_size=page_size, num_pages=num_pages,
-                             kv_quant=kv_quant)
-            for i, kind in enumerate(cfg.tail_blocks)}
-    if tail:
-        specs["tail"] = tail
+    for name, kinds in (("lead", cfg.lead_blocks),
+                        ("tail", cfg.tail_blocks)):
+        if kinds:
+            specs[name] = {
+                str(i): spec_for(kind, cfg, batch, max_len, dtype,
+                                 paged=paged, page_size=page_size,
+                                 num_pages=num_pages, kv_quant=kv_quant)
+                for i, kind in enumerate(kinds)}
     return specs
 
 

@@ -6,6 +6,7 @@ pure function usable under jit / scan / shard_map.
 """
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -65,19 +66,65 @@ def apply_norm(p: Params, x: jax.Array, norm_type: str,
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_correction_range(y, dim: int, theta: float) -> tuple[int, int]:
+    """DeepSeek-V2's ``yarn_find_correction_range``: the rotary dims whose
+    wavelength spans ``beta_fast`` .. ``beta_slow`` turns over the original
+    context, clamped to [0, dim - 1]."""
+    def dim_of(turns):
+        return (dim * math.log(y.original_max_position
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = math.floor(dim_of(y.beta_fast))
+    high = math.ceil(dim_of(y.beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> jax.Array:
+    """Inverse frequencies [D/2]; with a ``YarnConfig`` the YaRN blend:
+    original frequencies below the correction range, frequencies over
+    ``factor`` above it, a linear ramp between (``yarn_linear_ramp_mask``)."""
+    base = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
                             / head_dim))
+    if scaling is None:
+        return base
+    low, high = _yarn_correction_range(scaling, head_dim, theta)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                     # 1: original, 0: interpolated
+    return base / scaling.factor * (1.0 - keep) + base * keep
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, H, T, D]; positions: [B, T] (or [T] broadcast)."""
+def rope_mscale(scaling) -> float:
+    """Factor on cos/sin: mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim) (1 when the two are equal, as in DeepSeek-V2)."""
+    if scaling is None:
+        return 1.0
+    return (yarn_mscale(scaling.factor, scaling.mscale)
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
+    """x: [B, H, T, D]; positions: [B, T] (or [T] broadcast); ``scaling``
+    an optional ``YarnConfig``."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                                 # [D/2]
+    freqs = rope_freqs(d, theta, scaling)                        # [D/2]
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[:, None, :, None].astype(jnp.float32) * freqs  # [B,1,T,D/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    m = rope_mscale(scaling)
+    if m != 1.0:
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -19,9 +19,7 @@ class MoEConfig:
     top_k: int
     d_expert: int               # per-expert FFN hidden
     num_shared: int = 0         # shared (always-on) experts
-    capacity_factor: float = 1.25
-    router_jitter: float = 0.0
-    dispatch: str = "gather"    # "gather" (capacity einsum) | "dense" (all-expert)
+    norm_topk_prob: bool = True  # renormalise the top-k gate weights to 1
 
 
 @dataclass(frozen=True)
@@ -30,6 +28,20 @@ class MLAConfig:
     rope_head_dim: int = 64     # decoupled shared-key RoPE dims
     nope_head_dim: int = 128
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling as DeepSeek-V2 publishes it (``rope_scaling``,
+    type "yarn"): frequencies blended between interpolated and original
+    over a ramp of rotary dims, and the softmax scale multiplied by
+    ``mscale(factor, mscale_all_dim)**2``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -52,7 +64,8 @@ class ModelConfig:
     vocab_size: int
     head_dim: int = 0           # 0 -> d_model // num_heads
 
-    # Layer pattern: repeated to fill num_layers; remainder applied unstacked.
+    # Layer pattern: repeated to fill num_layers after ``first_dense``
+    # leading layers; the remainder is applied unstacked.
     #   "attn" full causal attention + FFN          (dense archs)
     #   "local" sliding-window attention + FFN      (recurrentgemma)
     #   "rglru" RG-LRU temporal block + FFN         (recurrentgemma)
@@ -62,9 +75,14 @@ class ModelConfig:
     #   "slstm"/"mlstm" xLSTM blocks (own projections, no separate FFN)
     #   "xattn" decoder block w/ cross-attention    (whisper decoder)
     block_pattern: tuple[str, ...] = ("attn",)
+    # Leading layers that keep the pattern's attention but a dense FFN of
+    # width d_ff (DeepSeek's first_k_dense_replace), run unstacked before
+    # the scanned groups.
+    first_dense: int = 0
 
     # Attention details
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnConfig] = None   # MLA layers' rotary dims
     window: Optional[int] = None       # for "local" blocks
     qk_norm: bool = False
     use_bias: bool = False
@@ -78,6 +96,11 @@ class ModelConfig:
 
     # Family sub-configs
     moe: Optional[MoEConfig] = None
+    # The routed experts this chip holds in every expert layer (expert
+    # parallelism): [experts_first, experts_first + experts_held); 0 holds
+    # all of them.  The router always scores all moe.num_experts.
+    experts_first: int = 0
+    experts_held: int = 0
     mla: Optional[MLAConfig] = None
     encoder: Optional[EncoderConfig] = None
 
@@ -104,17 +127,45 @@ class ModelConfig:
         if self.rglru_width == 0:
             object.__setattr__(self, "rglru_width", self.d_model)
         assert self.num_heads % self.num_kv_heads == 0
+        if self.moe is not None:
+            first, n = self.held_experts
+            assert 0 <= first and first + n <= self.moe.num_experts, \
+                f"held experts [{first}, {first + n}) outside " \
+                f"{self.moe.num_experts}"
 
     # ---- derived ----
     @property
+    def lead_blocks(self) -> tuple[str, ...]:
+        """The ``first_dense`` leading layers: the pattern's first kind with
+        its expert FFN replaced by the dense one."""
+        dense = {"mla_moe": "mla", "moe": "attn"}
+        kind = self.block_pattern[0]
+        return (dense.get(kind, kind),) * self.first_dense
+
+    @property
     def pattern_groups(self) -> int:
-        return self.num_layers // len(self.block_pattern)
+        return (self.num_layers - self.first_dense) // len(self.block_pattern)
 
     @property
     def tail_blocks(self) -> tuple[str, ...]:
-        """Remainder layers when num_layers % len(pattern) != 0."""
-        rem = self.num_layers % len(self.block_pattern)
+        """Remainder layers when the pattern does not divide the layers
+        after the leading ones."""
+        rem = (self.num_layers - self.first_dense) % len(self.block_pattern)
         return self.block_pattern[:rem]
+
+    @property
+    def all_blocks(self) -> tuple[str, ...]:
+        """Every layer's kind, in order."""
+        return (self.lead_blocks + self.block_pattern * self.pattern_groups
+                + self.tail_blocks)
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """(first, count) of the routed experts held here."""
+        if self.moe is None:
+            return 0, 0
+        return (self.experts_first,
+                self.experts_held or self.moe.num_experts)
 
     @property
     def is_encdec(self) -> bool:
@@ -132,8 +183,7 @@ class ModelConfig:
         total = v * d * (1 if self.tie_embeddings else 2)
         kv_dim = self.num_kv_heads * self.head_dim
         q_dim = self.num_heads * self.head_dim
-        for kind in (list(self.block_pattern) * self.pattern_groups
-                     + list(self.tail_blocks)):
+        for kind in self.all_blocks:
             if kind in ("attn", "local", "moe"):
                 total += d * q_dim + 2 * d * kv_dim + q_dim * d
             elif kind in ("mla", "mla_moe"):
@@ -159,7 +209,8 @@ class ModelConfig:
                 total += 2 * (d * q_dim + kv_dim * d)
             if kind in ("moe", "mla_moe"):
                 m = self.moe
-                total += 3 * d * m.d_expert * (m.num_experts + m.num_shared)
+                total += 3 * d * m.d_expert * (self.held_experts[1]
+                                               + m.num_shared)
                 total += d * m.num_experts
         if self.encoder is not None:
             e = self.encoder
@@ -172,11 +223,11 @@ class ModelConfig:
             return self.param_count()
         m = self.moe
         full = self.param_count()
-        moe_layers = sum(1 for k in (list(self.block_pattern)
-                                     * self.pattern_groups)
-                         + list(self.tail_blocks) if k in ("moe", "mla_moe"))
+        moe_layers = sum(1 for k in self.all_blocks
+                         if k in ("moe", "mla_moe"))
         d = self.d_model
-        all_experts = 3 * d * m.d_expert * (m.num_experts + m.num_shared)
+        all_experts = 3 * d * m.d_expert * (self.held_experts[1]
+                                            + m.num_shared)
         active = 3 * d * m.d_expert * (m.top_k + m.num_shared)
         return full - moe_layers * (all_experts - active)
 

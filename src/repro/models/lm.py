@@ -1,8 +1,9 @@
 """Top-level language model: embedding → scanned block groups → head.
 
 Depth is folded into a ``lax.scan`` over stacked per-group parameters so the
-HLO (and compile time at 512 devices) is O(1) in num_layers; pattern
-remainders run unstacked as "tail" blocks.  Supports decoder-only, prefix-LM
+HLO (and compile time at 512 devices) is O(1) in num_layers; leading dense
+layers (``cfg.first_dense``) run unstacked as "lead" blocks before the scan,
+pattern remainders as "tail" blocks after it.  Supports decoder-only, prefix-LM
 (VLM stub embeddings), and encoder-decoder (whisper stub frames).
 
 All entry points are pure functions over (params, cfg, inputs) — pjit them
@@ -65,12 +66,12 @@ def init(key, cfg: ModelConfig) -> Params:
             lambda k, kd=kind: blocks.block_init(kd, k, cfg))(gkeys)
     p["groups"] = groups
 
-    tail = {}
-    for i, kind in enumerate(cfg.tail_blocks):
-        tail[str(i)] = blocks.block_init(
-            kind, jax.random.fold_in(keys[3], i), cfg)
-    if tail:
-        p["tail"] = tail
+    for name, kinds, k in (("lead", cfg.lead_blocks, keys[6]),
+                           ("tail", cfg.tail_blocks, keys[3])):
+        if kinds:
+            p[name] = {str(i): blocks.block_init(
+                kind, jax.random.fold_in(k, i), cfg)
+                for i, kind in enumerate(kinds)}
 
     if cfg.is_encdec:
         e = cfg.encoder
@@ -159,15 +160,44 @@ def _make_ctx(cfg: ModelConfig, t: int, enc_out, impl: str,
                     impl=impl, prefix_len=prefix_len, lengths=lengths)
 
 
+def _fold_aux(acc: jax.Array, a: jax.Array) -> jax.Array:
+    """Accumulate one block's aux (see ``blocks.block_apply``): the loss
+    and the pairs add, the busiest expert's pairs take the max."""
+    return jnp.concatenate([acc[:2] + a[:2], jnp.maximum(acc[2:], a[2:])])
+
+
+def _run_unstacked(p: Params, cfg: ModelConfig, name: str, kinds, x, ctx,
+                   cache: Params | None, new_cache, aux):
+    """The unstacked ``lead`` or ``tail`` layers, each with its own cache."""
+    if name not in p:
+        return x, aux
+    layer_caches = cache.get(name, {}) if cache is not None else {}
+    out = {}
+    for i, kind in enumerate(kinds):
+        x, c, a = blocks.block_apply(kind, p[name][str(i)], cfg, x, ctx,
+                                     layer_caches.get(str(i)))
+        out[str(i)] = c
+        aux = _fold_aux(aux, a)
+    if cache is not None:
+        new_cache[name] = out
+    return x, aux
+
+
 def _run_blocks(p: Params, cfg: ModelConfig, x: jax.Array, ctx: BlockCtx,
                 cache: Params | None, remat: bool = False
                 ) -> tuple[jax.Array, Params | None, jax.Array]:
-    """Scanned groups + tail.  cache=None for pure training forward.
+    """Lead + scanned groups + tail.  cache=None for pure training forward.
 
     ``remat=True`` checkpoints each scanned group (activation recompute in
     the backward pass) — the standard memory/compute trade for deep stacks.
+    The aux is f32[3]: the MoE load-balancing loss and the expert pairs
+    computed, summed over layers, and the most pairs any one held expert
+    took in one layer.
     """
-    aux0 = jnp.zeros((), jnp.float32)
+    aux0 = jnp.zeros((3,), jnp.float32)
+    new_cache = dict(cache) if cache is not None else None
+    x, aux0 = _run_unstacked(p, cfg, "lead", cfg.lead_blocks, x, ctx, cache,
+                             new_cache, aux0)
 
     if cache is None:
         def body(carry, gp):
@@ -181,15 +211,13 @@ def _run_blocks(p: Params, cfg: ModelConfig, x: jax.Array, ctx: BlockCtx,
             for i, kind in enumerate(cfg.block_pattern):
                 y, _, a = blocks.block_apply(kind, gp[str(i)], cfg, y, ctx,
                                              None)
-                aux = aux + a
+                aux = _fold_aux(aux, a)
             return (y, aux), None
 
         if remat:
             body = jax.checkpoint(body, prevent_cse=False)
         (x, aux), _ = jax.lax.scan(body, (x, aux0), p["groups"],
                                     unroll=_UNROLL)
-        new_cache = None
-        tail_cache = {}
     else:
         def body_c(carry, inp):
             y, aux = carry
@@ -199,25 +227,15 @@ def _run_blocks(p: Params, cfg: ModelConfig, x: jax.Array, ctx: BlockCtx,
                 y, c, a = blocks.block_apply(kind, gp[str(i)], cfg, y, ctx,
                                              gc[str(i)])
                 new_gc[str(i)] = c
-                aux = aux + a
+                aux = _fold_aux(aux, a)
             return (y, aux), new_gc
 
-        (x, aux), new_groups = jax.lax.scan(
+        (x, aux), new_cache["groups"] = jax.lax.scan(
             body_c, (x, aux0), (p["groups"], cache["groups"]),
             unroll=_UNROLL)
-        new_cache = dict(cache, groups=new_groups)
-        tail_cache = cache.get("tail", {})
 
-    if "tail" in p:
-        new_tail = {}
-        for i, kind in enumerate(cfg.tail_blocks):
-            c_in = tail_cache.get(str(i)) if cache is not None else None
-            x, c, a = blocks.block_apply(kind, p["tail"][str(i)], cfg, x,
-                                         ctx, c_in)
-            new_tail[str(i)] = c
-            aux = aux + a
-        if cache is not None:
-            new_cache["tail"] = new_tail
+    x, aux = _run_unstacked(p, cfg, "tail", cfg.tail_blocks, x, ctx, cache,
+                            new_cache, aux)
     return x, new_cache, aux
 
 
@@ -241,7 +259,7 @@ def forward(p: Params, cfg: ModelConfig, tokens: jax.Array,
     ctx = _make_ctx(cfg, x.shape[1], enc_out, impl, prefix_len)
     x, _, aux = _run_blocks(p, cfg, x, ctx, None, remat=remat)
     x = common.apply_norm(p["final_norm"], x, cfg.norm_type, cfg.norm_eps)
-    return _head(p, cfg, x), aux
+    return _head(p, cfg, x), aux[0]
 
 
 def loss_fn(p: Params, cfg: ModelConfig, batch: dict[str, jax.Array],
@@ -297,9 +315,10 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
             lambda x: jnp.broadcast_to(x, (cfg.pattern_groups,) + x.shape)
             .copy() if hasattr(x, "shape") else x, one)
     cache: dict[str, Any] = {"groups": groups}
-    if "tail" in specs:
-        cache["tail"] = {i: spec.init()
-                         for i, spec in specs["tail"].items()}
+    for name in ("lead", "tail"):
+        if name in specs:
+            cache[name] = {i: spec.init()
+                           for i, spec in specs[name].items()}
     return cache
 
 
@@ -331,7 +350,7 @@ def prefill(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
         # carry-through (padding steps are exact identities per row).
         ragged_ok = {"attn", "local", "moe", "mla", "mla_moe",
                      "rglru", "slstm", "mlstm"}
-        kinds = set(cfg.block_pattern) | set(cfg.tail_blocks)
+        kinds = set(cfg.all_blocks)
         if (kinds - ragged_ok or cfg.num_prefix_tokens or cfg.is_encdec):
             raise NotImplementedError(
                 f"ragged prefill supports decoder-only patterns without "
@@ -375,7 +394,7 @@ MIXED_OK = {"attn", "local", "moe", "mla", "mla_moe",
 
 def mixed_step(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
                start: jax.Array, span: jax.Array, impl: str = "ref",
-               all_logits: bool = False) -> tuple[jax.Array, Params]:
+               all_logits: bool = False):
     """Token-budget mixed step: per-row query spans in one batched call.
 
     tokens: i32[B, C] right-padded span tokens; start: i32[B] tokens already
@@ -383,7 +402,10 @@ def mixed_step(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
     span of ``span[b]`` queries at positions ``start[b] + [0, span[b])`` —
     span 1 decodes one token, span C admits one prompt chunk, span 0 leaves
     the row's cache bit-for-bit untouched.  Returns (logits [B, V] at each
-    row's last valid span position, cache); span-0 rows' logits are garbage.
+    row's last valid span position, cache, counts); span-0 rows' logits are
+    garbage.  counts f32[2] are the step's expert pairs computed on held
+    experts and the busiest held expert's pairs in any one layer (zeros
+    without experts).
     With ``all_logits`` (the speculative-decoding verify mode) the head runs
     at EVERY span position and logits are [B, C, V] — position j's logits
     predict the token after span token j, so a drafted continuation can be
@@ -394,7 +416,7 @@ def mixed_step(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
     chunk partitioning cannot change the bits, which is what makes chunked
     admission bit-for-bit equivalent to a one-shot prefill.
     """
-    kinds = set(cfg.block_pattern) | set(cfg.tail_blocks)
+    kinds = set(cfg.all_blocks)
     if (kinds - MIXED_OK or cfg.num_prefix_tokens or cfg.is_encdec):
         raise NotImplementedError(
             f"mixed step supports decoder-only patterns without prefix or "
@@ -412,15 +434,16 @@ def mixed_step(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
     positions = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
     ctx = BlockCtx(positions=positions, mask_full=None, mask_local=None,
                    mode="mixed", pos=start, impl=impl, lengths=span)
-    x, cache, _ = _run_blocks(p, cfg, x, ctx, cache)
-    if all_logits:
-        x = common.apply_norm(p["final_norm"], x, cfg.norm_type, cfg.norm_eps)
-        return _head(p, cfg, x), cache
-    last = jnp.clip(span - 1, 0)[:, None, None]
-    x = jnp.take_along_axis(
-        x, jnp.broadcast_to(last, (b, 1, x.shape[2])), axis=1)
+    x, cache, aux = _run_blocks(p, cfg, x, ctx, cache)
+    if not all_logits:
+        last = jnp.clip(span - 1, 0)[:, None, None]
+        x = jnp.take_along_axis(
+            x, jnp.broadcast_to(last, (b, 1, x.shape[2])), axis=1)
     x = common.apply_norm(p["final_norm"], x, cfg.norm_type, cfg.norm_eps)
-    return _head(p, cfg, x)[:, 0], cache
+    logits = _head(p, cfg, x)
+    if not all_logits:
+        logits = logits[:, 0]
+    return logits, cache, aux[1:]
 
 
 def verify_step(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
@@ -440,12 +463,25 @@ def verify_step(p: Params, cfg: ModelConfig, tokens: jax.Array, cache: Params,
     and a committed-span replay for recurrent architectures).
     """
     from repro.kernels import ref as kref
-    logits, cache = mixed_step(p, cfg, tokens, cache, start, span, impl=impl,
-                               all_logits=True)
+    logits, cache, _ = mixed_step(p, cfg, tokens, cache, start, span,
+                                  impl=impl, all_logits=True)
     preds = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     accepted = kref.speculative_accept(
         preds, jnp.asarray(tokens, jnp.int32), jnp.asarray(span, jnp.int32))
     return preds, accepted, cache
+
+
+def _state_layers(cfg: ModelConfig, cache: Params):
+    """(part, index, kind) of every recurrent (state-layout) layer cache:
+    part "groups" is stacked over the scan, "lead" / "tail" are not."""
+    for name, kinds in (("lead", cfg.lead_blocks),
+                        ("groups", cfg.block_pattern),
+                        ("tail", cfg.tail_blocks)):
+        if name not in cache:
+            continue
+        for i, kind in enumerate(kinds):
+            if cache_mod.layout_for(kind, cfg, paged=False) == "state":
+                yield name, str(i), kind
 
 
 def reset_state_rows(cfg: ModelConfig, cache: Params, mask: jax.Array
@@ -469,17 +505,10 @@ def reset_state_rows(cfg: ModelConfig, cache: Params, mask: jax.Array
 
         return jax.tree.map(one, fresh, layer)
 
-    out: dict[str, Any] = {"groups": dict(cache["groups"])}
-    for i, kind in enumerate(cfg.block_pattern):
-        if cache_mod.layout_for(kind, cfg, paged=False) == "state":
-            out["groups"][str(i)] = blend(kind, cache["groups"][str(i)],
-                                          stacked=True)
-    if "tail" in cache:
-        out["tail"] = dict(cache["tail"])
-        for i, kind in enumerate(cfg.tail_blocks):
-            if cache_mod.layout_for(kind, cfg, paged=False) == "state":
-                out["tail"][str(i)] = blend(kind, cache["tail"][str(i)],
-                                            stacked=False)
+    out: dict[str, Any] = {}
+    for name, i, kind in _state_layers(cfg, cache):
+        out.setdefault(name, dict(cache[name]))[i] = blend(
+            kind, cache[name][i], stacked=name == "groups")
     return dict(cache, **out)
 
 
@@ -489,17 +518,8 @@ def snapshot_state_rows(cfg: ModelConfig, cache: Params) -> Params:
     see ``cache.snapshot_span``).  ``jnp.copy`` forces fresh buffers so the
     snapshot survives the verify call donating the live cache."""
     out: dict[str, Any] = {"groups": {}}
-    for i, kind in enumerate(cfg.block_pattern):
-        if cache_mod.layout_for(kind, cfg, paged=False) == "state":
-            out["groups"][str(i)] = jax.tree.map(jnp.copy,
-                                                 cache["groups"][str(i)])
-    if "tail" in cache:
-        tail = {}
-        for i, kind in enumerate(cfg.tail_blocks):
-            if cache_mod.layout_for(kind, cfg, paged=False) == "state":
-                tail[str(i)] = jax.tree.map(jnp.copy, cache["tail"][str(i)])
-        if tail:
-            out["tail"] = tail
+    for name, i, _ in _state_layers(cfg, cache):
+        out.setdefault(name, {})[i] = jax.tree.map(jnp.copy, cache[name][i])
     return out
 
 
@@ -526,17 +546,8 @@ def restore_state_rows(cfg: ModelConfig, cache: Params, snap: Params,
 
         return jax.tree.map(one, snap_layer, layer)
 
-    out: dict[str, Any] = {"groups": dict(cache["groups"])}
-    for i, kind in enumerate(cfg.block_pattern):
-        if cache_mod.layout_for(kind, cfg, paged=False) == "state":
-            out["groups"][str(i)] = blend(snap["groups"][str(i)],
-                                          cache["groups"][str(i)],
-                                          stacked=True)
-    if "tail" in cache:
-        out["tail"] = dict(cache["tail"])
-        for i, kind in enumerate(cfg.tail_blocks):
-            if cache_mod.layout_for(kind, cfg, paged=False) == "state":
-                out["tail"][str(i)] = blend(snap["tail"][str(i)],
-                                            cache["tail"][str(i)],
-                                            stacked=False)
+    out: dict[str, Any] = {}
+    for name, i, _ in _state_layers(cfg, cache):
+        out.setdefault(name, dict(cache[name]))[i] = blend(
+            snap[name][i], cache[name][i], stacked=name == "groups")
     return dict(cache, **out)

@@ -51,15 +51,28 @@ def _queries(p, cfg, x, positions):
     q = common.dense(p["w_q"], x).reshape(b, t, h, m.nope_head_dim + m.rope_head_dim)
     q = q.transpose(0, 2, 1, 3)                                  # [B,H,T,*]
     q_nope, q_rope = jnp.split(q, [m.nope_head_dim], axis=-1)
-    q_rope = common.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = common.apply_rope(q_rope, positions, cfg.rope_theta,
+                               cfg.rope_scaling)
     return q_nope, q_rope
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    """(nope + rope)^-1/2, times mscale(factor, mscale_all_dim)^2 under
+    YaRN (DeepSeek-V2's attention scale)."""
+    m = cfg.mla
+    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= common.yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 def _latents(p, cfg, x, positions):
     ckv = common.apply_norm(p["kv_norm"], common.dense(p["w_dkv"], x),
                             "rmsnorm", cfg.norm_eps)             # [B,T,r]
     krope = common.apply_rope(common.dense(p["w_kr"], x)[:, None],
-                              positions, cfg.rope_theta)[:, 0]   # [B,T,rope]
+                              positions, cfg.rope_theta,
+                              cfg.rope_scaling)[:, 0]            # [B,T,rope]
     return ckv, krope
 
 
@@ -86,7 +99,7 @@ def forward(p: Params, cfg: ModelConfig, x: jax.Array,
     kc = jnp.concatenate(
         [k_nope, jnp.broadcast_to(krope[:, None], (b, h, t, m.rope_head_dim)
                                   ).astype(k_nope.dtype)], axis=-1)
-    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    scale = softmax_scale(cfg)
     out = attention._sdpa(qc, kc, v, mask, scale, impl, chunked=chunked,
                           prefix_len=prefix_len)
     out = out.transpose(0, 2, 1, 3).reshape(b, t, h * m.v_head_dim)
@@ -187,7 +200,7 @@ def mixed_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Params,
     q_abs = jnp.einsum("bhcn,rhn->bhcr", q_nope.astype(jnp.float32),
                        w_uk.astype(jnp.float32),
                        preferred_element_type=jnp.float32)
-    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    scale = softmax_scale(cfg)
     layout = cache_mod.layout_of(cache)
     if layout in _PAGED_MLA:
         pool = cache["latent_pages"]
@@ -260,7 +273,7 @@ def decode_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Params,
                            q_nope[:, :, 0].astype(jnp.float32),
                            w_uk.astype(jnp.float32),
                            preferred_element_type=jnp.float32)
-        scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+        scale = softmax_scale(cfg)
         pool = cache["latent_pages"]
         dp = pool.shape[-1]
         lat_new = jnp.concatenate([ckv_t[:, 0], krope_t[:, 0]], axis=-1)
@@ -295,7 +308,7 @@ def decode_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Params,
     q_abs = jnp.einsum("bhn,rhn->bhr", q_nope[:, :, 0].astype(jnp.float32),
                        w_uk.astype(jnp.float32),
                        preferred_element_type=jnp.float32)
-    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    scale = softmax_scale(cfg)
     logits = (jnp.einsum("bhr,bsr->bhs", q_abs, ckv_c,
                          preferred_element_type=jnp.float32)
               + jnp.einsum("bhr,bsr->bhs", q_rope[:, :, 0], krope_c,

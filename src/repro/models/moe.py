@@ -1,134 +1,155 @@
 """Mixture-of-Experts FFN: DeepSeekMoE-style shared + fine-grained routed
-experts with top-k softmax routing.
+experts with top-k softmax routing, drop-free, over one chip's share.
 
-Two dispatch modes (a §Perf hillclimb axis):
+The router scores all ``moe.num_experts`` experts (softmax in f32, top-k
+over all of them, renormalised only with ``norm_topk_prob``).  This chip
+holds routed experts ``[first, first + held)`` (``cfg.held_experts``):
+a (token, expert) pair whose expert lies elsewhere contributes nothing
+here — on one chip the layer runs without the expert-parallel exchange —
+and the others are all computed, with no capacity and no dropped pair.
 
-  * ``gather`` — GShard/Switch capacity-based dispatch: tokens are packed
-    into [E, capacity] buffers with one-hot combine weights; expert matmuls
-    see only their assigned tokens, so compiled FLOPs track *active* params
-    (top_k/E of the expert pool, × capacity_factor slack).
-  * ``dense`` — every token through every expert, gated combine.  FLOP-waste
-    baseline (E/top_k× the compute) kept for roofline comparison.
+Live pairs are laid out grouped by held expert, each group starting on a
+tile of ``tm`` rows, and run through one grouped SwiGLU
+(``kernels.ops.moe_grouped_swiglu``) whose grid covers only the tiles
+that hold pairs.  ``valid`` marks the positions that are real tokens (a
+mixed step's span, a ragged prefill's length): padding is never routed.
+The shared experts run on every position, once.
 
-Load-balancing auxiliary loss (Switch-style) is returned for the trainer.
+Parameter leaves are named ``w``: ``router.w [d, E]``,
+``experts.{gate,up}.w [held, d, d_expert]``, ``experts.down.w
+[held, d_expert, d]``, ``shared`` likewise over the shared experts.
+
+Returns ``(y, aux, counts)``: the Switch load-balancing loss over the
+routed pairs (for the trainer), and f32[2] = (pairs computed here, the
+largest number of pairs one held expert took).
 """
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kops
 from repro.models import common
 from repro.models.config import ModelConfig
 
 Params = Any
 
 
+def _bank(key, n: int, d: int, h: int) -> Params:
+    kg, ku, kd = jax.random.split(key, 3)
+
+    def w(k, shape, fan_in):
+        return {"w": (jax.random.normal(k, shape, jnp.float32)
+                      * fan_in ** -0.5).astype(common.PARAM_DTYPE)}
+
+    return {"gate": w(kg, (n, d, h), d), "up": w(ku, (n, d, h), d),
+            "down": w(kd, (n, h, d), h)}
+
+
 def init(key, cfg: ModelConfig) -> Params:
     m = cfg.moe
     d, h = cfg.d_model, m.d_expert
     k_router, k_e, k_s = jax.random.split(key, 3)
-
-    def expert_bank(key, n):
-        kg, ku, kd = jax.random.split(key, 3)
-        scale = d ** -0.5
-        return {
-            "gate": (jax.random.normal(kg, (n, d, h), jnp.float32) * scale
-                     ).astype(common.PARAM_DTYPE),
-            "up": (jax.random.normal(ku, (n, d, h), jnp.float32) * scale
-                   ).astype(common.PARAM_DTYPE),
-            "down": (jax.random.normal(kd, (n, h, d), jnp.float32) * h ** -0.5
-                     ).astype(common.PARAM_DTYPE),
-        }
-
-    p = {
-        "router": common.dense_init(k_router, d, m.num_experts, False),
-        "experts": expert_bank(k_e, m.num_experts),
-    }
+    p = {"router": common.dense_init(k_router, d, m.num_experts),
+         "experts": _bank(k_e, cfg.held_experts[1], d, h)}
     if m.num_shared:
-        p["shared"] = expert_bank(k_s, m.num_shared)
+        p["shared"] = _bank(k_s, m.num_shared, d, h)
     return p
 
 
-def _expert_ffn(bank: Params, x: jax.Array) -> jax.Array:
-    """x: [E, C, d] through per-expert SwiGLU: [E, C, d]."""
-    g = jnp.einsum("ecd,edh->ech", x, bank["gate"].astype(x.dtype))
-    u = jnp.einsum("ecd,edh->ech", x, bank["up"].astype(x.dtype))
-    return jnp.einsum("ech,ehd->ecd", jax.nn.silu(g) * u,
-                      bank["down"].astype(x.dtype))
-
-
-def forward(p: Params, cfg: ModelConfig, x: jax.Array
-            ) -> tuple[jax.Array, jax.Array]:
-    """x: [B, T, d] -> (y, aux_loss)."""
+def route(p: Params, cfg: ModelConfig, xf: jax.Array):
+    """xf [N, d] -> (probs f32[N, E], gates f32[N, K], experts i32[N, K])."""
     m = cfg.moe
+    logits = jnp.matmul(xf.astype(jnp.float32),
+                        p["router"]["w"].astype(jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, idx = jax.lax.top_k(probs, m.top_k)
+    if m.norm_topk_prob:
+        gates = gates / jnp.clip(jnp.sum(gates, -1, keepdims=True), 1e-9)
+    return probs, gates, idx
+
+
+def tile_rows(pairs: int, num_experts: int) -> int:
+    """Rows per tile: the expected pairs of one expert (``pairs /
+    num_experts``) rounded up to a power of two in [16, 128]."""
+    want = -(-pairs // num_experts)
+    return min(128, max(16, 1 << max(want - 1, 0).bit_length()))
+
+
+def group_pairs(local: jax.Array, live: jax.Array, held: int, tm: int):
+    """Place live pairs grouped by local expert in tiles of ``tm`` rows.
+
+    local: i32[M] expert of each pair in [0, held); live: bool[M].
+    Returns (slot i32[M] row of each pair — out of range when dead,
+    tile_group i32[T], num_tiles i32[1], sizes i32[held]) with
+    T = ceil(M / tm) + held, enough for any routing.
+    """
+    m = local.shape[0]
+    e = jnp.where(live, local, held)                               # [M]
+    onehot = (e[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)
+    sizes = jnp.sum(onehot, axis=0)                                # [held]
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
+    tiles = (sizes + tm - 1) // tm
+    first_tile = jnp.cumsum(tiles) - tiles
+    n_tiles = -(-m // tm) + held
+    slot = jnp.where(live, first_tile[jnp.clip(e, 0, held - 1)] * tm + rank,
+                     n_tiles * tm)
+    tile_group = jnp.repeat(jnp.arange(held, dtype=jnp.int32), tiles,
+                            total_repeat_length=n_tiles)
+    return (slot.astype(jnp.int32), tile_group,
+            jnp.sum(tiles, keepdims=True).astype(jnp.int32), sizes)
+
+
+def _swiglu_bank(bank: Params, x: jax.Array) -> jax.Array:
+    """x [N, d] through every expert of ``bank``, summed: f32 [N, d]."""
+    g = jnp.einsum("nd,edh->enh", x, bank["gate"]["w"].astype(x.dtype))
+    u = jnp.einsum("nd,edh->enh", x, bank["up"]["w"].astype(x.dtype))
+    return jnp.einsum("enh,ehd->nd", jax.nn.silu(g) * u,
+                      bank["down"]["w"].astype(x.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def forward(p: Params, cfg: ModelConfig, x: jax.Array,
+            valid: Optional[jax.Array] = None):
+    """x: [B, T, d]; valid: bool[B, T] or None (all) -> (y, aux, counts)."""
+    m = cfg.moe
+    first, held = cfg.held_experts
     b, t, d = x.shape
     n = b * t
     xf = x.reshape(n, d)
-
-    logits = common.dense(p["router"], xf).astype(jnp.float32)   # [N, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, top_idx = jax.lax.top_k(probs, m.top_k)           # [N, K]
-    gate_vals = gate_vals / jnp.clip(
-        jnp.sum(gate_vals, -1, keepdims=True), 1e-9)             # renorm
+    probs, gates, idx = route(p, cfg, xf)                      # [N, K]
 
     # Switch aux loss: E * sum_e f_e * P_e.
-    onehot = jax.nn.one_hot(top_idx, m.num_experts, dtype=jnp.float32)
-    f_e = jnp.mean(jnp.sum(onehot, axis=1), axis=0)              # frac routed
-    p_e = jnp.mean(probs, axis=0)
-    aux = m.num_experts * jnp.sum(f_e * p_e)
+    onehot = jax.nn.one_hot(idx, m.num_experts, dtype=jnp.float32)
+    aux = m.num_experts * jnp.sum(jnp.mean(jnp.sum(onehot, axis=1), axis=0)
+                                  * jnp.mean(probs, axis=0))
 
-    # Tiny token counts (single-token decode) route densely: capacity math
-    # would drop tokens, and the dense pass is cheaper than the scatter.
-    if m.dispatch == "dense" or n <= m.num_experts:
-        all_out = _expert_ffn(p["experts"],
-                              jnp.broadcast_to(xf, (m.num_experts, n, d)))
-        combine = jnp.zeros((n, m.num_experts), xf.dtype)
-        combine = combine.at[jnp.arange(n)[:, None], top_idx].add(
-            gate_vals.astype(xf.dtype))
-        y = jnp.einsum("end,ne->nd", all_out, combine)
-    else:
-        # GShard-style GROUP-LOCAL dispatch (group = batch row): capacity and
-        # slot positions are computed within each row, so the scatter and the
-        # expert matmul partition cleanly as [B(data), E(model), C, *].  A
-        # global cumsum over all tokens would couple data shards and force
-        # GSPMD to materialize global-capacity buffers on every device
-        # (observed: ~100× FLOP inflation).
-        tk = m.top_k
-        capacity = max(int(m.capacity_factor * t * tk / m.num_experts), 1)
-        e_bt = top_idx.reshape(b, t * tk)                        # [B, T*K]
-        eq = jax.nn.one_hot(e_bt, m.num_experts, dtype=jnp.int32)
-        pos = jnp.cumsum(eq, axis=1) - 1                         # within-row
-        slot = jnp.take_along_axis(pos, e_bt[..., None], 2)[..., 0]
-        keep = slot < capacity
-        tok = jnp.repeat(jnp.arange(t), tk)                      # [T*K]
-
-        def dispatch_row(x_row, e_row, slot_row, keep_row):
-            buf = jnp.zeros((m.num_experts, capacity, d), x.dtype)
-            return buf.at[e_row, jnp.where(keep_row, slot_row, capacity)
-                          ].add(x_row[tok], mode="drop")
-
-        buf = jax.vmap(dispatch_row)(x, e_bt, slot, keep)        # [B,E,C,d]
-        gw = p["experts"]["gate"].astype(x.dtype)
-        uw = p["experts"]["up"].astype(x.dtype)
-        dw = p["experts"]["down"].astype(x.dtype)
-        g = jnp.einsum("becd,edh->bech", buf, gw)
-        u = jnp.einsum("becd,edh->bech", buf, uw)
-        out = jnp.einsum("bech,ehd->becd", jax.nn.silu(g) * u, dw)
-
-        def combine_row(out_row, e_row, slot_row, keep_row, gates_row):
-            gathered = out_row[e_row, jnp.clip(slot_row, 0, capacity - 1)]
-            w = (gates_row * keep_row).astype(out_row.dtype)
-            return jax.ops.segment_sum(gathered * w[:, None], tok,
-                                       num_segments=t)
-
-        y = jax.vmap(combine_row)(out, e_bt, slot, keep,
-                                  gate_vals.reshape(b, t * tk))  # [B,T,d]
-        y = y.reshape(n, d)
+    ok = (jnp.ones((n,), bool) if valid is None else valid.reshape(n))
+    local = (idx - first).reshape(-1)                          # [N*K]
+    live = (jnp.repeat(ok, m.top_k) & (local >= 0) & (local < held))
+    tm = tile_rows(n * m.top_k, m.num_experts)
+    slot, tile_group, num_tiles, sizes = group_pairs(
+        jnp.clip(local, 0, held - 1), live, held, tm)
+    rows = tile_group.shape[0] * tm
+    # Row -> pair (M for padding rows), then the pair's token.
+    pair_of_row = jnp.full((rows,), n * m.top_k, jnp.int32).at[slot].set(
+        jnp.arange(n * m.top_k, dtype=jnp.int32), mode="drop")
+    tok_of_row = jnp.where(pair_of_row < n * m.top_k,
+                           pair_of_row // m.top_k, 0)
+    ex = p["experts"]
+    ys = kops.moe_grouped_swiglu(
+        xf[tok_of_row], ex["gate"]["w"].astype(x.dtype),
+        ex["up"]["w"].astype(x.dtype), ex["down"]["w"].astype(x.dtype),
+        tile_group, num_tiles, tm=tm)                          # [rows, d]
+    # Rows of dead pairs are never read (unwritten rows may hold anything).
+    y_pair = jnp.where(live[:, None],
+                       ys[jnp.clip(slot, 0, rows - 1)].astype(jnp.float32)
+                       * gates.reshape(-1)[:, None], 0.0)
+    y = jnp.sum(y_pair.reshape(n, m.top_k, d), axis=1)
 
     if m.num_shared:
-        sh = _expert_ffn(p["shared"],
-                         jnp.broadcast_to(xf, (m.num_shared, n, d)))
-        y = y + jnp.sum(sh, axis=0)
-    return y.reshape(b, t, d), aux
+        y = y + _swiglu_bank(p["shared"], xf)
+    counts = jnp.stack([jnp.sum(live), jnp.max(sizes)]).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(b, t, d), aux, counts

@@ -109,14 +109,20 @@ def make_mixed_step_fn(cfg: ModelConfig, *, impl: Optional[str] = None,
     One call spends every row's span — 1 token for decoding rows, a prompt
     chunk for rows being admitted, 0 for idle rows — so admission never
     stalls decode.  ``next_token`` is sampled from each row's last valid
-    span position (garbage for span-0 rows; callers ignore it).
+    span position (garbage for span-0 rows; callers ignore it).  A model
+    with experts appends the step's two expert-pair counts (pairs computed
+    on held experts, the busiest held expert's pairs in one layer):
+    ``next_token`` is then i32[B + 2], read back in the same transfer.
     """
     impl = impl or ops.default_impl()
     def mixed_step(params, cache, tokens, start, span, rng):
-        logits, cache = lm.mixed_step(params, cfg, tokens, cache, start,
-                                      span, impl=impl)
+        logits, cache, counts = lm.mixed_step(params, cfg, tokens, cache,
+                                              start, span, impl=impl)
         nxt = sample_token(logits, rng, temperature)
-        return nxt, cache
+        if cfg.moe is None:
+            return nxt, cache
+        return jnp.concatenate([nxt.astype(jnp.int32),
+                                counts.astype(jnp.int32)]), cache
 
     return mixed_step
 

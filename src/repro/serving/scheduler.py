@@ -416,7 +416,7 @@ class ContinuousBatchingEngine:
             donate_argnums=(1,))
         self._has_state = any(
             cache_mod.layout_for(k, cfg, paged=False) == "state"
-            for k in tuple(cfg.block_pattern) + tuple(cfg.tail_blocks))
+            for k in cfg.all_blocks)
         if self._has_state:
             self._reset_state = jax.jit(
                 lambda c, m: lm.reset_state_rows(cfg, c, m),
@@ -524,7 +524,11 @@ class ContinuousBatchingEngine:
                       # Positions the dispatched mixed/verify steps computed
                       # (batch x width) and those that were rows' spans; the
                       # difference is padding.
-                      "step_positions": 0, "span_positions": 0}
+                      "step_positions": 0, "span_positions": 0,
+                      # Expert layers: (token, expert) pairs computed on
+                      # the held experts, and each step's busiest held
+                      # expert's pairs in one layer, summed over steps.
+                      "moe_pairs": 0, "moe_pairs_max": 0}
 
     # -- request lifecycle --------------------------------------------------
 
@@ -1234,6 +1238,11 @@ class ContinuousBatchingEngine:
                                               sub)
             with trace.span("engine.sync"):
                 sampled = np.asarray(nxt)          # the one per-step sync
+            if self.cfg.moe is not None:
+                # The step's expert-pair counts ride on the same transfer.
+                self.stats["moe_pairs"] += int(sampled[self.batch])
+                self.stats["moe_pairs_max"] += int(sampled[self.batch + 1])
+                sampled = sampled[:self.batch]
         self.stats["steps"] += 1
         with trace.span("engine.commit"):
             chunks = 0

@@ -89,11 +89,12 @@ class Partitioner:
             return P(self._m(shape[0]), None)            # vocab rows
         if parent in self._REPL or name in self._REPL:
             return P(*([None] * len(shape)))
-        # MoE expert banks: expert-parallel on dim 0.
-        if parent == "experts":
+        # MoE expert banks ({gate,up,down}.w [E, ., .]): expert-parallel
+        # on dim 0; shared experts split their hidden width.
+        if grandparent == "experts":
             return P(self._m(shape[0]), None, None)
-        if parent == "shared":
-            if name == "down":
+        if grandparent == "shared":
+            if parent == "down":
                 return P(None, self._m(shape[1]), None)
             return P(None, None, self._m(shape[2]))
         if name == "r" and len(shape) == 3:               # sLSTM recurrent

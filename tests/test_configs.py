@@ -17,7 +17,7 @@ ASSIGNED = {
     "whisper-tiny": (4, 384, 6, 6, 1536, 51865),
     "recurrentgemma-2b": (26, 2560, 10, 1, 7680, 256000),
     "deepseek-moe-16b": (28, 2048, 16, 16, 1408, 102400),
-    "deepseek-v2-lite-16b": (27, 2048, 16, 16, 1408, 102400),
+    "deepseek-v2-lite-16b": (27, 2048, 16, 16, 10944, 102400),
 }
 
 

@@ -81,9 +81,9 @@ def _chunked_admit(cfg, params, cache, prompts, lengths, chunk, impl="ref"):
         toks = np.zeros((len(lengths), chunk), np.int32)
         for b in range(len(lengths)):
             toks[b, :span[b]] = prompts[b, filled[b]:filled[b] + span[b]]
-        lg, cache = lm.mixed_step(params, cfg, jnp.asarray(toks), cache,
-                                  jnp.asarray(filled, jnp.int32),
-                                  jnp.asarray(span, jnp.int32), impl=impl)
+        lg, cache, _ = lm.mixed_step(params, cfg, jnp.asarray(toks), cache,
+                                     jnp.asarray(filled, jnp.int32),
+                                     jnp.asarray(span, jnp.int32), impl=impl)
         if logits is None:
             logits = np.array(lg)
         else:

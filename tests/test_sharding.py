@@ -56,8 +56,10 @@ def test_fsdp_adds_data_axis():
 
 def test_moe_expert_parallel():
     part = Partitioner(FakeMesh(), fsdp=False)
-    assert part.param_spec(("ffn", "experts", "gate"), (64, 2048, 1408)) == \
-        P("model", None, None)
+    assert part.param_spec(("ffn", "experts", "gate", "w"),
+                           (64, 2048, 1408)) == P("model", None, None)
+    assert part.param_spec(("ffn", "shared", "down", "w"),
+                           (2, 1408, 2048)) == P(None, "model", None)
 
 
 def test_cache_specs_head_vs_seq():

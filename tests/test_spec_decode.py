@@ -201,16 +201,16 @@ def test_verify_rollback_bitwise_and_streams(llm, paged):
     rng = np.random.RandomState(0)
     plen, k = 6, 4
     prompts = rng.randint(0, V, size=(B, plen)).astype(np.int32)
-    lg, cache = lm.mixed_step(params, cfg, jnp.asarray(prompts), cache,
-                              jnp.zeros(B, jnp.int32),
-                              jnp.full(B, plen, jnp.int32))
+    lg, cache, _ = lm.mixed_step(params, cfg, jnp.asarray(prompts), cache,
+                                 jnp.zeros(B, jnp.int32),
+                                 jnp.full(B, plen, jnp.int32))
     t0 = np.asarray(jnp.argmax(lg, -1)).astype(np.int32)
 
     # Never-drafted greedy reference (width-1 decode) for stream identity.
     cache_ref = jax.tree.map(jnp.copy, cache)
     toks_ref, cur, pos = [], t0.copy(), np.full(B, plen, np.int64)
     for _ in range(10):
-        lg2, cache_ref = lm.mixed_step(
+        lg2, cache_ref, _ = lm.mixed_step(
             params, cfg, jnp.asarray(cur[:, None]), cache_ref,
             jnp.asarray(pos, jnp.int32), jnp.ones(B, jnp.int32))
         cur = np.asarray(jnp.argmax(lg2, -1)).astype(np.int32)
@@ -259,9 +259,9 @@ def test_verify_rollback_bitwise_and_streams(llm, paged):
                                       jnp.asarray(mask))
         spans2 = np.where(mask, n_app, 0).astype(np.int32)
         w2 = int(spans2.max())
-        _, cache = lm.mixed_step(params, cfg, jnp.asarray(toks[:, :w2]),
-                                 cache, jnp.asarray(start),
-                                 jnp.asarray(spans2))
+        _, cache, _ = lm.mixed_step(params, cfg, jnp.asarray(toks[:, :w2]),
+                                    cache, jnp.asarray(start),
+                                    jnp.asarray(spans2))
     post = cache_mod.snapshot_span(cache, jnp.asarray(start), 1 + k)
     want = cache_mod.snapshot_span(pre, jnp.asarray(start), 1 + k)
     for la, lp in zip(jax.tree.leaves(post), jax.tree.leaves(want)):
@@ -277,9 +277,9 @@ def test_verify_rollback_bitwise_and_streams(llm, paged):
     cur = np.array([toks_ref[b, acc[b]] for b in range(B)], np.int32)
     pos = plen + n_app.astype(np.int64)
     for i in range(4):
-        lg3, cache = lm.mixed_step(params, cfg, jnp.asarray(cur[:, None]),
-                                   cache, jnp.asarray(pos, jnp.int32),
-                                   jnp.ones(B, jnp.int32))
+        lg3, cache, _ = lm.mixed_step(params, cfg, jnp.asarray(cur[:, None]),
+                                      cache, jnp.asarray(pos, jnp.int32),
+                                      jnp.ones(B, jnp.int32))
         cur = np.asarray(jnp.argmax(lg3, -1)).astype(np.int32)
         for b in range(B):
             assert int(cur[b]) == int(toks_ref[b, int(n_app[b]) + i])
@@ -317,6 +317,9 @@ def _run_sched(cfg, params, prompts, spec, *, drafter=None, paged=True,
 
 @pytest.mark.parametrize("arch", ["mha", "mla", "hybrid"])
 def test_scheduler_spec_streams_match_greedy(arch, request):
+    """n-gram drafts (accepted or rolled back) leave the greedy streams
+    as they are and save steps; drafts of the greedy streams themselves
+    save steps on every architecture."""
     cfg, params = request.getfixturevalue(f"{arch}_llm")
     prompts = _spec_prompts(np.random.RandomState(0))
     eng0, base = _run_sched(cfg, params, prompts, "off")
@@ -324,8 +327,22 @@ def test_scheduler_spec_streams_match_greedy(arch, request):
     assert got == base
     assert eng1.stats["draft_tokens"] > 0
     assert eng1.stats["accepted_tokens"] > 0
-    assert eng1.stats["steps"] < eng0.stats["steps"]
+    if arch == "mla":
+        # The mla fixture reduces deepseek-v2-lite (routed experts, YaRN,
+        # a dense lead layer): on its random weights the n-gram drafts of
+        # these prompts are mostly wrong, so they may save nothing, but
+        # they never cost a step.  The DocDrafter run below checks the
+        # saving on this architecture.
+        assert eng1.stats["steps"] <= eng0.stats["steps"]
+    else:
+        assert eng1.stats["steps"] < eng0.stats["steps"]
     assert eng1.allocator.available == eng1.allocator.num_pages
+    doc = draft_mod.DocDrafter()
+    doc.set_docs([list(p) + list(t) for p, t in zip(prompts, base)])
+    eng2, got2 = _run_sched(cfg, params, prompts, "doc", drafter=doc)
+    assert got2 == base
+    assert eng2.stats["steps"] < eng0.stats["steps"]
+    assert eng2.allocator.available == eng2.allocator.num_pages
 
 
 def test_scheduler_doc_drafter_beats_ngram_on_converged_docs(mha_llm):

@@ -4,8 +4,9 @@ Each test compiles for one chip of a described (not attached) ``v5e:2x2``
 topology with ``interpret=False`` and checks that the program holds the
 Mosaic kernel (``tpu_custom_call``).  Shapes are olmo-1b's (16 MHA heads,
 head_dim 128, bf16 pools, page 64, batch 8) and deepseek-v2-lite's latent
-width for MLA; the chunk kernel also compiles at each served cell's shapes
-(olmo-1b and starcoder2-15b, batch 32).  The quantized twins use page 128,
+width for MLA; the chunk kernels also compile at each served cell's shapes
+(olmo-1b, starcoder2-15b and deepseek-v2-lite, batch 32), and so does
+deepseek-v2-lite's grouped expert kernel.  The quantized twins use page 128,
 because their f32 scale rows are one page wide and a TPU row is 128 lanes.
 Mosaic refuses what interpret mode accepts (unaligned slices, oversized
 VMEM), so these guard every change to the kernels at no chip time.
@@ -114,6 +115,84 @@ def test_paged_chunk_attention_compiles_for_v5e_at_served_shapes(
         new)
     ops_ = _kernel_ops(compiled)
     assert len(ops_) == 1 and ops_[0].startswith("paged_chunk_attention")
+
+
+@pytest.mark.parametrize("c", [1, 32])
+def test_paged_mla_chunk_compiles_for_v5e_at_served_pool(spec, c):
+    """deepseek-v2-lite's served latent pool: 640 pages and the trash page,
+    rows of 640 lanes (512 + 64 padded), batch 32, 32 pages a row."""
+    from repro.kernels import paged_chunk_attention as pc
+    pool = spec((641, PS, MLA_DP), jnp.bfloat16)
+    bt, vec = spec((32, 32), jnp.int32), spec((32,), jnp.int32)
+    compiled = _compile(
+        lambda q, lp, bt, st, sp, ln: pc.paged_mla_chunk(
+            q, lp, bt, st, sp, ln, r=MLA_R, scale=0.1),
+        spec((32, H, c, MLA_R + MLA_RD), jnp.float32), pool, bt, vec, vec,
+        spec((32, c, MLA_DP), jnp.bfloat16))
+    ops_ = _kernel_ops(compiled)
+    assert len(ops_) == 1 and ops_[0].startswith("paged_mla_chunk")
+
+
+@pytest.mark.parametrize("width", [1, 32])
+def test_moe_grouped_matmul_compiles_for_v5e_at_served_shapes(
+        one_chip, monkeypatch, width):
+    """One expert layer of deepseek-v2-lite as served: d 2048, experts of
+    1408, 16 of 64 held, top 6, a batch of 32 rows at span widths 1 and
+    32.  The routed experts run in one Mosaic kernel, and its event keeps
+    the name the benchmark's matcher sums."""
+    import repro.configs as configs
+    from repro.kernels import ops
+    from repro.models import moe
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = configs.get("deepseek-v2-lite-16b").replace(experts_held=16)
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: moe.init(jax.random.PRNGKey(0), cfg)))
+    assert params["experts"]["gate"]["w"].shape == (16, 2048, 1408)
+    x = jax.ShapeDtypeStruct((32, width, 2048), jnp.bfloat16,
+                             sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((32, width), jnp.bool_, sharding=one_chip)
+    compiled = _compile(lambda p, x, v: moe.forward(p, cfg, x, v),
+                        params, x, valid)
+    ops_ = _kernel_ops(compiled)
+    assert len(ops_) == 1 and ops_[0].startswith("moe_grouped_matmul")
+
+
+def test_moe_layer_differentiates_for_v5e(one_chip, monkeypatch):
+    """The trainer's path: the gradient of a loss through one expert layer
+    (deepseek-v2-lite widths, all 64 experts held, 2 x 256 tokens)
+    compiles for v5e.  The forward keeps the Mosaic kernel; its backward
+    runs as XLA grouped matmuls."""
+    import repro.configs as configs
+    from repro.kernels import ops
+    from repro.models import moe
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = configs.get("deepseek-v2-lite-16b")
+    assert cfg.held_experts == (0, 64)
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: moe.init(jax.random.PRNGKey(0), cfg)))
+    x = jax.ShapeDtypeStruct((2, 256, 2048), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        y, aux, _ = moe.forward(p, cfg, x)
+        return jnp.mean(jnp.square(y.astype(jnp.float32))) + 0.01 * aux
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)), params, x)
+    ops_ = _kernel_ops(compiled)
+    assert len([o for o in ops_ if "moe_grouped_matmul" in o]) == 1
+    assert any(o.startswith("ragged-dot") for o in ops_)
 
 
 @pytest.mark.parametrize("kernel", ["paged_chunk_attention",
