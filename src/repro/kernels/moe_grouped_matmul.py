@@ -20,6 +20,13 @@ follows the public megablox ``gmm`` (jax.experimental.pallas.ops.tpu),
 with group-aligned tiles instead of shared boundary tiles and the three
 matmuls of the expert fused.
 
+The banks arrive as the stacks of every layer, ``[L, G, d, h]``, with the
+layer index in the scalar prefetch (``layer``, i32[1]): the weight blocks'
+index maps address that layer of the stack, so a layer scan closes over
+the stacks and no step slices a layer's bank out of them.  A caller that
+holds one layer's bank (the trainer's scan) passes a stack of one
+(``kernels.ops`` does: the leading axis is a free reshape).
+
 The kernel is forward only (Pallas cannot differentiate a dynamic grid or
 scalar-prefetched operands).  Its VJP recomputes the layer with
 ``jax.lax.ragged_dot`` over the same layout, where the groups are the
@@ -53,7 +60,8 @@ def hidden_block(d: int, h: int, itemsize: int = 2) -> int:
     return best
 
 
-def _kernel(tg_ref, nt_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref):
+def _kernel(layer_ref, tg_ref, nt_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
+            acc_ref):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -74,15 +82,15 @@ def _kernel(tg_ref, nt_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref):
 
 def moe_grouped_swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
                        w_down: jax.Array, tile_group: jax.Array,
-                       num_tiles: jax.Array, *, tm: int,
+                       num_tiles: jax.Array, *, tm: int, layer: jax.Array,
                        interpret: bool = False) -> jax.Array:
     """x: [T*tm, d] pair rows in group-aligned tiles; w_gate / w_up:
-    [G, d, h]; w_down: [G, h, d]; tile_group: i32[T] (expert of each
-    tile, non-decreasing over the live tiles); num_tiles: i32[1] live
-    tiles.  Returns [T*tm, d] in x's dtype, rows of live tiles only (the
-    rest unwritten)."""
-    return _grouped(x, w_gate, w_up, w_down, tile_group, num_tiles, tm,
-                    interpret)
+    [L, G, d, h] and w_down: [L, G, h, d] stacks addressed at ``layer``
+    (i32[1]); tile_group: i32[T] (expert of each tile, non-decreasing over
+    the live tiles); num_tiles: i32[1] live tiles.  Returns [T*tm, d] in
+    x's dtype, rows of live tiles only (the rest unwritten)."""
+    return _grouped(x, w_gate, w_up, w_down, tile_group, num_tiles, layer,
+                    tm, interpret)
 
 
 def ragged_swiglu(x, w_gate, w_up, w_down, tile_group, num_tiles, *,
@@ -100,47 +108,52 @@ def ragged_swiglu(x, w_gate, w_up, w_down, tile_group, num_tiles, *,
     return dot(a, w_down).astype(x.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _grouped(x, w_gate, w_up, w_down, tile_group, num_tiles, tm, interpret):
-    return _forward(x, w_gate, w_up, w_down, tile_group, num_tiles, tm,
-                    interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _grouped(x, w_gate, w_up, w_down, tile_group, num_tiles, layer, tm,
+             interpret):
+    return _forward(x, w_gate, w_up, w_down, tile_group, num_tiles, layer,
+                    tm, interpret)
 
 
-def _grouped_fwd(x, w_gate, w_up, w_down, tile_group, num_tiles, tm,
+def _grouped_fwd(x, w_gate, w_up, w_down, tile_group, num_tiles, layer, tm,
                  interpret):
-    y = _forward(x, w_gate, w_up, w_down, tile_group, num_tiles, tm,
+    y = _forward(x, w_gate, w_up, w_down, tile_group, num_tiles, layer, tm,
                  interpret)
-    return y, (x, w_gate, w_up, w_down, tile_group, num_tiles)
+    return y, (x, w_gate, w_up, w_down, tile_group, num_tiles, layer)
 
 
 def _grouped_bwd(tm, interpret, res, ct):
-    x, w_gate, w_up, w_down, tile_group, num_tiles = res
-    _, vjp = jax.vjp(lambda *a: ragged_swiglu(*a, tile_group, num_tiles,
-                                              tm=tm),
-                     x, w_gate, w_up, w_down)
-    return (*vjp(ct), None, None)
+    x, w_gate, w_up, w_down, tile_group, num_tiles, layer = res
+    _, vjp = jax.vjp(lambda x, *w: ragged_swiglu(
+        x, *(b[layer[0]] for b in w), tile_group, num_tiles, tm=tm),
+        x, w_gate, w_up, w_down)
+    return (*vjp(ct), None, None, None)
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def _forward(x, w_gate, w_up, w_down, tile_group, num_tiles, tm, interpret):
+def _forward(x, w_gate, w_up, w_down, tile_group, num_tiles, layer, tm,
+             interpret):
     rows, d = x.shape
-    _, _, h = w_gate.shape
+    h = w_gate.shape[-1]
     th = hidden_block(d, h, w_gate.dtype.itemsize)
     if rows % tm:
         raise ValueError(f"{rows} pair rows are not whole tiles of {tm}")
 
+    # Weight blocks of the tile's expert, in layer ``layer`` of the stacks.
+    up = pl.BlockSpec((None, None, d, th),
+                      lambda t, j, lyr, tg, nt: (lyr[0], tg[t], 0, j))
+    down = pl.BlockSpec((None, None, th, d),
+                        lambda t, j, lyr, tg, nt: (lyr[0], tg[t], j, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                  # tile_group, num_tiles
+        num_scalar_prefetch=3,                  # layer, tile_group, num_tiles
         grid=(num_tiles[0], h // th),
         in_specs=[
-            pl.BlockSpec((tm, d), lambda t, j, tg, nt: (t, 0)),
-            pl.BlockSpec((None, d, th), lambda t, j, tg, nt: (tg[t], 0, j)),
-            pl.BlockSpec((None, d, th), lambda t, j, tg, nt: (tg[t], 0, j)),
-            pl.BlockSpec((None, th, d), lambda t, j, tg, nt: (tg[t], j, 0)),
+            pl.BlockSpec((tm, d), lambda t, j, lyr, tg, nt: (t, 0)),
+            up, up, down,
         ],
-        out_specs=pl.BlockSpec((tm, d), lambda t, j, tg, nt: (t, 0)),
+        out_specs=pl.BlockSpec((tm, d), lambda t, j, lyr, tg, nt: (t, 0)),
         scratch_shapes=[pltpu.VMEM((tm, d), jnp.float32)],
     )
     return pl.pallas_call(
@@ -151,4 +164,4 @@ def _forward(x, w_gate, w_up, w_down, tile_group, num_tiles, tm, interpret):
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="moe_grouped_matmul",
-    )(tile_group, num_tiles, x, w_gate, w_up, w_down)
+    )(layer, tile_group, num_tiles, x, w_gate, w_up, w_down)

@@ -246,22 +246,57 @@ def paged_decode_attention_quant(q, k_pages, k_scales, v_pages, v_scales,
         interpret=not on_tpu)
 
 
+def _layer_operand(layer) -> jax.Array:
+    """A kernel's scalar-prefetch layer operand: i32[1]."""
+    return jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+
+
+def _one_layer_as_stack(*pool_args: int):
+    """Let a wrapper over ``[L, P, ...]`` pool stacks also take one layer's
+    pools, with ``layer`` None: the pools at positions ``pool_args`` become
+    stacks of one layer and the returned pools lose that axis again.  Both
+    are free reshapes, so the kernels still update the pools in place."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, layer=None, **kw):
+            if layer is not None:
+                return fn(*args, layer=layer, **kw)
+            out, *pools = fn(*(a[None] if i in pool_args else a
+                               for i, a in enumerate(args)), layer=0, **kw)
+            return (out, *(p[0] for p in pools))
+        return call
+    return wrap
+
+
+def _ref_at(layer, run, *pools):
+    """The oracle on layer ``layer`` of ``[L, P, ...]`` pool stacks: run
+    ``run(*pools_of_that_layer) -> (out, *new_pools)`` and set the new
+    pools back into their stacks."""
+    out, *new = run(*(p[layer] for p in pools))
+    return (out, *(p.at[layer].set(n) for p, n in zip(pools, new)))
+
+
+@_one_layer_as_stack(1, 2)
 def paged_chunk_attention(q, k_pages, v_pages, block_tables, start, span,
-                          k_new, v_new, *, scale: float | None = None,
+                          k_new, v_new, *, layer=None,
+                          scale: float | None = None,
                           window: int | None = None, use_pallas: bool = True):
     """Chunked mixed-step attention over a paged KV cache, writes fused.
 
-    q: [B, Hq, C, D] per-row query spans; k_pages, v_pages: [P, Hkv, ps, D];
+    q: [B, Hq, C, D] per-row query spans; k_pages, v_pages: [L, P, Hkv, ps,
+    D] stacks of every layer's pool with ``layer`` (i32 scalar) the layer
+    this call reads and writes, or [P, Hkv, ps, D] with ``layer`` None;
     block_tables: i32[B, maxp]; start: i32[B] tokens already cached; span:
     i32[B] valid new tokens in [0, C]; k_new, v_new: [B, Hkv, C, D].
     Returns (out [B, Hq, C, D], k_pages, v_pages) — the span's K/V written
-    at slots ``start..start+span`` (in place on TPU via aliasing).
+    at slots ``start..start+span`` of that layer (in place on TPU via
+    aliasing; every other layer untouched).
 
     Span 1 is the fused decode step; span C is one prompt chunk.  Like the
     decode wrapper, the pool is never padded per step — on TPU it must be
     tileable at init; off-TPU the kernel runs in interpret mode.
     """
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[-2]
     maxp = block_tables.shape[1]
     # Clamp start to table capacity on BOTH paths (one contract with the
     # decode wrapper): writes past the table drop and the walk stays in
@@ -269,9 +304,9 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, start, span,
     start = jnp.minimum(start, maxp * ps - 1)
     span = jnp.clip(span, 0, q.shape[2])
     if not use_pallas:
-        return ref.paged_chunk_attention(q, k_pages, v_pages, block_tables,
-                                         start, span, k_new, v_new,
-                                         scale=scale, window=window)
+        return _ref_at(layer, lambda kp, vp: ref.paged_chunk_attention(
+            q, kp, vp, block_tables, start, span, k_new, v_new, scale=scale,
+            window=window), k_pages, v_pages)
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     on_tpu = _on_tpu()
@@ -282,29 +317,34 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, start, span,
         q, k_pages, v_pages, block_tables.astype(jnp.int32),
         start.astype(jnp.int32), span.astype(jnp.int32),
         k_new.astype(k_pages.dtype), v_new.astype(v_pages.dtype),
-        scale=scale, window=window, interpret=not on_tpu)
+        layer=_layer_operand(layer), scale=scale, window=window,
+        interpret=not on_tpu)
 
 
+@_one_layer_as_stack(1, 2, 3, 4)
 def paged_chunk_attention_quant(q, k_pages, k_scales, v_pages, v_scales,
                                 block_tables, start, span, k_new, v_new, *,
-                                scale: float | None = None,
+                                layer=None, scale: float | None = None,
                                 window: int | None = None,
                                 use_pallas: bool = True):
     """Quantized-pool chunked mixed-step attention.
 
-    Same contract as ``paged_chunk_attention`` with int8/fp8 pools and
-    per-row f32 scale pools; k/v_new arrive FLOAT [B, Hkv, C, D] and
-    quantize inside the kernel's fused multi-slot write.  Returns
+    Same contract as ``paged_chunk_attention`` (stacks with ``layer``, or
+    one layer's pools with ``layer`` None) with int8/fp8 pools and per-row
+    f32 scale pools; k/v_new arrive FLOAT [B, Hkv, C, D] and quantize
+    inside the kernel's fused multi-slot write.  Returns
     (out, k_pages, v_pages, k_scales, v_scales).
     """
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[-2]
     maxp = block_tables.shape[1]
     start = jnp.minimum(start, maxp * ps - 1)
     span = jnp.clip(span, 0, q.shape[2])
     if not use_pallas:
-        return ref.paged_chunk_attention_quant(
-            q, k_pages, k_scales, v_pages, v_scales, block_tables, start,
-            span, k_new, v_new, scale=scale, window=window)
+        return _ref_at(layer, lambda kp, vp, ks, vs: (
+            ref.paged_chunk_attention_quant(
+                q, kp, ks, vp, vs, block_tables, start, span, k_new, v_new,
+                scale=scale, window=window)),
+            k_pages, v_pages, k_scales, v_scales)
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     on_tpu = _on_tpu()
@@ -317,23 +357,27 @@ def paged_chunk_attention_quant(q, k_pages, k_scales, v_pages, v_scales,
         v_scales.astype(jnp.float32), block_tables.astype(jnp.int32),
         start.astype(jnp.int32), span.astype(jnp.int32),
         k_new.astype(jnp.float32), v_new.astype(jnp.float32),
-        scale=scale, qmax=_quant_qmax(k_pages.dtype), window=window,
+        layer=_layer_operand(layer), scale=scale,
+        qmax=_quant_qmax(k_pages.dtype), window=window,
         interpret=not on_tpu)
 
 
+@_one_layer_as_stack(2)
 def paged_mla_chunk(q_abs, q_rope, latent_pages, block_tables, start, span,
-                    latent_new, *, scale: float, use_pallas: bool = True):
+                    latent_new, *, layer=None, scale: float,
+                    use_pallas: bool = True):
     """Chunked mixed-step MLA decode over a paged latent cache.
 
     q_abs: [B, H, C, r] (f32 absorbed queries); q_rope: [B, H, C, rd];
-    latent_pages: [P, ps, Dp] with Dp >= r + rd; block_tables: i32[B, maxp];
-    start/span: i32[B]; latent_new: [B, C, Dp].
+    latent_pages: [L, P, ps, Dp] stack addressed at ``layer`` (i32
+    scalar), or [P, ps, Dp] with ``layer`` None, Dp >= r + rd;
+    block_tables: i32[B, maxp]; start/span: i32[B]; latent_new: [B, C, Dp].
     Returns (ctx [B, H, C, r] f32, latent_pages updated in place on TPU).
     """
     r = q_abs.shape[-1]
     rd = q_rope.shape[-1]
-    ps = latent_pages.shape[1]
-    dp = latent_pages.shape[2]
+    ps = latent_pages.shape[-2]
+    dp = latent_pages.shape[-1]
     maxp = block_tables.shape[1]
     if dp < r + rd:
         raise ValueError(f"latent pool width {dp} < kv_lora_rank + rope_dim "
@@ -341,9 +385,9 @@ def paged_mla_chunk(q_abs, q_rope, latent_pages, block_tables, start, span,
     start = jnp.minimum(start, maxp * ps - 1)
     span = jnp.clip(span, 0, q_abs.shape[2])
     if not use_pallas:
-        return ref.paged_mla_chunk(q_abs, q_rope, latent_pages,
-                                   block_tables, start, span, latent_new,
-                                   r=r, scale=scale)
+        return _ref_at(layer, lambda lp: ref.paged_mla_chunk(
+            q_abs, q_rope, lp, block_tables, start, span, latent_new, r=r,
+            scale=scale), latent_pages)
     on_tpu = _on_tpu()
     if on_tpu:
         _check_tileable("paged_mla_chunk", latent_pages.dtype,
@@ -353,24 +397,26 @@ def paged_mla_chunk(q_abs, q_rope, latent_pages, block_tables, start, span,
     return _pchunk.paged_mla_chunk(
         qc, latent_pages, block_tables.astype(jnp.int32),
         start.astype(jnp.int32), span.astype(jnp.int32),
-        latent_new.astype(latent_pages.dtype), r=r, scale=scale,
-        interpret=not on_tpu)
+        latent_new.astype(latent_pages.dtype), r=r,
+        layer=_layer_operand(layer), scale=scale, interpret=not on_tpu)
 
 
+@_one_layer_as_stack(2, 3)
 def paged_mla_chunk_quant(q_abs, q_rope, latent_pages, latent_scales,
                           block_tables, start, span, latent_new, *,
-                          scale: float, use_pallas: bool = True):
+                          layer=None, scale: float, use_pallas: bool = True):
     """Quantized-pool chunked MLA decode.
 
-    Same contract as ``paged_mla_chunk`` with an int8/fp8 latent pool and
-    a per-row f32 scale pool (latent_scales: [P, ps]); latent_new arrives
-    FLOAT [B, C, Dp] and quantizes inside the kernel's fused write.
-    Returns (ctx, latent_pages, latent_scales).
+    Same contract as ``paged_mla_chunk`` (a stack with ``layer``, or one
+    layer's pool with ``layer`` None) with an int8/fp8 latent pool and a
+    per-row f32 scale pool (latent_scales: [(L,) P, ps]); latent_new
+    arrives FLOAT [B, C, Dp] and quantizes inside the kernel's fused
+    write.  Returns (ctx, latent_pages, latent_scales).
     """
     r = q_abs.shape[-1]
     rd = q_rope.shape[-1]
-    ps = latent_pages.shape[1]
-    dp = latent_pages.shape[2]
+    ps = latent_pages.shape[-2]
+    dp = latent_pages.shape[-1]
     maxp = block_tables.shape[1]
     if dp < r + rd:
         raise ValueError(f"latent pool width {dp} < kv_lora_rank + rope_dim "
@@ -378,9 +424,9 @@ def paged_mla_chunk_quant(q_abs, q_rope, latent_pages, latent_scales,
     start = jnp.minimum(start, maxp * ps - 1)
     span = jnp.clip(span, 0, q_abs.shape[2])
     if not use_pallas:
-        return ref.paged_mla_chunk_quant(
-            q_abs, q_rope, latent_pages, latent_scales, block_tables,
-            start, span, latent_new, r=r, scale=scale)
+        return _ref_at(layer, lambda lp, ls: ref.paged_mla_chunk_quant(
+            q_abs, q_rope, lp, ls, block_tables, start, span, latent_new,
+            r=r, scale=scale), latent_pages, latent_scales)
     on_tpu = _on_tpu()
     if on_tpu:
         _check_tileable("paged_mla_chunk_quant", latent_pages.dtype,
@@ -392,7 +438,8 @@ def paged_mla_chunk_quant(q_abs, q_rope, latent_pages, latent_scales,
         qc, latent_pages, latent_scales.astype(jnp.float32),
         block_tables.astype(jnp.int32), start.astype(jnp.int32),
         span.astype(jnp.int32), latent_new.astype(jnp.float32),
-        r=r, scale=scale, qmax=_quant_qmax(latent_pages.dtype),
+        r=r, layer=_layer_operand(layer), scale=scale,
+        qmax=_quant_qmax(latent_pages.dtype),
         interpret=not on_tpu)
 
 
@@ -523,18 +570,24 @@ def page_transfer(src_pool, dst_pool, src_ids, dst_ids, *,
 
 
 def moe_grouped_swiglu(x, w_gate, w_up, w_down, tile_group, num_tiles, *,
-                       tm: int):
+                       tm: int, layer=None):
     """Grouped SwiGLU of the held experts over group-aligned pair tiles
     (see kernels/moe_grouped_matmul.py): the Pallas kernel on a TPU, where
     it visits only the ``num_tiles`` live tiles, the jnp oracle elsewhere.
-    x: [T*tm, d]; w_gate / w_up: [G, d, h]; w_down: [G, h, d];
-    tile_group: i32[T]; num_tiles: i32[1].  Rows of tiles past
-    ``num_tiles`` are undefined."""
+    x: [T*tm, d]; w_gate / w_up: [L, G, d, h] and w_down: [L, G, h, d]
+    stacks addressed at ``layer`` (i32 scalar), or one layer's [G, d, h] /
+    [G, h, d] with ``layer`` None; tile_group: i32[T]; num_tiles: i32[1].
+    Rows of tiles past ``num_tiles`` are undefined."""
+    if layer is None:         # one layer's banks: a stack of one (a reshape)
+        w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
+        layer = 0
     if not _on_tpu():
-        return ref.moe_grouped_swiglu(x, w_gate, w_up, w_down, tile_group,
-                                      num_tiles, tm=tm)
+        return ref.moe_grouped_swiglu(x, w_gate[layer], w_up[layer],
+                                      w_down[layer], tile_group, num_tiles,
+                                      tm=tm)
     return _moe.moe_grouped_swiglu(x, w_gate, w_up, w_down, tile_group,
-                                   num_tiles, tm=tm)
+                                   num_tiles, tm=tm,
+                                   layer=_layer_operand(layer))
 
 
 def linear_scan(a, b, h0, *, block_t: int = 128, use_pallas: bool = True):

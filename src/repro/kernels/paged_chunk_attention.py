@@ -18,7 +18,15 @@ being admitted chunk by chunk.  These kernels are the hot path of that step:
     below the sliding window, when one applies), so one kernel serves spans
     of any width: span 1 degenerates to the fused decode kernel.
 
-MHA variant, pools ``[P, Hkv, ps, D]``: grid (B, Hkv / hb), one program
+Pools arrive as the stacks of every layer, ``[L, P, ...]``, with the
+layer index as a scalar-prefetch operand (``layer``, i32[1]): a kernel
+DMAs the pages of that layer straight out of the stack and aliases the
+whole stack input→output, so the layer scan that calls it carries the
+stacks and no step slices, copies or writes back a layer's pool.  A
+caller that holds one layer's pool passes a stack of one
+(``kernels.ops`` does: the leading axis is a free reshape).
+
+MHA variant, pools ``[L, P, Hkv, ps, D]``: grid (B, Hkv / hb), one program
 per row and block of hb KV heads.  A page is contiguous across heads, so
 one DMA moves a page for all hb heads.  The walk takes ppb pages per step:
 their DMAs start together, the next block's are in flight while this one
@@ -29,7 +37,7 @@ every KV head while two such pages fit, then as many pages as fit.  A
 row's last block may run past its pages: those are not fetched, their
 columns are masked and their V slots zeroed.  A span-0 row skips both the
 write and the walk.  The quantized twin keeps grid (B, Hkv) and one page
-per step.  MLA-latent variant: grid (B,), pool ``[P, ps, Dp]`` storing
+per step.  MLA-latent variant: grid (B,), pool ``[L, P, ps, Dp]`` storing
 concat([ckv; krope]) rows; queries arrive pre-absorbed (concat([q_abs;
 q_rope])) so both logits terms are one contraction, as in
 kernels/paged_mla_decode.py.
@@ -38,10 +46,10 @@ Each ``pallas_call`` names its kernel (``paged_chunk_attention``,
 ``paged_mla_chunk``, and the ``_quant`` twins), so a profiler trace finds
 it by that name whatever jitted function wraps it.
 
-The pools are ANY-space refs aliased input→output (in-place update on TPU).
-Alignment follows the decode kernels: ``page_size`` a multiple of the
-sublane count and the lane dim a multiple of 128 on real TPU; interpret
-mode runs any shape.
+The pool stacks are ANY-space refs aliased input→output (in-place update
+on TPU).  Alignment follows the decode kernels: ``page_size`` a multiple
+of the sublane count and the lane dim a multiple of 128 on real TPU;
+interpret mode runs any shape.
 """
 from __future__ import annotations
 
@@ -90,12 +98,15 @@ def _merge_head_rows(old, new, base, count):
         picked = jnp.broadcast_to(new.astype(jnp.float32), old.shape)
     else:
         # One-hot gather on the MXU, as in ``_merge_rows``: one nonzero
-        # term per output element, so the contraction is exact.
+        # term per output element, so the contraction is exact; span rows
+        # past ``count`` (padded positions' garbage) are zeroed first.
         f32 = old.dtype == jnp.float32
         wide = jnp.float32 if f32 else jnp.bfloat16
         onehot = jax.lax.broadcasted_iota(jnp.int32, (h, ps, c), 2) == tpos
+        live = jax.lax.broadcasted_iota(jnp.int32, (1, c, 1), 1) < count
         picked = jnp.einsum(
-            "hpc,hcw->hpw", onehot.astype(wide), new.astype(wide),
+            "hpc,hcw->hpw", onehot.astype(wide),
+            jnp.where(live, new, 0).astype(wide),
             precision=jax.lax.Precision.HIGHEST if f32 else None,
             preferred_element_type=jnp.float32)
     return jnp.where(take, picked, old.astype(jnp.float32)).astype(old.dtype)
@@ -129,14 +140,15 @@ def _walk_blocks(hkv: int, rows: int, ps: int, d: int, itemsize: int,
     return hb, max(1, min(maxp, _BLOCK_VMEM // page_bytes(hb)))
 
 
-def _mha_kernel(bt_ref, start_ref, span_ref, q_ref, kn_ref, vn_ref,
-                kp_in, vp_in, o_ref, kp, vp, kbuf, vbuf, ksem, vsem,
+def _mha_kernel(layer_ref, bt_ref, start_ref, span_ref, q_ref, kn_ref,
+                vn_ref, kp_in, vp_in, o_ref, kp, vp, kbuf, vbuf, ksem, vsem,
                 *, ps: int, c: int, scale: float, window: int | None):
     # One program per (row, block of hb KV heads).  kbuf/vbuf: [2, hb,
     # ppb, ps, D] -- two slots of one walk block, pages laid out per head
     # so a head's block is one [ppb*ps, D] run.
     b = pl.program_id(0)
     _, hb, ppb, _, d = kbuf.shape
+    kp, vp = kp.at[layer_ref[0]], vp.at[layer_ref[0]]
     heads = pl.ds(pl.program_id(1) * hb, hb)
     start = start_ref[b]
     span = span_ref[b]
@@ -247,15 +259,18 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
                           v_pages: jax.Array, block_tables: jax.Array,
                           start: jax.Array, span: jax.Array,
                           k_new: jax.Array, v_new: jax.Array, *,
-                          scale: float, window: int | None = None,
+                          layer: jax.Array, scale: float,
+                          window: int | None = None,
                           interpret: bool = False
                           ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """q: [B, Hq, C, D]; k/v_pages: [P, Hkv, ps, D]; block_tables: i32[B,
-    maxp]; start/span: i32[B]; k/v_new: [B, Hkv, C, D] (pool dtype).
-    Returns (out [B, Hq, C, D], k_pages, v_pages) with the span written at
-    slots ``start..start+span`` (pools updated in place via aliasing)."""
+    """q: [B, Hq, C, D]; k/v_pages: [L, P, Hkv, ps, D] stacks addressed at
+    ``layer`` (i32[1]); block_tables: i32[B, maxp]; start/span: i32[B];
+    k/v_new: [B, Hkv, C, D] (pool dtype).  Returns (out [B, Hq, C, D],
+    k_pages, v_pages) with the span written at slots ``start..start+span``
+    of that layer (pools updated in place via aliasing, every other layer
+    untouched)."""
     b, hq, c, d = q.shape
-    _, hkv, ps, _ = k_pages.shape
+    _, _, hkv, ps, _ = k_pages.shape
     rows = hq // hkv * c
     hb, ppb = _walk_blocks(hkv, rows, ps, d, k_pages.dtype.itemsize,
                            block_tables.shape[1])
@@ -267,7 +282,7 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,              # block_tables, start, span
+        num_scalar_prefetch=4,              # layer, block_tables, start, span
         grid=(b, hkv // hb),
         in_specs=[q_spec, tok_spec, tok_spec, any_spec, any_spec],
         out_specs=[q_spec, any_spec, any_spec],
@@ -288,17 +303,17 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
             jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ],
-        # Input indices count the scalar-prefetch operands (0, 1, 2).
-        input_output_aliases={6: 1, 7: 2},
+        # Input indices count the scalar-prefetch operands (0 .. 3).
+        input_output_aliases={7: 1, 8: 2},
         interpret=interpret,
         name="paged_chunk_attention",
-    )(block_tables, start, span, q, k_new, v_new, k_pages, v_pages)
+    )(layer, block_tables, start, span, q, k_new, v_new, k_pages, v_pages)
     return out.reshape(b, hq, c, d), k_pages, v_pages
 
 
-def _mha_kernel_quant(bt_ref, start_ref, span_ref, q_ref, kn_ref, vn_ref,
-                      kp_in, vp_in, ks_in, vs_in, o_ref, kp, vp, ks, vs,
-                      kbuf, vbuf, ksbuf, vsbuf, ksem, vsem, kssem, vssem,
+def _mha_kernel_quant(layer_ref, bt_ref, start_ref, span_ref, q_ref, kn_ref,
+                      vn_ref, kp_in, vp_in, ks_in, vs_in, o_ref, kp, vp, ks,
+                      vs, kbuf, vbuf, ksbuf, vsbuf, ksem, vsem, kssem, vssem,
                       *, ps: int, c: int, scale: float, window: int | None,
                       qmax: float, qdtype):
     """Quantized twin of ``_mha_kernel``: the span's K/V rows quantize
@@ -307,6 +322,7 @@ def _mha_kernel_quant(bt_ref, start_ref, span_ref, q_ref, kn_ref, vn_ref,
     page with its DMA'd scale row."""
     b = pl.program_id(0)
     h = pl.program_id(1)
+    kp, vp, ks, vs = (r.at[layer_ref[0]] for r in (kp, vp, ks, vs))
     start = start_ref[b]
     span = span_ref[b]
     kv_len = start + span
@@ -402,15 +418,17 @@ def paged_chunk_attention_quant(q: jax.Array, k_pages: jax.Array,
                                 v_scales: jax.Array,
                                 block_tables: jax.Array, start: jax.Array,
                                 span: jax.Array, k_new: jax.Array,
-                                v_new: jax.Array, *, scale: float,
-                                qmax: float, window: int | None = None,
+                                v_new: jax.Array, *,
+                                layer: jax.Array, scale: float, qmax: float,
+                                window: int | None = None,
                                 interpret: bool = False):
-    """Quantized-pool chunked attention: k/v_pages [P, Hkv, ps, D] int8/fp8
-    with k/v_scales [P, Hkv, ps] f32; k/v_new arrive FLOAT [B, Hkv, C, D]
-    and quantize in-kernel.  Returns (out, k_pages, v_pages, k_scales,
-    v_scales) — pools + scales updated in place via aliasing."""
+    """Quantized-pool chunked attention: k/v_pages [L, P, Hkv, ps, D]
+    int8/fp8 with k/v_scales [L, P, Hkv, ps] f32, addressed at ``layer``
+    (i32[1]); k/v_new arrive FLOAT [B, Hkv, C, D] and quantize in-kernel.
+    Returns (out, k_pages, v_pages, k_scales, v_scales) — pools + scales
+    updated in place via aliasing."""
     b, hq, c, d = q.shape
-    _, hkv, ps, _ = k_pages.shape
+    _, _, hkv, ps, _ = k_pages.shape
     group = hq // hkv
     grid = (b, hkv)
 
@@ -419,7 +437,7 @@ def paged_chunk_attention_quant(q: jax.Array, k_pages: jax.Array,
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,              # block_tables, start, span
+        num_scalar_prefetch=4,              # layer, block_tables, start, span
         grid=grid,
         in_specs=[q_spec, tok_spec, tok_spec,
                   any_spec, any_spec, any_spec, any_spec],
@@ -448,18 +466,19 @@ def paged_chunk_attention_quant(q: jax.Array, k_pages: jax.Array,
             jax.ShapeDtypeStruct(k_scales.shape, k_scales.dtype),
             jax.ShapeDtypeStruct(v_scales.shape, v_scales.dtype),
         ],
-        # Input indices count the scalar-prefetch operands (0, 1, 2).
-        input_output_aliases={6: 1, 7: 2, 8: 3, 9: 4},
+        # Input indices count the scalar-prefetch operands (0 .. 3).
+        input_output_aliases={7: 1, 8: 2, 9: 3, 10: 4},
         interpret=interpret,
         name="paged_chunk_attention_quant",
-    )(block_tables, start, span, q, k_new, v_new,
+    )(layer, block_tables, start, span, q, k_new, v_new,
       k_pages, v_pages, k_scales, v_scales)
 
 
-def _mla_kernel(bt_ref, start_ref, span_ref, q_ref, ln_ref, lp_in,
+def _mla_kernel(layer_ref, bt_ref, start_ref, span_ref, q_ref, ln_ref, lp_in,
                 o_ref, lp, buf, dsem, *, ps: int, c: int,
                 r: int, width: int, scale: float):
     b = pl.program_id(0)
+    lp = lp.at[layer_ref[0]]
     start = start_ref[b]
     span = span_ref[b]
     kv_len = start + span
@@ -525,15 +544,17 @@ def _mla_kernel(bt_ref, start_ref, span_ref, q_ref, ln_ref, lp_in,
 def paged_mla_chunk(q: jax.Array, latent_pages: jax.Array,
                     block_tables: jax.Array, start: jax.Array,
                     span: jax.Array, latent_new: jax.Array, *, r: int,
-                    scale: float, interpret: bool = False
+                    layer: jax.Array, scale: float, interpret: bool = False
                     ) -> tuple[jax.Array, jax.Array]:
     """q: [B, H, C, width] absorbed queries concat([q_abs; q_rope]);
-    latent_pages: [P, ps, Dp] (Dp >= width, first r features are ckv);
+    latent_pages: [L, P, ps, Dp] stack addressed at ``layer`` (i32[1];
+    Dp >= width, first r features are ckv);
     block_tables: i32[B, maxp]; start/span: i32[B]; latent_new: [B, C, Dp].
     Returns (ctx [B, H, C, r] f32, latent_pages) with the span's latent rows
-    written at slots ``start..start+span`` (pool updated in place)."""
+    written at slots ``start..start+span`` of that layer (pool updated in
+    place)."""
     b, h, c, width = q.shape
-    _, ps, dp = latent_pages.shape
+    _, _, ps, dp = latent_pages.shape
     grid = (b,)
 
     q_spec = pl.BlockSpec((1, h, c, width), lambda i, *_: (i, 0, 0, 0))
@@ -542,7 +563,7 @@ def paged_mla_chunk(q: jax.Array, latent_pages: jax.Array,
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,              # block_tables, start, span
+        num_scalar_prefetch=4,              # layer, block_tables, start, span
         grid=grid,
         in_specs=[q_spec, tok_spec, any_spec],
         out_specs=[out_spec, any_spec],
@@ -560,21 +581,23 @@ def paged_mla_chunk(q: jax.Array, latent_pages: jax.Array,
             jax.ShapeDtypeStruct((b, h, c, r), jnp.float32),
             jax.ShapeDtypeStruct(latent_pages.shape, latent_pages.dtype),
         ],
-        # Input indices count the scalar-prefetch operands (0, 1, 2).
-        input_output_aliases={5: 1},
+        # Input indices count the scalar-prefetch operands (0 .. 3).
+        input_output_aliases={6: 1},
         interpret=interpret,
         name="paged_mla_chunk",
-    )(block_tables, start, span, q, latent_new, latent_pages)
+    )(layer, block_tables, start, span, q, latent_new,
+      latent_pages)
 
 
-def _mla_kernel_quant(bt_ref, start_ref, span_ref, q_ref, ln_ref, lp_in,
-                      ls_in, o_ref, lp, ls, buf, sbuf, dsem, ssem,
-                      *, ps: int, c: int,
-                      r: int, width: int, scale: float, qmax: float, qdtype):
+def _mla_kernel_quant(layer_ref, bt_ref, start_ref, span_ref, q_ref, ln_ref,
+                      lp_in, ls_in, o_ref, lp, ls, buf, sbuf, dsem, ssem,
+                      *, ps: int, c: int, r: int, width: int, scale: float,
+                      qmax: float, qdtype):
     """Quantized twin of ``_mla_kernel``: span latent rows quantize
     in-kernel (one scale per token), write fused with their scales, and the
     walk dequantizes each page with its DMA'd scale row."""
     b = pl.program_id(0)
+    lp, ls = lp.at[layer_ref[0]], ls.at[layer_ref[0]]
     start = start_ref[b]
     span = span_ref[b]
     kv_len = start + span
@@ -654,14 +677,15 @@ def paged_mla_chunk_quant(q: jax.Array, latent_pages: jax.Array,
                           latent_scales: jax.Array,
                           block_tables: jax.Array, start: jax.Array,
                           span: jax.Array, latent_new: jax.Array, *,
-                          r: int, scale: float, qmax: float,
-                          interpret: bool = False):
-    """Quantized-pool chunked MLA: latent_pages [P, ps, Dp] int8/fp8 with
-    latent_scales [P, ps] f32; latent_new arrives FLOAT [B, C, Dp] and
-    quantizes in-kernel.  Returns (ctx [B, H, C, r] f32, latent_pages,
-    latent_scales) — pool + scales updated in place via aliasing."""
+                          r: int, layer: jax.Array, scale: float,
+                          qmax: float, interpret: bool = False):
+    """Quantized-pool chunked MLA: latent_pages [L, P, ps, Dp] int8/fp8 with
+    latent_scales [L, P, ps] f32, addressed at ``layer`` (i32[1]);
+    latent_new arrives FLOAT [B, C, Dp] and quantizes in-kernel.  Returns
+    (ctx [B, H, C, r] f32, latent_pages, latent_scales) — pool + scales
+    updated in place via aliasing."""
     b, h, c, width = q.shape
-    _, ps, dp = latent_pages.shape
+    _, _, ps, dp = latent_pages.shape
     grid = (b,)
 
     q_spec = pl.BlockSpec((1, h, c, width), lambda i, *_: (i, 0, 0, 0))
@@ -670,7 +694,7 @@ def paged_mla_chunk_quant(q: jax.Array, latent_pages: jax.Array,
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,              # block_tables, start, span
+        num_scalar_prefetch=4,              # layer, block_tables, start, span
         grid=grid,
         in_specs=[q_spec, tok_spec, any_spec, any_spec],
         out_specs=[out_spec, any_spec, any_spec],
@@ -692,9 +716,9 @@ def paged_mla_chunk_quant(q: jax.Array, latent_pages: jax.Array,
             jax.ShapeDtypeStruct(latent_pages.shape, latent_pages.dtype),
             jax.ShapeDtypeStruct(latent_scales.shape, latent_scales.dtype),
         ],
-        # Input indices count the scalar-prefetch operands (0, 1, 2).
-        input_output_aliases={5: 1, 6: 2},
+        # Input indices count the scalar-prefetch operands (0 .. 3).
+        input_output_aliases={6: 1, 7: 2},
         interpret=interpret,
         name="paged_mla_chunk_quant",
-    )(block_tables, start, span, q, latent_new,
+    )(layer, block_tables, start, span, q, latent_new,
       latent_pages, latent_scales)

@@ -75,11 +75,15 @@ def _merge_rows(old, new, base, count):
     else:
         # One-hot gather on the MXU: one nonzero term per output element,
         # so the contraction is exact (bf16 holds every int8/fp8 value).
+        # Rows past ``count`` are zeroed first: they hold whatever the
+        # padded positions computed, and 0 * NaN would poison every row.
         f32 = old.dtype == jnp.float32
         wide = jnp.float32 if f32 else jnp.bfloat16
         onehot = jax.lax.broadcasted_iota(jnp.int32, (ps, c), 1) == tpos
+        live = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) < count
         picked = jax.lax.dot_general(
-            onehot.astype(wide), new.astype(wide), (((1,), (0,)), ((), ())),
+            onehot.astype(wide), jnp.where(live, new, 0).astype(wide),
+            (((1,), (0,)), ((), ())),
             precision=jax.lax.Precision.HIGHEST if f32 else None,
             preferred_element_type=jnp.float32)
     return jnp.where(take, picked, old.astype(jnp.float32)).astype(old.dtype)

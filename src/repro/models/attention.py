@@ -303,7 +303,8 @@ def prefill(p: Params, cfg: ModelConfig, x: jax.Array, cache: Params,
 
 def mixed_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Params,
                start: jax.Array, span: jax.Array, positions: jax.Array,
-               impl: str = "ref") -> tuple[jax.Array, Params]:
+               impl: str = "ref", layer: jax.Array | None = None
+               ) -> tuple[jax.Array, Params]:
     """Per-row query spans against the cache (the mixed serve step).
 
     x: [B, C, D]; start: i32[B] tokens already cached per row; span: i32[B]
@@ -312,6 +313,8 @@ def mixed_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Params,
     *before* the attend, so query j sees the whole cached prefix plus the
     span's keys up to itself — span 1 is a decode step, span C a prompt
     chunk, span 0 an idle row whose cache is untouched (output garbage).
+    With ``layer``, a paged cache's pools are the [L, ...] stacks of every
+    layer and this layer is ``layer`` of them.
     """
     b, c, _ = x.shape
     q, k, v = _qkv(p, cfg, x, positions)
@@ -323,14 +326,15 @@ def mixed_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Params,
                 kops.paged_chunk_attention_quant(
                     q, cache["k_pages"], cache["k_scales"],
                     cache["v_pages"], cache["v_scales"],
-                    cache["block_tables"], start, span, k, v, scale=scale,
-                    window=cfg.window, use_pallas=(impl == "pallas")))
+                    cache["block_tables"], start, span, k, v, layer=layer,
+                    scale=scale, window=cfg.window,
+                    use_pallas=(impl == "pallas")))
             return (common.dense(p["wo"], _merge_heads(out).astype(x.dtype)),
                     dict(cache, k_pages=k_pages, v_pages=v_pages,
                          k_scales=k_scales, v_scales=v_scales))
         out, k_pages, v_pages = kops.paged_chunk_attention(
             q, cache["k_pages"], cache["v_pages"], cache["block_tables"],
-            start, span, k, v, scale=scale, window=cfg.window,
+            start, span, k, v, layer=layer, scale=scale, window=cfg.window,
             use_pallas=(impl == "pallas"))
         return (common.dense(p["wo"], _merge_heads(out).astype(x.dtype)),
                 dict(cache, k_pages=k_pages, v_pages=v_pages))

@@ -33,6 +33,10 @@ class BlockCtx(NamedTuple):
     chunked: bool = False                 # blockwise attention (long T)
     prefix_len: int = 0                   # bidirectional prefix (VLM)
     lengths: Optional[jax.Array] = None   # i32[B] ragged prefill lengths
+    # i32 index of the layer in the [L, ...] page-pool and expert-bank
+    # stacks that the mixed step's layer scan hands its kernels whole;
+    # None where a layer's pools and banks carry no layer axis.
+    layer: Optional[jax.Array] = None
 
 
 # ---------------------------------------------------------------------------
@@ -116,7 +120,7 @@ def _moe(p, cfg, x, ctx):
     spans, a ragged prefill's lengths), and its aux (see ``block_apply``)."""
     valid = (None if ctx.lengths is None
              else jnp.arange(x.shape[1])[None, :] < ctx.lengths[:, None])
-    f, aux, counts = moe.forward(p, cfg, x, valid)
+    f, aux, counts = moe.forward(p, cfg, x, valid, layer=ctx.layer)
     return f, jnp.concatenate([aux[None], counts])
 
 
@@ -137,7 +141,8 @@ def block_apply(kind: str, p: Params, cfg: ModelConfig, x: jax.Array,
         if mixed:
             a, cache = attention.mixed_step(p["attn"], local_cfg, h, cache,
                                             ctx.pos, ctx.lengths,
-                                            ctx.positions, ctx.impl)
+                                            ctx.positions, ctx.impl,
+                                            layer=ctx.layer)
         elif decode:
             a, cache = attention.decode_step(p["attn"], local_cfg, h, cache,
                                              ctx.pos, ctx.impl)
@@ -167,7 +172,8 @@ def block_apply(kind: str, p: Params, cfg: ModelConfig, x: jax.Array,
         h = _norm(p["norm1"], cfg, x)
         if mixed:
             a, cache = mla.mixed_step(p["attn"], cfg, h, cache, ctx.pos,
-                                      ctx.lengths, ctx.positions, ctx.impl)
+                                      ctx.lengths, ctx.positions, ctx.impl,
+                                      layer=ctx.layer)
         elif decode:
             a, cache = mla.decode_step(p["attn"], cfg, h, cache, ctx.pos,
                                        ctx.impl)

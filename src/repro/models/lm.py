@@ -183,6 +183,63 @@ def _run_unstacked(p: Params, cfg: ModelConfig, name: str, kinds, x, ctx,
     return x, aux
 
 
+def _split(tree, names) -> tuple[dict, Any]:
+    """(the leaves of dict ``tree`` named in ``names``, the rest)."""
+    if not names:
+        return {}, tree
+    return ({k: tree[k] for k in names},
+            {k: v for k, v in tree.items() if k not in names})
+
+
+def _scan_cached(p: Params, cfg: ModelConfig, x: jax.Array, ctx: BlockCtx,
+                 groups: Params, aux: jax.Array):
+    """The cache-carrying layer scan of every mode.
+
+    In the mixed step no kernel gets a scan slice of a stacked buffer.  A
+    Pallas call cannot fuse one, so XLA would materialise each layer's page
+    pools and held-expert banks (and copy and write back the pools, which
+    the kernels alias) every step.  Instead the pools (``cache.pool_leaves``:
+    pool and scale roles) ride the scan's carry as whole ``[L, P, ...]``
+    stacks, updated in place; the expert banks are closed over as read-only
+    constants; and each kernel addresses layer ``ctx.layer`` of its stack.
+    Block tables, recurrent state, dense caches and every other parameter
+    stay in the scan's xs / ys, as everything does in the other modes
+    (``ctx.layer`` None there)."""
+    mixed = ctx.mode == "mixed"
+    pools, rest, banks, gps = {}, {}, {}, {}
+    for k, gc in groups.items():
+        gp = p["groups"][k]
+        pools[k], rest[k] = _split(gc, cache_mod.pool_leaves(
+            gc, cache_mod.layout_of(gc)) if mixed else ())
+        if mixed and "experts" in gp.get("ffn", {}):
+            ffn = dict(gp["ffn"])
+            banks[k] = ffn.pop("experts")
+            gp = dict(gp, ffn=ffn)
+        gps[k] = gp
+
+    def body(carry, inp):
+        y, aux, pools = carry
+        gp, gc, layer = inp
+        lctx = ctx._replace(layer=layer)
+        new_pools, new_gc = {}, {}
+        for i, kind in enumerate(cfg.block_pattern):
+            k = str(i)
+            lp = (dict(gp[k], ffn=dict(gp[k]["ffn"], experts=banks[k]))
+                  if k in banks else gp[k])
+            lc = dict(gc[k], **pools[k]) if pools[k] else gc[k]
+            y, c, a = blocks.block_apply(kind, lp, cfg, y, lctx, lc)
+            new_pools[k], new_gc[k] = _split(c, list(pools[k]))
+            aux = _fold_aux(aux, a)
+        return (y, aux, new_pools), new_gc
+
+    layers = (jnp.arange(cfg.pattern_groups, dtype=jnp.int32) if mixed
+              else None)
+    (x, aux, pools), rest = jax.lax.scan(body, (x, aux, pools),
+                                         (gps, rest, layers), unroll=_UNROLL)
+    return x, {k: dict(rest[k], **pools[k]) if pools[k] else rest[k]
+               for k in rest}, aux
+
+
 def _run_blocks(p: Params, cfg: ModelConfig, x: jax.Array, ctx: BlockCtx,
                 cache: Params | None, remat: bool = False
                 ) -> tuple[jax.Array, Params | None, jax.Array]:
@@ -219,20 +276,8 @@ def _run_blocks(p: Params, cfg: ModelConfig, x: jax.Array, ctx: BlockCtx,
         (x, aux), _ = jax.lax.scan(body, (x, aux0), p["groups"],
                                     unroll=_UNROLL)
     else:
-        def body_c(carry, inp):
-            y, aux = carry
-            gp, gc = inp
-            new_gc = {}
-            for i, kind in enumerate(cfg.block_pattern):
-                y, c, a = blocks.block_apply(kind, gp[str(i)], cfg, y, ctx,
-                                             gc[str(i)])
-                new_gc[str(i)] = c
-                aux = _fold_aux(aux, a)
-            return (y, aux), new_gc
-
-        (x, aux), new_cache["groups"] = jax.lax.scan(
-            body_c, (x, aux0), (p["groups"], cache["groups"]),
-            unroll=_UNROLL)
+        x, new_cache["groups"], aux = _scan_cached(p, cfg, x, ctx,
+                                                   cache["groups"], aux0)
 
     x, aux = _run_unstacked(p, cfg, "tail", cfg.tail_blocks, x, ctx, cache,
                             new_cache, aux)
@@ -310,10 +355,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                         kv_quant=kv_quant)
     groups = {}
     for i, spec in specs["groups"].items():
-        one = spec.init()
-        groups[i] = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (cfg.pattern_groups,) + x.shape)
-            .copy() if hasattr(x, "shape") else x, one)
+        # One jitted call writes each [G, ...] stack once; eager
+        # broadcast-then-copy held two transient stacks per leaf, which
+        # set a paged cache's device memory peak.
+        groups[i] = jax.jit(lambda spec=spec: jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (cfg.pattern_groups,) + x.shape),
+            spec.init()))()
     cache: dict[str, Any] = {"groups": groups}
     for name in ("lead", "tail"):
         if name in specs:

@@ -182,7 +182,8 @@ def prefill(p, cfg, x, cache, mask, positions, impl="ref", chunked=False,
 
 def mixed_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Params,
                start: jax.Array, span: jax.Array, positions: jax.Array,
-               impl: str = "ref") -> tuple[jax.Array, Params]:
+               impl: str = "ref", layer: jax.Array | None = None
+               ) -> tuple[jax.Array, Params]:
     """Per-row query spans against the compressed cache (mixed serve step).
 
     x: [B, C, d]; start/span: i32[B]; positions: i32[B, C].  Runs the
@@ -190,6 +191,8 @@ def mixed_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Params,
     span — one math for decode (span 1) and chunked admission (span C), so
     chunk partitioning cannot change the bits.  The span's latent rows are
     written before the attend (write-then-attend, causal intra-span).
+    With ``layer``, a paged cache's pools are the [L, ...] stacks of every
+    layer and this layer is ``layer`` of them.
     """
     m = cfg.mla
     b, c, _ = x.shape
@@ -211,14 +214,15 @@ def mixed_step(p: Params, cfg: ModelConfig, x: jax.Array, cache: Params,
         if layout != "paged_mla":
             ctx, pool, scales = kops.paged_mla_chunk_quant(
                 q_abs, q_rope, pool, cache["latent_scales"],
-                cache["block_tables"], start, span, lat_new, scale=scale,
-                use_pallas=(impl == "pallas"))
+                cache["block_tables"], start, span, lat_new, layer=layer,
+                scale=scale, use_pallas=(impl == "pallas"))
             new_cache = dict(cache, latent_pages=pool,
                              latent_scales=scales)
         else:
             ctx, pool = kops.paged_mla_chunk(
                 q_abs, q_rope, pool, cache["block_tables"], start, span,
-                lat_new, scale=scale, use_pallas=(impl == "pallas"))
+                lat_new, layer=layer, scale=scale,
+                use_pallas=(impl == "pallas"))
             new_cache = dict(cache, latent_pages=pool)
     else:
         # Dense latent cache: write the span via a position gather, then the

@@ -112,8 +112,11 @@ def _swiglu_bank(bank: Params, x: jax.Array) -> jax.Array:
 
 
 def forward(p: Params, cfg: ModelConfig, x: jax.Array,
-            valid: Optional[jax.Array] = None):
-    """x: [B, T, d]; valid: bool[B, T] or None (all) -> (y, aux, counts)."""
+            valid: Optional[jax.Array] = None,
+            layer: Optional[jax.Array] = None):
+    """x: [B, T, d]; valid: bool[B, T] or None (all) -> (y, aux, counts).
+    With ``layer``, the held-expert bank ``p["experts"]`` is the [L, ...]
+    stack of every layer's and this layer is ``layer`` of it."""
     m = cfg.moe
     first, held = cfg.held_experts
     b, t, d = x.shape
@@ -142,7 +145,7 @@ def forward(p: Params, cfg: ModelConfig, x: jax.Array,
     ys = kops.moe_grouped_swiglu(
         xf[tok_of_row], ex["gate"]["w"].astype(x.dtype),
         ex["up"]["w"].astype(x.dtype), ex["down"]["w"].astype(x.dtype),
-        tile_group, num_tiles, tm=tm)                          # [rows, d]
+        tile_group, num_tiles, tm=tm, layer=layer)             # [rows, d]
     # Rows of dead pairs are never read (unwritten rows may hold anything).
     y_pair = jnp.where(live[:, None],
                        ys[jnp.clip(slot, 0, rows - 1)].astype(jnp.float32)

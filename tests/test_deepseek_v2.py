@@ -250,6 +250,14 @@ def test_all_pairs_to_one_expert_drop_nothing_and_padding_is_unrouted():
 
 # -- (e) the grouped kernel in interpret mode -----------------------------
 
+def _one_layer_kernel(x, w_gate, w_up, w_down, tg, nt, *, tm):
+    """The kernel, interpreted, on one layer's banks: a stack of one."""
+    return gmm.moe_grouped_swiglu(x, w_gate[None], w_up[None], w_down[None],
+                                  tg, nt, tm=tm,
+                                  layer=jnp.zeros((1,), jnp.int32),
+                                  interpret=True)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_grouped_kernel_matches_jnp_path_with_empty_experts(dtype):
     """Group sizes 0, 37, 0, 5, 16 (two empty experts, one spanning three
@@ -276,7 +284,7 @@ def test_grouped_kernel_matches_jnp_path_with_empty_experts(dtype):
     x = jnp.asarray(rng.normal(size=(rows, d)), dtype)
     w = [jnp.asarray(rng.normal(size=sh) * 0.2, dtype)
          for sh in ((held, d, h), (held, d, h), (held, h, d))]
-    got = gmm.moe_grouped_swiglu(x, *w, tg, nt, tm=tm, interpret=True)
+    got = _one_layer_kernel(x, *w, tg, nt, tm=tm)
     want = kref.moe_grouped_swiglu(x, *w, tg, nt, tm=tm)
     live_rows = int(nt[0]) * tm
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
@@ -307,8 +315,7 @@ def test_grouped_kernel_gradient_matches_jnp_path():
         return jax.grad(lambda *a: jnp.sum(fn(*a) * r),
                         argnums=(0, 1, 2, 3))(x, *w)
 
-    got = grads(lambda *a: gmm.moe_grouped_swiglu(*a, tg, nt, tm=tm,
-                                                  interpret=True))
+    got = grads(lambda *a: _one_layer_kernel(*a, tg, nt, tm=tm))
     want = grads(lambda *a: kref.moe_grouped_swiglu(*a, tg, nt, tm=tm))
     for g, v in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(v),

@@ -227,6 +227,57 @@ def test_paged_mla_chunk_kernel_matches_oracle(c, dtype):
     np.testing.assert_array_equal(np.asarray(lp1), np.asarray(lp2))
 
 
+@pytest.mark.parametrize("kernel", ["mha", "mha_int8", "mla", "mla_int8"])
+def test_chunk_kernels_write_no_padded_span_row(kernel):
+    """A row whose span is shorter than the step's width carries, in its
+    padded span rows, whatever the padded positions computed -- NaN
+    included.  The fused write must take the span's rows alone: with NaN
+    in every padded row of the new K/V (latent) rows, the pools and the
+    live outputs are bit for bit those of zeros there."""
+    b, h, hkv, ps, maxp, d, c = 2, 4, 2, 8, 4, 32, 8
+    r, rd, dp = 16, 8, 128
+    rng = np.random.default_rng(11)
+    pool = b * maxp + 1
+    bt = jnp.asarray(rng.permutation(pool)[:b * maxp].reshape(b, maxp)
+                     .astype(np.int32))
+    start = jnp.asarray([3, 11], jnp.int32)  # row 0 straddles two pages
+    span = jnp.asarray([5, 2], jnp.int32)
+    pad = (np.arange(c)[None, :] >= np.asarray(span)[:, None])     # [B, C]
+    normal = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    if kernel.startswith("mha"):
+        q = normal(b, h, c, d)
+        pools = [normal(pool, hkv, ps, d) for _ in range(2)]
+        if kernel == "mha_int8":
+            (kp, ks), (vp, vs) = (ref.quantize_rows(p, jnp.int8)
+                                  for p in pools)
+            run = lambda kn, vn: ops.paged_chunk_attention_quant(
+                q, kp, ks, vp, vs, bt, start, span, kn, vn)
+        else:
+            run = lambda kn, vn: ops.paged_chunk_attention(
+                q, *pools, bt, start, span, kn, vn)
+        new = [normal(b, hkv, c, d) for _ in range(2)]
+        poison = jnp.asarray(pad)[:, None, :, None]
+    else:
+        q_abs, q_rope = normal(b, h, c, r), normal(b, h, c, rd)
+        lp = normal(pool, ps, dp)
+        if kernel == "mla_int8":
+            lq, ls = ref.quantize_rows(lp, jnp.int8)
+            run = lambda ln: ops.paged_mla_chunk_quant(
+                q_abs, q_rope, lq, ls, bt, start, span, ln, scale=0.125)
+        else:
+            run = lambda ln: ops.paged_mla_chunk(
+                q_abs, q_rope, lp, bt, start, span, ln, scale=0.125)
+        new = [normal(b, c, dp)]
+        poison = jnp.asarray(pad)[:, :, None]
+    got = run(*(jnp.where(poison, jnp.nan, x) for x in new))
+    want = run(*(jnp.where(poison, 0.0, x) for x in new))
+    live = ~pad[:, None, :, None]
+    np.testing.assert_array_equal(np.where(live, np.asarray(got[0]), 0),
+                                  np.where(live, np.asarray(want[0]), 0))
+    for g, w in zip(got[1:], want[1:]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 # ---------------------------------------------------------------------------
 # Acceptance: chunked admission ≡ one-shot ragged prefill
 # ---------------------------------------------------------------------------
