@@ -15,83 +15,13 @@ the dominant term down".
 from __future__ import annotations
 
 import json
-import math
 from pathlib import Path
-
-import jax.numpy as jnp
 
 PEAK_FLOPS_BF16 = 197e12
 HBM_BW = 819e9
 ICI_BW = 50e9 * 4          # ~4 usable links per v5e chip (2D torus)
 
 DRYRUN_DIR = Path(__file__).resolve().parent.parent / "experiments" / "dryrun"
-
-
-# ---------------------------------------------------------------------------
-# KV-cache byte accounting, shared with bench_serving's analytic counters.
-#
-# Everything is derived from a cache tree's own leaf shapes/dtypes (works on
-# concrete arrays and on jax.eval_shape abstract trees alike), so the roofline
-# model and the serving bench agree on bytes/token by construction: there is
-# exactly one place that knows how many bytes a page or a token slot costs,
-# including quantized pools where int8/fp8 payload and f32 scale leaves have
-# different dtypes.
-# ---------------------------------------------------------------------------
-
-def leaf_nbytes(leaf) -> int:
-    """Bytes of one cache leaf (concrete array or ShapeDtypeStruct)."""
-    return int(math.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
-
-
-def kv_page_bytes(cache) -> int:
-    """Bytes one page occupies summed over every paged layer of the model.
-
-    Covers every leaf that travels with a page — quantized pools AND their
-    scale leaves — via cache._POOL_LEAF_NDIM, so a q8 layout reports the
-    int8 payload plus the f32 per-row scales, not a hand-derived formula.
-    Stacked [G, P, ...] group pools count all G groups.
-    """
-    from repro.models import cache as cache_mod
-    total = 0
-    for _path, layout, layer in cache_mod.iter_layers(cache):
-        if layout not in cache_mod.PAGED_LAYOUTS:
-            continue
-        for name, core in cache_mod._POOL_LEAF_NDIM[layout].items():
-            leaf = layer[name]
-            stacked = leaf.ndim == core + 1
-            num_pages = leaf.shape[1 if stacked else 0]
-            total += leaf_nbytes(leaf) // num_pages
-    return total
-
-
-def kv_slot_bytes(cache) -> int:
-    """Bytes one token slot occupies summed over every paged layer."""
-    from repro.models import cache as cache_mod
-    total = 0
-    for _path, layout, layer in cache_mod.iter_layers(cache):
-        if layout not in cache_mod.PAGED_LAYOUTS:
-            continue
-        ax = cache_mod._SPAN_SLOT_AXIS[layout]
-        for name, core in cache_mod._POOL_LEAF_NDIM[layout].items():
-            leaf = layer[name]
-            stacked = leaf.ndim == core + 1
-            num_pages = leaf.shape[1 if stacked else 0]
-            page_size = leaf.shape[ax + (1 if stacked else 0)]
-            total += leaf_nbytes(leaf) // (num_pages * page_size)
-    return total
-
-
-def dense_kv_bytes(cache) -> int:
-    """Total KV bytes of every non-paged layer (dense / dense_mla / xattn):
-    what a step streams when the whole preallocated cache is read+written."""
-    from repro.models import cache as cache_mod
-    total = 0
-    for _path, layout, layer in cache_mod.iter_layers(cache):
-        if layout in cache_mod.PAGED_LAYOUTS or layout == "state":
-            continue
-        total += sum(leaf_nbytes(v) for k, v in layer.items()
-                     if k != "block_tables")
-    return total
 
 
 def load_cells(mesh: str = "single", variant: str = "baseline") -> list[dict]:

@@ -44,10 +44,6 @@ def main() -> None:
     for row in tables.rq3_consistency(suite):
         print(row)
 
-    # Serving sweep: dense-vs-paged KV cache (also writes BENCH_serving.json).
-    from benchmarks.bench_serving import run_bench
-    run_bench(quick=args.quick)
-
     # Roofline summary (reads dry-run artifacts if present).
     try:
         from benchmarks.roofline import summary_rows

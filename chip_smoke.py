@@ -203,9 +203,8 @@ def serve_phase(cfg, params, seed: int) -> None:
     from repro.serving.scheduler import COMPLETED, ContinuousBatchingEngine
 
     eng = ContinuousBatchingEngine(
-        cfg, params, batch=BATCH, max_len=MAX_LEN, paged=True,
-        page_size=PAGE_SIZE, chunk_size=CHUNK, prefix_sharing=True,
-        seed=seed)
+        cfg, params, batch=BATCH, max_len=MAX_LEN, page_size=PAGE_SIZE,
+        chunk_size=CHUNK, prefix_sharing=True, seed=seed)
     t0 = time.perf_counter()
     compiled = eng._mixed.lower(
         params, eng.cache, jnp.zeros((BATCH, CHUNK), jnp.int32),

@@ -62,20 +62,6 @@ def make_prefill_fn(cfg: ModelConfig, *, impl: str = "ref"):
     return prefill_fn
 
 
-def make_ragged_prefill_fn(cfg: ModelConfig, *, impl: str = "ref"):
-    """(params, cache, tokens [B, P], lengths i32[B]) -> (logits, cache).
-
-    Rows with ``lengths[b] == 0`` keep their cache.  This is the one-shot
-    oracle the mixed step is verified against — serving itself admits
-    prompts chunk by chunk through ``make_mixed_step_fn``.
-    """
-    def prefill_fn(params, cache, tokens, lengths):
-        return lm.prefill(params, cfg, tokens, cache, impl=impl,
-                          lengths=lengths)
-
-    return prefill_fn
-
-
 PROMPT_BUCKETS = (8, 16, 32, 64, 128, 256, 512, 1024)
 
 

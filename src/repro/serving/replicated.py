@@ -885,7 +885,7 @@ class MultiEngineServer:
             self.caches.append(ReplicatedPrefixCache(alloc, page_size))
         self.engines = [
             sched_mod.ContinuousBatchingEngine(
-                cfg, params, batch=batch, max_len=max_len, paged=True,
+                cfg, params, batch=batch, max_len=max_len,
                 page_size=page_size, num_pages=num_pages,
                 prefix_sharing=True, allocator=self.allocators[r],
                 prefix_cache=self.caches[r], max_queue=max_queue,

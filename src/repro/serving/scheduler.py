@@ -1,4 +1,4 @@
-"""Continuous batching over the paged KV cache: token-budget mixed steps,
+"""Continuous batching over KV page pools: token-budget mixed steps,
 chunk-granular page accounting, copy-on-write prefix sharing, and completion
 at token granularity.
 
@@ -33,14 +33,6 @@ step zeroed.
 Idle rows still ride the batched mixed step (no dynamic batch shape under
 jit) with span 0 — a span-0 row writes nothing, so its block table can stay
 parked on the trash page indefinitely.
-
-Dense mode (``paged=False``) runs the same composer against the classic
-[B, Hkv, S, D] cache — the benchmark's apples-to-apples baseline.
-
-``prefill_interleave=False`` is the *stalled-admission* baseline the bench
-sweeps against: admission chunks run whole-prompt and decode rows get span 0
-while any prompt is in flight — the old bucketed-admission behaviour,
-measured by ``decode_stall_steps`` / ``stalled_lane_steps``.
 """
 from __future__ import annotations
 
@@ -331,7 +323,7 @@ class Request:
 
 
 class ContinuousBatchingEngine:
-    """Token-granularity continuous batching over a (paged) decode engine."""
+    """Token-granularity continuous batching over page pools."""
 
     def __init__(self, cfg: ModelConfig, params: Params, *, batch: int,
                  max_len: int, paged: bool = True, page_size: int = 64,
@@ -340,7 +332,6 @@ class ContinuousBatchingEngine:
                  temperature: float = 0.0, seed: int = 0,
                  prefix_sharing: bool = False, chunk_size: int = 32,
                  token_budget: Optional[int] = None,
-                 prefill_interleave: bool = True,
                  allocator: Optional[Any] = None,
                  prefix_cache: Optional[Any] = None,
                  max_queue: Optional[int] = None,
@@ -350,11 +341,14 @@ class ContinuousBatchingEngine:
                  kv_quant: str = "off", swap_tier_pages: int = 0,
                  swap_min_tokens: Optional[int] = None,
                  role: str = "mixed"):
+        if not paged:
+            raise ValueError("the dense-cache mode (paged=False) was removed:"
+                             " the engine serves from page pools only")
+        self.paged = True         # read by benchmarks/chip/harness/cell.py
         self.cfg = cfg
         self.params = params
         self.batch = batch
         self.max_len = max_len
-        self.paged = paged
         self.page_size = page_size
         if role not in ("prefill", "decode", "mixed"):
             raise ValueError(f"role must be prefill/decode/mixed, "
@@ -370,46 +364,35 @@ class ContinuousBatchingEngine:
         self.role = role
         self.adopt_hook = None
         self.temperature = temperature
-        self.prefix_sharing = prefix_sharing and paged
+        self.prefix_sharing = prefix_sharing
         self.chunk_size = max(1, min(chunk_size, max_len))
         self.token_budget = (max(1, token_budget)
                              if token_budget is not None else None)
-        self.prefill_interleave = prefill_interleave
         self.maxp = -(-max_len // page_size)
         if kv_quant not in cache_mod.KV_QUANT_MODES:
             raise ValueError(f"kv_quant must be one of "
                              f"{cache_mod.KV_QUANT_MODES}, got {kv_quant!r}")
-        if kv_quant != "off" and not paged:
-            raise ValueError("kv_quant requires paged=True (quantized "
-                             "layouts are page-pool layouts)")
         self.kv_quant = kv_quant
-        if paged:
-            # Injectable backends: a replicated allocator / prefix cache
-            # (serving/replicated.py) swaps in for the host-local ones as
-            # long as it speaks the same API; the engine sizes its physical
-            # pool to the allocator's full page space either way.
-            if allocator is not None:
-                num_pages = allocator.num_pages
-            elif num_pages is None:
-                num_pages = batch * self.maxp
-            self.allocator = (PageAllocator(num_pages) if allocator is None
-                              else allocator)
-            self.prefix_cache = (PrefixCache(self.allocator, page_size)
-                                 if prefix_cache is None else prefix_cache)
-            self.trash_page = num_pages          # extra physical page
-            self.cache = lm.init_cache(cfg, batch, max_len, paged=True,
-                                       page_size=page_size,
-                                       num_pages=num_pages + 1,
-                                       kv_quant=kv_quant)
-            self.host_bt = np.full((batch, self.maxp), self.trash_page,
-                                   np.int32)
-            self.cache = lm.set_block_tables(self.cache,
-                                             jnp.asarray(self.host_bt))
-            self._copy_pages = jax.jit(lm.copy_pages, donate_argnums=(0,))
-        else:
-            self.allocator = None
-            self.prefix_cache = None
-            self.cache = lm.init_cache(cfg, batch, max_len)
+        # Injectable backends: a replicated allocator / prefix cache
+        # (serving/replicated.py) swaps in for the host-local ones as long
+        # as it speaks the same API; the engine sizes its physical pool to
+        # the allocator's full page space either way.
+        if allocator is not None:
+            num_pages = allocator.num_pages
+        elif num_pages is None:
+            num_pages = batch * self.maxp
+        self.allocator = (PageAllocator(num_pages) if allocator is None
+                          else allocator)
+        self.prefix_cache = (PrefixCache(self.allocator, page_size)
+                             if prefix_cache is None else prefix_cache)
+        self.trash_page = num_pages          # extra physical page
+        self.cache = lm.init_cache(cfg, batch, max_len, paged=True,
+                                   page_size=page_size,
+                                   num_pages=num_pages + 1,
+                                   kv_quant=kv_quant)
+        self.host_bt = np.full((batch, self.maxp), self.trash_page, np.int32)
+        self.cache = lm.set_block_tables(self.cache, jnp.asarray(self.host_bt))
+        self._copy_pages = jax.jit(lm.copy_pages, donate_argnums=(0,))
         self._mixed = jax.jit(
             engine_mod.make_mixed_step_fn(cfg, impl=impl,
                                           temperature=temperature),
@@ -427,7 +410,7 @@ class ContinuousBatchingEngine:
         # bit-exactly and resumes from the saved cursor.  Recurrent (state)
         # architectures always recompute — swap restores pages, not carries.
         self.swap_tier_pages = int(swap_tier_pages)
-        if paged and self.swap_tier_pages > 0 and not self._has_state:
+        if self.swap_tier_pages > 0 and not self._has_state:
             self.swap_pool = cache_mod.make_swap_pool(self.cache,
                                                       self.swap_tier_pages)
             self._swap_free = list(range(self.swap_tier_pages))
@@ -542,12 +525,10 @@ class ContinuousBatchingEngine:
             raise ValueError(f"request {req.rid} needs "
                              f"{len(req.prompt) + req.max_new_tokens} slots "
                              f"> max_len {self.max_len}")
-        if self.paged:
-            worst = -(-(len(req.prompt) + req.max_new_tokens)
-                      // self.page_size)
-            if worst > self.allocator.num_pages:
-                raise ValueError(f"request {req.rid} needs {worst} pages "
-                                 f"> pool {self.allocator.num_pages}")
+        worst = -(-(len(req.prompt) + req.max_new_tokens) // self.page_size)
+        if worst > self.allocator.num_pages:
+            raise ValueError(f"request {req.rid} needs {worst} pages "
+                             f"> pool {self.allocator.num_pages}")
         req.status = QUEUED
         req.submitted_step = self.stats["steps"]
         req.t_submit_ns = req.t_queued_ns = time.perf_counter_ns()
@@ -653,13 +634,11 @@ class ContinuousBatchingEngine:
         self.row_pos[row] = 0
 
     def _release_row(self, row: int) -> None:
-        req = self.rows[row]
-        if self.paged:
-            # req.pages is kept (now historical) — the allocator owns reuse,
-            # and a preempted request's re-admission overwrites the list.
-            self.allocator.free(req.pages, row=row)
-            self.host_bt[row, :] = self.trash_page
-            self._bt_dirty = True
+        # req.pages is kept (now historical) — the allocator owns reuse, and
+        # a preempted request's re-admission overwrites the list.
+        self.allocator.free(self.rows[row].pages, row=row)
+        self.host_bt[row, :] = self.trash_page
+        self._bt_dirty = True
 
     def _push_tables(self) -> None:
         if self._bt_dirty:
@@ -677,8 +656,6 @@ class ContinuousBatchingEngine:
         *published* at reservation time, before the chunk writes land, so
         physical adoption gates on this — the host-local ``PrefixCache``
         has no ``mark_filled`` and metadata-only sharing skips the call."""
-        if self.prefix_cache is None:
-            return
         mf = getattr(self.prefix_cache, "mark_filled", None)
         if mf is None or not req.pages:
             return
@@ -720,7 +697,7 @@ class ContinuousBatchingEngine:
             ctx = req.context
             swapped = bool(req.swap_slots)
             covered = 0
-            if self.paged and swapped:
+            if swapped:
                 # Swapped-out victim: pull its saved pages back from the
                 # host tier into fresh device pages and resume from the
                 # saved cursor — no recompute chunks for the covered prefix.
@@ -738,9 +715,8 @@ class ContinuousBatchingEngine:
                 self.host_bt[row, :len(pages)] = pages
                 self._bt_dirty = True
                 self._last_alloc[row] = self.stats["steps"]
-            elif self.paged:
-                first = min(self.chunk_size, len(ctx)) \
-                    if self.prefill_interleave else len(ctx)
+            else:
+                first = min(self.chunk_size, len(ctx))
                 npages_ctx = self._chunk_pages(len(ctx))
                 shared: list[int] = []
                 if self.prefix_sharing:
@@ -827,8 +803,7 @@ class ContinuousBatchingEngine:
                 mask[reset_rows] = True
                 self.cache = self._reset_state(self.cache, jnp.asarray(mask))
             self.stats["admitted"] += admitted
-            if self.paged:
-                self._note_peak()
+            self._note_peak()
         return admitted
 
     def _done(self, req: Request) -> bool:
@@ -1044,17 +1019,6 @@ class ContinuousBatchingEngine:
                      if self.rows[r] is not None and self.rows[r].admitting]
         budget = self.token_budget if self.token_budget is not None \
             else self.batch * self.chunk_size
-        if admitting and not self.prefill_interleave:
-            # Stalled-admission baseline: prompts land whole, decode lanes
-            # idle while any admission is in flight (the pre-mixed-step
-            # behaviour the bench quantifies).
-            if decoding:
-                self.stats["decode_stall_steps"] += 1
-                self.stats["stalled_lane_steps"] += len(decoding)
-            for r in admitting:
-                req = self.rows[r]
-                spans[r] = req.admit_len - req.filled
-            return spans
         starved = 0
         for r in decoding:
             if budget <= 0:
@@ -1063,8 +1027,8 @@ class ContinuousBatchingEngine:
             spans[r] = 1
             budget -= 1
         if starved:
-            # Same unit as the stalled baseline: a step counts once however
-            # many lanes it starves; the lane total rides the second counter.
+            # ``decode_stall_steps`` counts a step once however many decode
+            # lanes the budget starves; ``stalled_lane_steps`` counts lanes.
             self.stats["decode_stall_steps"] += 1
             self.stats["stalled_lane_steps"] += starved
         for r in admitting:
@@ -1182,9 +1146,8 @@ class ContinuousBatchingEngine:
             spans = self._compose()
             drafts = (self._fund_drafts(spans) if self.drafter is not None
                       else {})
-        if self.paged:
-            with trace.span("engine.pages"):
-                self._ensure_pages(spans)
+        with trace.span("engine.pages"):
+            self._ensure_pages(spans)
         # A mid-walk eviction zeroes the victim's span; drop its draft.
         drafts = {r: d for r, d in drafts.items()
                   if self.rows[r] is not None and spans[r] == 1 + len(d)}
@@ -1194,8 +1157,7 @@ class ContinuousBatchingEngine:
             self.stats["steps"] += 1
             return True
         with trace.span("engine.compose"):
-            clamp = (max(self.chunk_size, 1) if self.prefill_interleave
-                     else self.max_len)
+            clamp = max(self.chunk_size, 1)
             if self.drafter is not None:
                 clamp = max(clamp, 1 + self.spec_k)
             width = engine_mod.width_bucket(int(spans.max()), clamp)
@@ -1282,9 +1244,8 @@ class ContinuousBatchingEngine:
                         roll_hi[row] = pos0 + int(spans[row])
                         replay_spans[row] = n_app
                         rolled = True
-                        if self.paged:
-                            self._rollback_tail_pages(row, pos0 + n_app,
-                                                      pos0 + int(spans[row]))
+                        self._rollback_tail_pages(row, pos0 + n_app,
+                                                  pos0 + int(spans[row]))
                     self._mark_filled(req, upto=int(self.row_pos[row]))
                     if self._done(req):
                         self._free_row(row)
@@ -1376,14 +1337,10 @@ class ContinuousBatchingEngine:
                    for r in self.rows if r is not None)
 
     def resident_cache_bytes(self) -> int:
-        """Bytes of KV actually pinned right now.
-
-        Dense: the whole [B, Hkv, S, D] allocation, always.  Paged: pages in
-        use × per-page bytes — what a pool sized to the live-token watermark
-        would hold.  Shared (prefix) pages count once: that is the point.
+        """Bytes of KV actually pinned right now: pages in use × per-page
+        bytes — what a pool sized to the live-token watermark would hold.
+        Shared (prefix) pages count once: that is the point.
         """
-        if not self.paged:
-            return sum(int(x.nbytes) for x in jax.tree.leaves(self.cache))
         used = self.allocator.num_pages - self.allocator.available
         total = 0
         for _, layout, layer in cache_mod.iter_layers(self.cache):

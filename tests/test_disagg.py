@@ -68,14 +68,14 @@ def _requests(cfg, count=8, prompt_len=17, new_tokens=4, seed=11):
 
 
 def _run_disagg(cfg, params, reqs, *, adopt=True, xfer_crash=False,
-                replicas=2, **kw):
+                replicas=2, max_len=32, page_size=8, **kw):
     """Staggered arrivals (first wave of 2, then one per step) so the
     decode tier admits AFTER the prefill tier's publications gossip."""
     roles = ["prefill"] + ["decode"] * (replicas - 1)
     server = MultiEngineServer(cfg, params, replicas=replicas, batch=2,
-                               max_len=32, page_size=8, sync_every=1,
-                               chunk_size=8, roles=roles,
-                               adopt_pages=adopt, **kw)
+                               max_len=max_len, page_size=page_size,
+                               sync_every=1, chunk_size=page_size,
+                               roles=roles, adopt_pages=adopt, **kw)
     if xfer_crash:
         server.arm_transfer_crash(0)
     pending = list(reqs)
@@ -215,3 +215,43 @@ def test_disagg_deterministic_counters(mha_llm):
                      s["adopt_aborts"], s["cross_replica_hits"],
                      s["steps"], s["sync_bytes"]))
     assert runs[0] == runs[1]
+
+
+@pytest.fixture(scope="module")
+def sim_disagg_flags():
+    """Adoption off and on over the same staggered workload on the
+    simulated agent LLM: eight requests over two 53-token prompts (three
+    full 16-token pages and a partial one), 8 tokens each."""
+    from repro.agents.orchestrator import make_sim_llm
+    cfg, params = make_sim_llm()
+    runs = {}
+    for adopt in (False, True):
+        reqs = _requests(cfg, count=8, prompt_len=53, new_tokens=8, seed=0)
+        server = _run_disagg(cfg, params, reqs, adopt=adopt, max_len=128,
+                             page_size=16)
+        ttft = [r.first_token_step - r.admitted_step for r in reqs]
+        runs[adopt] = (server.stats(), server.converged(), reqs,
+                       sum(ttft) / len(ttft))
+    (s_off, conv_off, reqs_off, ttft_off) = runs[False]
+    (s_on, conv_on, reqs_on, ttft_on) = runs[True]
+    return {
+        "adopted_pages_positive": s_on["adopted_pages"] > 0,
+        "prefill_steps_avoided_positive": s_on["prefill_steps_avoided"] > 0,
+        "ttft_adopt_not_worse": ttft_on <= ttft_off,
+        "streams_match": ([r.tokens for r in reqs_on]
+                          == [r.tokens for r in reqs_off]),
+        "baseline_never_adopts": (s_off["adopted_pages"] == 0
+                                  and s_off["transfer_bytes"] == 0),
+        "all_completed": (s_off["completed"] == len(reqs_off)
+                          and s_on["completed"] == len(reqs_on)),
+        "all_converged": conv_off and conv_on,
+    }
+
+
+def test_disagg_acceptance_flags(sim_disagg_flags):
+    """Adoption moves pages and skips prefill chunks, never lengthens the
+    mean time to first token (in steps) against local prefill on the same
+    workload, leaves the token streams as they are, and the baseline
+    moves no byte."""
+    failed = [flag for flag, ok in sim_disagg_flags.items() if not ok]
+    assert not failed, failed

@@ -179,21 +179,24 @@ def test_mla_ragged_prefill_preserves_untouched_rows(mla_llm):
 
 
 def test_mla_scheduler_paged_dense_agree(mla_llm):
-    """Continuous batching over an MLA model: paged == dense token streams
-    (universal paging — the scheduler no longer cares about the layout)."""
+    """Continuous batching over an MLA model's latent page pools: batched
+    token streams equal each request's solo stream."""
     from repro.serving.scheduler import ContinuousBatchingEngine, Request
     cfg, params = mla_llm
     spec = [(5, 4), (9, 3), (3, 5), (7, 2)]
-    outs = {}
-    for mode in ("paged", "dense"):
+
+    def requests():
         rng = np.random.default_rng(7)
-        reqs = [Request(rid=i,
+        return [Request(rid=i,
                         prompt=[int(t) for t in rng.integers(2, 100, n)],
                         max_new_tokens=m)
                 for i, (n, m) in enumerate(spec)]
-        eng = ContinuousBatchingEngine(cfg, params, batch=2, max_len=32,
-                                       paged=(mode == "paged"), page_size=8)
-        outs[mode] = eng.run(reqs)
-        assert eng.stats["completed"] == len(spec)
-    for got, want in zip(outs["paged"], outs["dense"]):
+
+    eng = ContinuousBatchingEngine(cfg, params, batch=2, max_len=32,
+                                   page_size=8)
+    batched = eng.run(requests())
+    assert eng.stats["completed"] == len(spec)
+    for got, want in zip(batched, requests()):
+        ContinuousBatchingEngine(cfg, params, batch=1, max_len=32,
+                                 page_size=8).run([want])
         assert got.tokens == want.tokens, got.rid

@@ -193,24 +193,22 @@ def _mk_requests(rng, spec):
 
 
 def test_scheduler_paged_dense_solo_agree(llm):
+    """Batched serving emits each request's solo stream."""
     cfg, params = llm
     spec = [(5, 6), (9, 4), (3, 8), (7, 5), (4, 3)]
-    outs = {}
-    for mode in ("paged", "dense"):
-        rng = np.random.default_rng(7)
-        eng = ContinuousBatchingEngine(cfg, params, batch=2, max_len=32,
-                                       paged=(mode == "paged"), page_size=8)
-        outs[mode] = eng.run(_mk_requests(rng, spec))
-        assert eng.stats["completed"] == len(spec)
+    rng = np.random.default_rng(7)
+    eng = ContinuousBatchingEngine(cfg, params, batch=2, max_len=32,
+                                   page_size=8)
+    batched = eng.run(_mk_requests(rng, spec))
+    assert eng.stats["completed"] == len(spec)
     rng = np.random.default_rng(7)
     solo_reqs = _mk_requests(rng, spec)
     for r in solo_reqs:
         solo = ContinuousBatchingEngine(cfg, params, batch=1, max_len=32,
-                                        paged=True, page_size=8)
+                                        page_size=8)
         solo.run([r])
-    for mode in ("paged", "dense"):
-        for got, want in zip(outs[mode], solo_reqs):
-            assert got.tokens == want.tokens, (mode, got.rid)
+    for got, want in zip(batched, solo_reqs):
+        assert got.tokens == want.tokens, got.rid
 
 
 def test_mid_flight_admission_reuses_pages_without_disturbing_rows(llm):
@@ -338,35 +336,6 @@ def test_fused_serve_step_temperature_sampling(llm):
 
 
 # ---------------------------------------------------------------------------
-# Benchmark accounting: the write really is O(page), not O(max_len)
-# ---------------------------------------------------------------------------
-
-def test_serving_write_bytes_o_page_not_o_max_len(llm):
-    import sys
-    from pathlib import Path
-    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-    from benchmarks.bench_serving import analytic_step_bytes
-    cfg, _ = llm
-    live = [40, 10, 100]
-    w_dense_1k, _ = analytic_step_bytes(cfg, batch=3, max_len=1024,
-                                        page_size=16, live_lens=live,
-                                        paged=False)
-    w_dense_4k, _ = analytic_step_bytes(cfg, batch=3, max_len=4096,
-                                        page_size=16, live_lens=live,
-                                        paged=False)
-    w_paged_1k, r_paged_1k = analytic_step_bytes(cfg, batch=3, max_len=1024,
-                                                 page_size=16,
-                                                 live_lens=live, paged=True)
-    w_paged_4k, r_paged_4k = analytic_step_bytes(cfg, batch=3, max_len=4096,
-                                                 page_size=16,
-                                                 live_lens=live, paged=True)
-    assert w_dense_4k == 4 * w_dense_1k          # dense write ~ max_len
-    assert w_paged_4k == w_paged_1k              # paged write ~ O(page)
-    assert r_paged_4k == r_paged_1k              # reads ~ live tokens
-    assert w_dense_1k // w_paged_1k == 1024      # the headline ratio
-
-
-# ---------------------------------------------------------------------------
 # Reservation path, incremental growth, preemption, COW prefix sharing
 # ---------------------------------------------------------------------------
 
@@ -489,19 +458,27 @@ def test_prefix_sharing_cow_matches_unshared_tokens(llm):
 
 
 def test_prefix_share_resident_mb_below_unshared_at_fanout_4(llm):
-    """Acceptance: bench prefix-share column shows shared < non-shared."""
-    import sys
-    from pathlib import Path
-    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-    from benchmarks.bench_serving import run_prefix_share
+    """Four agents forked from one 21-token prompt pin fewer resident KV
+    bytes at their peak with copy-on-write prefix sharing than without."""
     cfg, params = llm
-    rows = {share: run_prefix_share(cfg, params, max_len=64, page_size=8,
-                                    fanout=4, prompt_len=21, max_new=4,
-                                    share=share)
-            for share in (False, True)}
-    assert rows[True]["resident_cache_mb"] < rows[False]["resident_cache_mb"]
-    assert rows[True]["shared_pages"] > 0
-    assert rows[True]["completed"] == rows[False]["completed"] == 4
+    prompt = [int(t) for t in
+              np.random.default_rng(0).integers(2, cfg.vocab_size, 21)]
+    peak, stats = {}, {}
+    for share in (False, True):
+        eng = ContinuousBatchingEngine(cfg, params, batch=4, max_len=64,
+                                       page_size=8, prefix_sharing=share)
+        for i in range(4):
+            eng.submit(Request(rid=i, prompt=list(prompt), max_new_tokens=4))
+        peak[share] = 0
+        while True:
+            more = eng.step()
+            peak[share] = max(peak[share], eng.resident_cache_bytes())
+            if not more:
+                break
+        stats[share] = eng.stats
+    assert peak[True] < peak[False]
+    assert stats[True]["shared_pages"] > 0
+    assert stats[True]["completed"] == stats[False]["completed"] == 4
 
 
 def test_zero_page_admission_fully_covered_by_shared_prefix(llm):

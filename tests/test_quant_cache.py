@@ -13,6 +13,9 @@ Covers the quantized cache layouts end to end:
     iterates _POOL_LEAF_NDIM, so scales must travel with their pages),
   * the serving stack under ``kv_quant="int8"``: greedy streams identical
     to bf16 pools, COW prefix sharing, speculative rollback, replication,
+  * at one KV head of 64: teacher-forced logits within a documented
+    error budget of bf16 pools (int8 keeping every greedy token), and
+    ≥1.8x fewer resident bytes per page than bf16,
   * tiered memory: swap-preemption recovers a long-context victim in fewer
     steps than recompute-from-scratch with identical streams and no leaked
     swap slots or device pages.
@@ -370,7 +373,10 @@ def test_engine_int8_streams_match_fp32(llm):
 
 def test_engine_rejects_quant_without_paged(llm):
     cfg, params = llm
-    with pytest.raises(ValueError, match="paged"):
+    with pytest.raises(ValueError, match="dense-cache mode"):
+        ContinuousBatchingEngine(cfg, params, batch=2, max_len=32,
+                                 paged=False)
+    with pytest.raises(ValueError, match="dense-cache mode"):
         ContinuousBatchingEngine(cfg, params, batch=2, max_len=32,
                                  paged=False, kv_quant="int8")
     with pytest.raises(ValueError, match="kv_quant"):
@@ -421,6 +427,132 @@ def test_replicated_server_with_int8_pools(llm):
     server.sync()
     assert server.stats()["completed"] == len(reqs)
     assert server.converged()
+
+
+@pytest.fixture(scope="module")
+def head64_llm():
+    """The simulated agent LLM with one KV head of 64, in bf16.  A quantized
+    pool's scales amortise over the feature axis, so its gain over bf16 is
+    2D/(D+4): 1.88x here (the 16-wide heads of the plain sim-LLM cap it at
+    1.6x whatever the layout)."""
+    from repro.agents.orchestrator import make_sim_llm
+    cfg = make_sim_llm()[0].replace(num_heads=1, num_kv_heads=1,
+                                    head_dim=64)
+    return cfg, lm.init(jax.random.PRNGKey(0), cfg)
+
+
+# Teacher-forced logit error against bf16 pools: int8's per-page-row scales
+# bound an element's error by amax/254; fp8 e4m3 keeps ~3 mantissa bits, so
+# its budget is looser and it may flip a near-tie argmax.
+QUANT_LOGIT_TOL = {"int8": 0.25, "fp8": 0.5}
+
+
+def _forced_logits(cfg, params, mode, tokens, feed=None, steps=12,
+                   page_size=8, max_len=64):
+    """Prefill ``tokens`` and decode ``steps`` tokens on ``mode`` pools,
+    feeding ``feed`` (or the run's own greedy tokens); returns the logits
+    of every step and the tokens fed."""
+    b, n = tokens.shape
+    maxp = -(-max_len // page_size)
+    cache = lm.set_block_tables(
+        lm.init_cache(cfg, b, max_len, paged=True, page_size=page_size,
+                      kv_quant=mode),
+        jnp.arange(b * maxp, dtype=jnp.int32).reshape(b, maxp))
+    logits, cache = lm.prefill(params, cfg, tokens, cache)
+    outs, fed = [np.asarray(logits, np.float32)], []
+    for t in range(steps):
+        nxt = (jnp.asarray(np.argmax(outs[-1], -1), jnp.int32)
+               if feed is None else feed[t])
+        fed.append(nxt)
+        logits, cache = lm.decode_step(params, cfg, nxt, cache,
+                                       jnp.full((b,), n + t, jnp.int32))
+        outs.append(np.asarray(logits, np.float32))
+    return outs, fed
+
+
+@pytest.mark.parametrize("mode", QMODES)
+def test_quant_logit_error_within_budget(head64_llm, mode):
+    cfg, params = head64_llm
+    tokens = jnp.asarray(np.random.default_rng(0).integers(
+        2, cfg.vocab_size, (2, 12)), jnp.int32)
+    want, fed = _forced_logits(cfg, params, "off", tokens)
+    got, _ = _forced_logits(cfg, params, mode, tokens, feed=fed)
+    worst = max(float(np.max(np.abs(g - w))) for g, w in zip(got, want))
+    assert worst <= QUANT_LOGIT_TOL[mode], worst
+    if mode == "int8":
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.argmax(g, -1),
+                                          np.argmax(w, -1))
+
+
+@pytest.fixture(scope="module")
+def head64_ragged_runs(head64_llm):
+    """The full engine on bf16 and int8 pools over one ragged workload with
+    mid-flight admission: six prompts of 4 to 59 tokens (log-uniform), 8
+    tokens each, at batch 4, page 16, chunk 16."""
+    cfg, params = head64_llm
+    rng = np.random.default_rng(0)
+    lens = np.exp(rng.uniform(np.log(4), np.log(59), 6)).astype(int)
+    prompts = [[int(t) for t in rng.integers(2, cfg.vocab_size, n)]
+               for n in lens]
+    runs = {}
+    for mode in ("off", "int8"):
+        reqs = [Request(rid=i, prompt=list(p), max_new_tokens=8)
+                for i, p in enumerate(prompts)]
+        eng = ContinuousBatchingEngine(cfg, params, batch=4, max_len=128,
+                                       page_size=16, chunk_size=16,
+                                       kv_quant=mode)
+        for r in reqs:
+            eng.submit(r)
+        peak = 0
+        while eng.step():
+            peak = max(peak, eng.resident_cache_bytes())
+        assert eng.stats["completed"] == len(reqs)
+        runs[mode] = (eng.stats, peak, [list(r.tokens) for r in reqs])
+    return prompts, runs
+
+
+def test_engine_int8_streams_match_bf16_pools(head64_ragged_runs):
+    _, runs = head64_ragged_runs
+    assert runs["int8"][2] == runs["off"][2]
+
+
+def test_engine_int8_tokens_within_budget_on_fewer_bytes(head64_llm,
+                                                         head64_ragged_runs):
+    """int8 pools walk the bf16 run's pages in the same steps while pinning
+    fewer bytes (a step reads each live page whole, pool and scales), and
+    each token they serve scores within the int8 logit budget of the best
+    under a cache-free bf16 forward of the sequence served so far."""
+    cfg, params = head64_llm
+    prompts, runs = head64_ragged_runs
+    (s_off, peak_off, _), (s_q, peak_q, streams) = runs["off"], runs["int8"]
+    for name in ("steps", "peak_pages", "prefill_chunks"):
+        assert s_q[name] == s_off[name], name
+    assert peak_q < peak_off
+    for prompt, toks in zip(prompts, streams):
+        seq = jnp.asarray([prompt + toks], jnp.int32)
+        logits = np.asarray(lm.forward(params, cfg, seq)[0][0], np.float32)
+        for k, tok in enumerate(toks):
+            row = logits[len(prompt) + k - 1]
+            assert row.max() - row[tok] <= QUANT_LOGIT_TOL["int8"], (k, tok)
+
+
+@pytest.mark.parametrize("mode", QMODES)
+def test_quant_pool_pins_fewer_bytes_per_page(head64_llm, mode):
+    """What one resident page pins, pool and scale leaves together, against
+    bf16 pools: how many more tokens the same pool memory holds."""
+    cfg, params = head64_llm
+    per_page = {}
+    for m in ("off", mode):
+        eng = ContinuousBatchingEngine(cfg, params, batch=2, max_len=64,
+                                       page_size=8, chunk_size=8, kv_quant=m)
+        for r, p in enumerate(_prompts(cfg, (20, 9))):
+            eng.submit(Request(rid=r, prompt=p, max_new_tokens=4))
+        eng.step()
+        used = eng.allocator.num_pages - eng.allocator.available
+        assert used > 0
+        per_page[m] = eng.resident_cache_bytes() / used
+    assert per_page["off"] / per_page[mode] >= 1.8
 
 
 # ---------------------------------------------------------------------------

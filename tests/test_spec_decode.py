@@ -15,11 +15,12 @@ Acceptance bar (mirrors PR-4's chunked-prefill equivalence sweep):
   matvecs, which is why the *byte* guarantee is scoped to the restored
   tail, not cross-width cache equality);
 * the scheduler end-to-end: speculative streams equal non-speculative
-  greedy across drafters (ngram / doc / adversarial), chunk sizes, dense
-  and paged modes, with zero page leaks — including COW prefix-shared
-  rows (no double-free);
+  greedy across drafters (ngram / doc / adversarial) and chunk sizes,
+  with zero page leaks — including COW prefix-shared rows (no
+  double-free); on the simulated agent LLM both drafters accept drafts;
 * the orchestrator: sequential agent trials are digest-identical off vs
-  speculative, and uncoupled parallel trials too.
+  speculative (and doc-drafted trials take fewer steps), and uncoupled
+  parallel trials too.
 
 Everything runs in f32 interpret mode on CPU.
 """
@@ -305,10 +306,10 @@ def _spec_prompts(rng, n=5):
     return [(pat * 4)[:12 + i] for i in range(n)]
 
 
-def _run_sched(cfg, params, prompts, spec, *, drafter=None, paged=True,
-               chunk=8, max_new=8):
+def _run_sched(cfg, params, prompts, spec, *, drafter=None, chunk=8,
+               max_new=8):
     eng = ContinuousBatchingEngine(
-        cfg, params, batch=3, max_len=64, paged=paged, page_size=PS,
+        cfg, params, batch=3, max_len=64, page_size=PS,
         chunk_size=chunk, spec_decode=spec, spec_k=4, drafter=drafter)
     out = eng.run([Request(rid=i, prompt=list(p), max_new_tokens=max_new)
                    for i, p in enumerate(prompts)])
@@ -378,15 +379,6 @@ def test_scheduler_spec_streams_across_chunk_sizes(mha_llm, chunk):
     assert got == base
 
 
-def test_scheduler_spec_dense_mode(mha_llm):
-    cfg, params = mha_llm
-    prompts = _spec_prompts(np.random.RandomState(0))
-    _, base = _run_sched(cfg, params, prompts, "off", paged=False)
-    eng, got = _run_sched(cfg, params, prompts, "ngram", paged=False)
-    assert got == base
-    assert eng.stats["accepted_tokens"] > 0
-
-
 def test_scheduler_cow_prefix_shared_rollback_no_double_free(mha_llm):
     cfg, params = mha_llm
     prompt = np.random.RandomState(1).randint(0, V, size=17).tolist()
@@ -454,6 +446,82 @@ def test_orchestrator_spec_digest_identity():
                              chunk_size=16, spec_decode=spec)
     assert par["doc"].digest == par["off"].digest
     assert par["doc"].gen_tokens == par["off"].gen_tokens
+
+
+@pytest.fixture(scope="module")
+def sim_spec_runs():
+    """The simulated agent LLM serving six motif-repeating 59-token prompts
+    (trailing n-grams recur upstream, the case prompt lookup targets), 16
+    tokens each, at batch 4, page 16, chunk 16 and ``spec_k`` 4: without
+    speculation, with n-gram drafts, and with doc drafts seeded by the
+    non-speculative streams (standing in for converged CRDT documents)."""
+    from repro.agents.orchestrator import make_sim_llm
+    cfg, params = make_sim_llm()
+    rng = np.random.default_rng(0)
+    prompts = []
+    for _ in range(6):
+        m = 4 + int(rng.integers(0, 4))
+        motif = [int(t) for t in rng.integers(2, cfg.vocab_size, m)]
+        tail = [int(t) for t in rng.integers(2, cfg.vocab_size, m)]
+        prompts.append((motif * -(-59 // m))[:59 - m] + tail)
+
+    def run(spec, drafter=None):
+        eng = ContinuousBatchingEngine(
+            cfg, params, batch=4, max_len=128, page_size=16, chunk_size=16,
+            spec_decode=spec, spec_k=4, drafter=drafter)
+        out = eng.run([Request(rid=i, prompt=list(p), max_new_tokens=16)
+                       for i, p in enumerate(prompts)])
+        return eng, [r.tokens for r in out]
+
+    runs = {"off": run("off")}
+    doc = draft_mod.DocDrafter()
+    doc.set_docs([list(p) + t for p, t in zip(prompts, runs["off"][1])])
+    runs["ngram"] = run("ngram")
+    runs["doc"] = run("doc", drafter=doc)
+    return runs
+
+
+def test_sim_llm_speculation_flags(sim_spec_runs):
+    """Both drafters serve the greedy streams, draft, and have drafts
+    accepted."""
+    greedy = sim_spec_runs["off"][1]
+    failed = []
+    for spec in ("ngram", "doc"):
+        eng, streams = sim_spec_runs[spec]
+        flags = {"streams_match": streams == greedy,
+                 "all_completed": eng.stats["completed"] == len(streams),
+                 "drafted": eng.stats["draft_tokens"] > 0,
+                 "accept_rate_positive": eng.spec_accept_rate > 0}
+        failed += [f"{spec}:{f}" for f, ok in flags.items() if not ok]
+    assert not failed, failed
+
+
+@pytest.fixture(scope="module")
+def doc_agent_trials():
+    """The tic-tac-toe task run sequentially (one writer, so the document
+    digest is a function of the token streams), without speculation and
+    with doc drafting."""
+    from repro.agents.orchestrator import make_sim_llm, run_task
+    from repro.agents.tasks import TASKS
+
+    cfg, params = make_sim_llm()
+    return {spec: run_task(cfg, params, TASKS["tic_tac_toe"],
+                           mode="sequential", seed=0, max_len=256,
+                           kv="paged", prefill="chunked", page_size=16,
+                           chunk_size=16, spec_decode=spec, spec_k=4)
+            for spec in ("off", "doc")}
+
+
+def test_doc_drafted_agent_trial_digest_matches(doc_agent_trials):
+    off, doc = doc_agent_trials["off"], doc_agent_trials["doc"]
+    assert doc.digest == off.digest
+    assert doc.gen_tokens == off.gen_tokens
+
+
+def test_doc_drafted_agent_trial_takes_fewer_steps(doc_agent_trials):
+    off, doc = doc_agent_trials["off"], doc_agent_trials["doc"]
+    assert doc.accepted_tokens > 0
+    assert doc.steps < off.steps
 
 
 def test_orchestrator_spec_requires_chunked():

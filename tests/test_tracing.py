@@ -103,11 +103,6 @@ STEP_CASES = {
                 ["engine.admit", "engine.compose", "engine.pages",
                  "engine.compose", "engine.upload", "engine.dispatch",
                  "engine.sync", "engine.commit", "engine.admit"]),
-    # dense cache: no pages to walk
-    "dense": (dict(batch=2, paged=False), [6, 3], [2, 2],
-              ["engine.admit", "engine.compose", "engine.compose",
-               "engine.upload", "engine.dispatch", "engine.sync",
-               "engine.commit"]),
 }
 
 
@@ -221,10 +216,9 @@ def test_step_and_span_positions_match_a_scripted_sequence(llm, case):
 
 # -- tokens do not depend on tracing ---------------------------------------------
 
-@pytest.mark.parametrize("mode", ["paged", "dense", "speculative"])
+@pytest.mark.parametrize("mode", ["paged", "speculative"])
 def test_generated_tokens_identical_with_tracing_on_and_off(llm, mode):
-    kw = {"paged": dict(), "dense": dict(paged=False),
-          "speculative": dict(spec_decode="ngram")}[mode]
+    kw = {"paged": dict(), "speculative": dict(spec_decode="ngram")}[mode]
     lens, news = [6, 11, 3], [5, 4, 6]
     out = {}
     for on in (False, True):
