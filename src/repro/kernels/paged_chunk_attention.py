@@ -40,7 +40,9 @@ write and the walk.  The quantized twin keeps grid (B, Hkv) and one page
 per step.  MLA-latent variant: grid (B,), pool ``[L, P, ps, Dp]`` storing
 concat([ckv; krope]) rows; queries arrive pre-absorbed (concat([q_abs;
 q_rope])) so both logits terms are one contraction, as in
-kernels/paged_mla_decode.py.
+kernels/paged_mla_decode.py.  A span-1 row of a wider step walks with its
+H live query rows alone and writes zeros at its padded positions; every
+other row carries all H·C.
 
 Each ``pallas_call`` names its kernel (``paged_chunk_attention``,
 ``paged_mla_chunk``, and the ``_quant`` twins), so a profiler trace finds
@@ -484,6 +486,7 @@ def _mla_kernel(layer_ref, bt_ref, start_ref, span_ref, q_ref, ln_ref, lp_in,
     kv_len = start + span
     maxp = bt_ref.shape[1]
     n_pages = jnp.minimum((jnp.maximum(kv_len, 1) + ps - 1) // ps, maxp)
+    h = q_ref.shape[1]
 
     # -- fused multi-slot write: span latent rows -> their page slots -------
     def write_page(page, base):
@@ -499,44 +502,63 @@ def _mla_kernel(layer_ref, bt_ref, start_ref, span_ref, q_ref, ln_ref, lp_in,
         return pltpu.make_async_copy(
             lp.at[pl.ds(pg, 1)], buf.at[pl.ds(slot, 1)], dsem.at[slot])
 
-    page_dma(0, 0).start()
+    def walk(qf, qpos):
+        """Context [rows, r] of the f32 query rows ``qf`` [rows, width] at
+        positions ``qpos`` (broadcast against [rows, ps]): both kinds of
+        row take this one walk."""
+        rows = qf.shape[0]
+        page_dma(0, 0).start()
 
-    q = q_ref[0].astype(jnp.float32)                   # [H, C, width]
-    h = q.shape[0]
-    qf = q.reshape(h * c, width)
-    qpos = start + jax.lax.broadcasted_iota(jnp.int32, (h * c, ps), 0) % c
+        def body(i, carry):
+            m, l, acc = carry
+            slot = jax.lax.rem(i, 2)
 
-    def body(i, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(i, 2)
-        nxt = jax.lax.rem(i + 1, 2)
+            @pl.when(i + 1 < n_pages)
+            def _prefetch():
+                page_dma(i + 1, jax.lax.rem(i + 1, 2)).start()
 
-        @pl.when(i + 1 < n_pages)
-        def _prefetch():
-            page_dma(i + 1, nxt).start()
+            page_dma(i, slot).wait()
+            lat = buf[slot].astype(jnp.float32)        # [ps, Dp]
+            s = jax.lax.dot_general(
+                qf, lat[:, :width], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [rows, ps]
+            cols = i * ps + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
+            s = jnp.where(cols <= qpos, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[:, None])
+            l_new = l * alpha + jnp.sum(p, axis=1)
+            acc_new = acc * alpha[:, None] + jax.lax.dot_general(
+                p, lat[:, :r], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)    # [rows, r]
+            return m_new, l_new, acc_new
 
-        page_dma(i, slot).wait()
-        lat = buf[slot].astype(jnp.float32)            # [ps, Dp]
-        s = jax.lax.dot_general(
-            qf, lat[:, :width], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # [H*C, ps]
-        cols = i * ps + jax.lax.broadcasted_iota(jnp.int32, (h * c, ps), 1)
-        s = jnp.where(cols <= qpos, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_new = l * alpha + jnp.sum(p, axis=1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-            p, lat[:, :r], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [H*C, r]
-        return m_new, l_new, acc_new
+        m0 = jnp.full((rows,), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((rows,), jnp.float32)
+        a0 = jnp.zeros((rows, r), jnp.float32)
+        _, l, acc = jax.lax.fori_loop(0, n_pages, body, (m0, l0, a0))
+        return acc / jnp.maximum(l, 1e-30)[:, None]
 
-    m0 = jnp.full((h * c,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((h * c,), jnp.float32)
-    a0 = jnp.zeros((h * c, r), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n_pages, body, (m0, l0, a0))
-    out = acc / jnp.maximum(l, 1e-30)[:, None]
-    o_ref[0] = out.reshape(h, c, r).astype(o_ref.dtype)
+    def walk_all():
+        # Query row h*C + j carries intra-span offset j -> position start+j.
+        qf = q_ref[0].astype(jnp.float32).reshape(h * c, width)
+        qpos = start + jax.lax.broadcasted_iota(jnp.int32, (h * c, ps), 0) % c
+        o_ref[0] = walk(qf, qpos).reshape(h, c, r).astype(o_ref.dtype)
+
+    if c == 1:                      # every row's live queries are its H rows
+        walk_all()
+        return
+
+    # A span-1 row (a decoding row of a wider step) has H live query rows of
+    # its H x C: walk with those alone.  Its padded positions read zeros.
+    @pl.when(span == 1)
+    def _one():
+        ctx = walk(q_ref[0, :, 0, :].astype(jnp.float32), start)   # [H, r]
+        pos = jax.lax.broadcasted_iota(jnp.int32, (h, c, r), 1)
+        o_ref[0] = jnp.where(pos == 0, ctx[:, None, :],
+                             0.0).astype(o_ref.dtype)
+
+    pl.when(span != 1)(walk_all)
 
 
 @functools.partial(

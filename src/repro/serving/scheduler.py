@@ -508,6 +508,8 @@ class ContinuousBatchingEngine:
                       # (batch x width) and those that were rows' spans; the
                       # difference is padding.
                       "step_positions": 0, "span_positions": 0,
+                      # Span-1 rows of the dispatched steps wider than 1.
+                      "span1_rows": 0,
                       # Expert layers: (token, expert) pairs computed on
                       # the held experts, and each step's busiest held
                       # expert's pairs in one layer, summed over steps.
@@ -1111,6 +1113,13 @@ class ContinuousBatchingEngine:
         self._dev_memo[name] = (arr.copy(), dev)
         return dev
 
+    def _count_positions(self, spans: np.ndarray, width: int) -> None:
+        """Count a dispatched step of ``width`` positions a row."""
+        self.stats["step_positions"] += self.batch * width
+        self.stats["span_positions"] += int(spans.sum())
+        if width > 1:
+            self.stats["span1_rows"] += int((spans == 1).sum())
+
     # -- decode loop --------------------------------------------------------
 
     def step(self) -> bool:
@@ -1179,8 +1188,7 @@ class ContinuousBatchingEngine:
             toks_dev = self._to_dev(f"tok{width}", toks.astype(np.int32))
             start_dev = self._to_dev("start", self.row_pos.astype(np.int32))
             span_dev = self._to_dev(f"span{width}", spans.astype(np.int32))
-        self.stats["step_positions"] += self.batch * width
-        self.stats["span_positions"] += int(spans.sum())
+        self._count_positions(spans, width)
         if self.drafter is not None:
             with trace.span("engine.dispatch"):
                 snap = (self._snap(self.cache, start_dev, width)
@@ -1297,8 +1305,7 @@ class ContinuousBatchingEngine:
                     # overwrites.
                     w2 = engine_mod.width_bucket(int(replay_spans.max()),
                                                  clamp)
-                    self.stats["step_positions"] += self.batch * w2
-                    self.stats["span_positions"] += int(replay_spans.sum())
+                    self._count_positions(replay_spans, w2)
                     _, _, self.cache = self._verify(
                         self.params, self.cache,
                         self._to_dev(f"rtok{w2}",

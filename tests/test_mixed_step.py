@@ -208,10 +208,16 @@ def test_paged_chunk_span1_matches_decode_kernel_write():
     np.testing.assert_array_equal(np.asarray(vp1), np.asarray(vp2))
 
 
-@pytest.mark.parametrize("c", [1, 4, 8])
+@pytest.mark.parametrize("c", [1, 4, 8, 16, 32])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_mla_chunk_kernel_matches_oracle(c, dtype):
-    b, h, r, rd, ps, maxp = 2, 4, 16, 8, 8, 4
+    """Widths 16 and 32 are the served step's: their rows hold span 0, span
+    1 (the one-position walk), a partial and a full span, over contexts of
+    several pages.  Every padded query row's output is finite, and a
+    span-1 row's is zero."""
+    wide = c >= 16
+    b, h, r, rd, ps, maxp = (4 if wide else 2), 4, 16, 8, 8, (12 if wide
+                                                              else 4)
     dp = 128
     rng = np.random.default_rng(7)
     pool = b * maxp + 1
@@ -220,8 +226,12 @@ def test_paged_mla_chunk_kernel_matches_oracle(c, dtype):
     lp = jnp.asarray(rng.normal(size=(pool, ps, dp)), dtype)
     bt = jnp.asarray(rng.permutation(pool)[:b * maxp].reshape(b, maxp)
                      .astype(np.int32))
-    start = jnp.asarray(rng.integers(0, maxp * ps - c, b), jnp.int32)
-    span = jnp.asarray(rng.integers(0, c + 1, b), jnp.int32)
+    if wide:
+        start = jnp.asarray([19, 45, 27, 50], jnp.int32)
+        span = jnp.asarray([0, 1, c // 2 + 3, c], jnp.int32)
+    else:
+        start = jnp.asarray(rng.integers(0, maxp * ps - c, b), jnp.int32)
+        span = jnp.asarray(rng.integers(0, c + 1, b), jnp.int32)
     ln = jnp.asarray(rng.normal(size=(b, c, dp)), dtype)
     ctx1, lp1 = ops.paged_mla_chunk(q_abs, q_rope, lp, bt, start, span, ln,
                                     scale=0.125)
@@ -233,6 +243,38 @@ def test_paged_mla_chunk_kernel_matches_oracle(c, dtype):
         np.where(mask, np.asarray(ctx1), 0.0),
         np.where(mask, np.asarray(ctx2), 0.0), **_tol(dtype))
     np.testing.assert_array_equal(np.asarray(lp1), np.asarray(lp2))
+    assert np.isfinite(np.asarray(ctx1)).all()
+    one = np.asarray(span) == 1
+    np.testing.assert_array_equal(np.asarray(ctx1)[one][:, :, 1:], 0.0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_mla_chunk_span1_row_equals_width1_call(dtype):
+    """A span-1 row of a width-32 call attends with its H live queries
+    exactly as a width-1 call does: the context is bit for bit the same,
+    whatever its padded query positions hold (NaN here), and so is the
+    pool after the fused write."""
+    b, h, r, rd, ps, maxp, c, dp = 3, 4, 16, 8, 8, 12, 32, 128
+    rng = np.random.default_rng(5)
+    pool = b * maxp + 1
+    normal = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    q_abs, q_rope = normal(b, h, c, r), normal(b, h, c, rd)
+    lp = normal(pool, ps, dp).astype(dtype)
+    ln = normal(b, c, dp).astype(dtype)
+    bt = jnp.asarray(rng.permutation(pool)[:b * maxp].reshape(b, maxp)
+                     .astype(np.int32))
+    start = jnp.asarray([37, 70, 5], jnp.int32)   # 5, 9 and 1 pages
+    span = jnp.asarray([1, 1, 0], jnp.int32)
+    padded = (jnp.arange(c) > 0)[None, None, :, None] & (span == 1)[
+        :, None, None, None]
+    wide = ops.paged_mla_chunk(jnp.where(padded, jnp.nan, q_abs),
+                               jnp.where(padded, jnp.nan, q_rope), lp, bt,
+                               start, span, ln, scale=0.125)
+    narrow = ops.paged_mla_chunk(q_abs[:, :, :1], q_rope[:, :, :1], lp, bt,
+                                 start, span, ln[:, :1], scale=0.125)
+    np.testing.assert_array_equal(np.asarray(wide[0])[:2, :, :1],
+                                  np.asarray(narrow[0])[:2])
+    np.testing.assert_array_equal(np.asarray(wide[1]), np.asarray(narrow[1]))
 
 
 @pytest.mark.parametrize("kernel", ["mha", "mha_int8", "mla", "mla_int8"])

@@ -1,5 +1,6 @@
 """Tracing of the serving step (``repro.runtime.trace``) and the step's
-position counters (``stats["step_positions"]``, ``["span_positions"]``),
+position counters (``stats["step_positions"]``, ``["span_positions"]``,
+``["span1_rows"]``),
 at tiny sizes on the CPU: what is recorded off and on, how the spans of one
 step nest, the per-request intervals (with preemption), the counters over
 scripted steps, tokens unchanged by tracing, and the spans' names in a
@@ -212,6 +213,36 @@ def test_step_and_span_positions_match_a_scripted_sequence(llm, case):
     assert got == want
     assert eng.stats["step_positions"] - eng.stats["span_positions"] \
         == sum(w - s for w, s in want)
+
+
+def test_span1_rows_count_the_decoding_rows_of_wide_steps(llm):
+    """One row admits a prompt chunk by chunk while three decode: each
+    step wider than 1 adds its span-1 rows to ``stats["span1_rows"]``, and
+    a width-1 step adds none."""
+    eng = _engine(llm, batch=4, chunk=4)
+    dispatched = []
+    mixed = eng._mixed
+
+    def spy(params, cache, toks, start, span, key):
+        dispatched.append((toks.shape[1], np.asarray(span)))
+        return mixed(params, cache, toks, start, span, key)
+
+    eng._mixed = spy
+    for r in _requests([2, 2, 2, 14], [8, 8, 8, 2]):
+        eng.submit(r)
+    got, want = [], []
+    more = True
+    while more:
+        n0, s0 = len(dispatched), eng.stats["span1_rows"]
+        more = eng.step()
+        got.append(eng.stats["span1_rows"] - s0)
+        want.append(sum(int((span == 1).sum()) for width, span
+                        in dispatched[n0:] if width > 1))
+    assert got == want
+    widths = [w for w, _ in dispatched]
+    assert max(got) == 3 and 1 in widths          # both kinds of step ran
+    assert eng.stats["span1_rows"] == sum(
+        int((span == 1).sum()) for w, span in dispatched if w > 1)
 
 
 # -- tokens do not depend on tracing ---------------------------------------------
